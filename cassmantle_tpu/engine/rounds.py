@@ -212,9 +212,13 @@ class RoundManager:
 
     # -- content helpers --------------------------------------------------
     async def _store_content(self, slot: str, content: RoundContent) -> None:
-        prompt_state = build_prompt_state(
-            content.prompt_text, self.embed, self.num_masked
-        )
+        # off the event loop: ``embed`` is sync and device-embeds every
+        # word the int8 table does not hold — a cold encode compile here
+        # stalled the loop past the overload plane's lag bound, which
+        # then shed interactive scoring (same rule as _notify_answers)
+        prompt_state = await asyncio.to_thread(
+            build_prompt_state, content.prompt_text, self.embed,
+            self.num_masked)
         state_json = json.dumps(prompt_state)
         jpeg = encode_jpeg(content.image)
         await self.store.hset(PROMPT_KEY, "seed", content.prompt_text)

@@ -121,7 +121,7 @@ async def polled_store_lock(send, name: str, timeout: float,
     mantlestore-speaking backend, shared by :class:`MantleStore
     <cassmantle_tpu.native.client.MantleStore>` and
     :class:`ReplicatedStore` so lock semantics (poll cadence, timeout,
-    and the ``:2`` overrun / ``:0`` expired-in-hold hazard taxonomy)
+    and the ``:2`` overrun / ``:0`` expired-in-hold hazard classification)
     can never drift between the two transports. ``send(*args: bytes)``
     performs one command round trip."""
     token = uuid.uuid4().hex.encode()

@@ -25,7 +25,14 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import random
-from typing import AsyncIterator, Callable, Dict, List, Optional
+from typing import (
+    AsyncIterator,
+    Awaitable,
+    Callable,
+    Dict,
+    List,
+    Optional,
+)
 
 from cassmantle_tpu.config import FrameworkConfig
 from cassmantle_tpu.engine.game import Game
@@ -138,10 +145,15 @@ class RoomFabric:
         start_timers: bool = True,
         heartbeat: bool = True,
         supervisor=None,
+        serving_stop: Optional[Callable[[], Awaitable[None]]] = None,
     ) -> None:
         self.cfg = cfg
         self.store = store
         self.game_factory = game_factory
+        # stops the worker's one serving stack (the InferenceService's
+        # batching queues and their dispatch threads) once the rooms
+        # that feed it are drained; None when the backend owns none
+        self._serving_stop = serving_stop
         self.worker_id = worker_id
         self.start_timers = start_timers
         # ONE supervisor per worker, shared by every room's game (and
@@ -568,6 +580,8 @@ class RoomFabric:
                 await self.membership.leave()
         for room in list(self._games):
             await self.drain_room(room)
+        if self._serving_stop is not None:
+            await self._serving_stop()
         await self.store.close()
 
     async def _heartbeat_loop(self) -> None:

@@ -310,7 +310,7 @@ class MantleStore(StateStore):
     def lock(self, name: str, timeout: float = 120.0,
              blocking_timeout: float = 2.0):
         # the shared polled protocol (engine/store.py): one definition
-        # of the acquire loop and the :2/:0 hazard taxonomy for both
+        # of the acquire loop and the :2/:0 hazard classification for both
         # the single-node and replicated transports
         return polled_store_lock(self._cmd, name, timeout,
                                  blocking_timeout)

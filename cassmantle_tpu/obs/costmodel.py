@@ -48,21 +48,45 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 COST_MODEL_PATH = os.path.join(_REPO_ROOT, "data", "cost_model.json")
 
-#: default per-chip peak (bf16 TFLOP/s): the v5e figure every PERF_NOTES
-#: ceiling uses; override per fleet via CASSMANTLE_CHIP_TFLOPS (§6).
-DEFAULT_CHIP_TFLOPS = 197.0
+#: Peak dense bf16 FLOP/s of one chip, keyed by the ``device_kind`` jax
+#: reports, each with its source. The only table of peaks in the repo:
+#: a device that is not here has no utilization, not a default one.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
+                  "bf16 per chip",
+    },
+}
 
 
-def chip_peak_flops() -> float:
-    """Peak device FLOP/s the ``pipeline.mxu_utilization`` gauge divides
-    by. On a non-TPU backend the ratio still renders (a tiny honest
-    number) so the CPU smoke path exercises the same code."""
-    raw = os.environ.get("CASSMANTLE_CHIP_TFLOPS", "")
+class UnknownDeviceKind(LookupError):
+    """A TPU whose ``device_kind`` has no row in :data:`CHIP_PEAKS`."""
+
+
+def peak_flops_for_kind(device_kind: str) -> float:
+    """Peak bf16 FLOP/s for a ``device_kind``; raises
+    :class:`UnknownDeviceKind` for one the table does not hold."""
     try:
-        tflops = float(raw) if raw else DEFAULT_CHIP_TFLOPS
-    except ValueError:
-        tflops = DEFAULT_CHIP_TFLOPS
-    return tflops * 1e12
+        return float(CHIP_PEAKS[device_kind]["bf16_flops"])
+    except KeyError:
+        raise UnknownDeviceKind(
+            f"no peak FLOP/s on record for device_kind {device_kind!r}; "
+            f"add it to obs/costmodel.py CHIP_PEAKS with its source "
+            f"(known: {sorted(CHIP_PEAKS)})") from None
+
+
+def chip_peak_flops() -> Optional[float]:
+    """Peak FLOP/s the ``pipeline.mxu_utilization`` gauge divides by:
+    the attached TPU's row of :data:`CHIP_PEAKS` (an unknown kind
+    raises), or None off-TPU — a CPU run has no MXU to be utilized, so
+    it renders no gauge."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    return peak_flops_for_kind(device.device_kind)
 
 
 # -- per-eqn analytic math (shared with tools/profile_unet.py) -------------

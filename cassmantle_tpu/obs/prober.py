@@ -117,7 +117,9 @@ async def ensure_probe_round(game) -> Dict:
     and cheap once seeded (one hget + one ttl)."""
     from cassmantle_tpu.utils.codec import encode_jpeg
 
-    state = probe_state(game)
+    # off the event loop: the first derivation device-embeds the probe
+    # sentence (engine/rounds.py::_store_content has the same rule)
+    state = await asyncio.to_thread(probe_state, game)
     store = game.store
     if await store.hget(PROMPT_KEY, "current") is None:
         await store.hset(PROMPT_KEY, "seed", PROBE_SENTENCE)
@@ -247,7 +249,8 @@ class CanaryProber:
         from cassmantle_tpu.engine.game import PROBE_ROOM
         from cassmantle_tpu.utils.codec import decode_jpeg
 
-        state = probe_state(self.fabric.probe_game())
+        state = await asyncio.to_thread(
+            probe_state, self.fabric.probe_game())
         answers = probe_answers(state)
         session_id = f"canary-{self.fabric.worker_id}"
         params = {"room": PROBE_ROOM, "session": session_id}

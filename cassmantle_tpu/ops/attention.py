@@ -9,7 +9,8 @@ remote API with local TPU compute" north star.
 
 Dispatch policy:
 - TPU + no mask + seq long enough to tile → Pallas flash attention
-  (blockwise online-softmax, O(N) memory; ops/flash_attention.py);
+  (blockwise online-softmax, O(N) memory; ops/flash_attention.py),
+  per batch shard inside a :func:`batch_sharded_kernels` region;
 - otherwise → jnp.einsum attention, which XLA fuses well on its own.
 """
 
@@ -17,10 +18,14 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from cassmantle_tpu.ops.platform import on_tpu
 
 # When set, every attention site uses the plain XLA path — used when
 # tracing for a non-TPU device (e.g. CPU-side param init) while the default
@@ -33,6 +38,16 @@ _FORCE_XLA = contextvars.ContextVar("cassmantle_force_xla", default=False)
 # (parallel/ring.py) and stay permuted through the whole network.
 _CONTEXT_PARALLEL = contextvars.ContextVar(
     "cassmantle_context_parallel", default=None
+)
+
+# When set to (mesh, batch_axis), the flash kernels traced inside run
+# per batch shard under shard_map. A Mosaic kernel cannot be partitioned
+# by GSPMD ("Mosaic kernels cannot be automatically partitioned"), so a
+# jit whose inputs arrive batch-sharded — the dp serving mesh,
+# serving/pipeline.py::dp_sharded_sampler — has to hand each device its
+# own rows explicitly. Everything around the kernels stays GSPMD's.
+_BATCH_SHARDED = contextvars.ContextVar(
+    "cassmantle_batch_sharded_kernels", default=None
 )
 
 
@@ -58,11 +73,32 @@ def context_parallel(mesh, axis_name: str = "sp",
         _CONTEXT_PARALLEL.reset(token)
 
 
-def _on_tpu() -> bool:
+@contextlib.contextmanager
+def batch_sharded_kernels(mesh, batch_axis: str = "dp"):
+    """Trace context for a jit whose batch is sharded over
+    ``mesh[batch_axis]``: every flash-kernel site traced inside runs
+    under ``shard_map`` on its local rows (attention never mixes batch
+    rows, so the result is the unsharded one). The batch at each site
+    must divide by the axis size."""
+    token = _BATCH_SHARDED.set((mesh, batch_axis))
     try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+        yield
+    finally:
+        _BATCH_SHARDED.reset(token)
+
+
+def _flash_per_batch_shard(kernel, q, k, v):
+    """``kernel(q, k, v)``, per batch shard where a
+    :func:`batch_sharded_kernels` region is active."""
+    ctx = _BATCH_SHARDED.get()
+    if ctx is None:
+        return kernel(q, k, v)
+    mesh, batch_axis = ctx
+    rows = P(batch_axis)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(rows, rows, rows), out_specs=rows,
+        check_vma=False,
+    )(q, k, v)
 
 
 def xla_attention(
@@ -87,8 +123,6 @@ def xla_attention(
     return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 
 
-# Pallas kernel lands in ops/flash_attention.py; until then this alias keeps
-# the dispatch seam stable.
 def multi_head_attention(
     q: jax.Array,
     k: jax.Array,
@@ -131,7 +165,7 @@ def multi_head_attention(
     if _FORCE_XLA.get():
         use_flash = False
     if use_flash is None:
-        use_flash = _on_tpu() and mask is None
+        use_flash = on_tpu() and mask is None
     if use_flash and mask is None:
         from cassmantle_tpu.ops.flash_attention import (
             flash_attention_ok,
@@ -142,7 +176,8 @@ def multi_head_attention(
         if flash_attention_ok(q, k):
             from cassmantle_tpu.ops.flash_attention import flash_attention
 
-            return flash_attention(q, k, v, scale=scale)
+            return _flash_per_batch_shard(
+                partial(flash_attention, scale=scale), q, k, v)
         if flash_wide_ok(q, k):
             # wide-head self-attention (the VAE mid block: single head
             # over H·W tokens at full channel width — S=16k, D=512 at
@@ -154,8 +189,9 @@ def multi_head_attention(
                 flash_attention,
             )
 
-            return flash_attention(q, k, v, scale=scale,
-                                   block_q=WIDE_BLOCK, block_k=WIDE_BLOCK)
+            return _flash_per_batch_shard(
+                partial(flash_attention, scale=scale,
+                        block_q=WIDE_BLOCK, block_k=WIDE_BLOCK), q, k, v)
         if flash_cross_ok(q, k):
             import os
 
@@ -172,5 +208,6 @@ def multi_head_attention(
                     flash_cross_attention,
                 )
 
-                return flash_cross_attention(q, k, v, scale=scale)
+                return _flash_per_batch_shard(
+                    partial(flash_cross_attention, scale=scale), q, k, v)
     return xla_attention(q, k, v, mask=mask, scale=scale)

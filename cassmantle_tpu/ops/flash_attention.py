@@ -30,14 +30,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever the installed version exports.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from cassmantle_tpu.ops.platform import on_tpu
 
-# 1024-blocks measured ~2x faster than 512 at the UNet's level-0 site
-# (S=4096, d=40, bh=64) on v5e: fewer grid programs amortize the per-
-# program MXU setup over more work. (1024, 40)-bf16 q/k/v tiles plus two
+# 1024-blocks: a round-1 builder's note put them ~2x faster than 512 at
+# the UNet's level-0 site (S=4096, d=40, bh=64) on v5e (unverified, no
+# ledger line): fewer grid programs amortize the per-program MXU setup
+# over more work. (1024, 40)-bf16 q/k/v tiles plus two
 # (1024, 1024)-fp32 intermediates stay well inside VMEM. Env-tunable so
 # a hardware window can sweep block sizes without an edit-reinstall
 # cycle (tools/profile_unet.py A/Bs per-resolution; each sweep point is
@@ -58,13 +56,6 @@ BLOCK_Q = _block_env("CASSMANTLE_FLASH_BLOCK_Q", 1024)
 BLOCK_K = _block_env("CASSMANTLE_FLASH_BLOCK_K", 1024)
 MAX_HEAD_DIM = 256
 _NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def flash_attention_ok(q: jax.Array, k: jax.Array) -> bool:
@@ -142,7 +133,7 @@ def _flash_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
                                block_k=block_k, kv_len=kv_len)
     # Only the k-block axis carries state (online-softmax scratch); the
     # batch*heads and q-block axes are embarrassingly parallel.
-    compiler_params = _CompilerParams(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
     flops = 2 * 2 * bh * sq * sk * d  # QK^T + PV
@@ -188,7 +179,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
 
     *batch, sq, h, d = q.shape
     sk = k.shape[-3]
@@ -262,7 +253,7 @@ def flash_cross_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
 
     *batch, sq, h, d = q.shape
     sk = k.shape[-3]

@@ -49,6 +49,8 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from cassmantle_tpu.ops.platform import host_cpu_device
+
 
 class QTensor(NamedTuple):
     """int8 data + broadcastable fp32 scale. A pytree by construction."""
@@ -123,8 +125,7 @@ def quantize_tree_host(
     keeps peak HBM at the int8 footprint. Quantizing after would hold the
     full fp tree and the int8 tree resident together, which is exactly
     what breaks a 7B-class model on a 16 GB chip."""
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
+    with jax.default_device(host_cpu_device()):
         return quantize_tree(params, predicate)
 
 
@@ -403,8 +404,7 @@ def w8a8_tree_host(params: Any,
                    dtype=jnp.int8) -> Any:
     """w8a8_tree pinned to host CPU — the loader-transform form (same
     peak-HBM argument as :func:`quantize_tree_host`)."""
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
+    with jax.default_device(host_cpu_device()):
         return w8a8_tree(params, act_scales, predicate, dtype)
 
 

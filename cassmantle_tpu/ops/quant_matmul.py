@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from cassmantle_tpu.ops.platform import on_tpu
 from cassmantle_tpu.ops.quant import (
     ActQTensor,
     act_absmax,
@@ -69,10 +70,7 @@ from cassmantle_tpu.ops.quant import (
     quantize_act,
 )
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-# Per-program VMEM budget (same conservative bar as ops/fused_conv.py).
+# Per-program VMEM budget, inside Mosaic's default 16 MiB scope.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 # int8 MXU tiling: 32 sublanes × 128 lanes is the minimum int8 tile, so
@@ -83,13 +81,6 @@ _LANE = 128
 _BLOCK_M = 128
 _BLOCK_N = 128
 _CONV_F_CANDIDATES = (256, 128, 64, 32)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def w8a8_disabled() -> bool:
@@ -172,7 +163,7 @@ def _matmul_padded(x_q, w_q, row_scale, col_scale, bias, out_dtype,
     mp, kp = x_q.shape
     np_ = w_q.shape[-1]
     grid = (mp // bm, np_ // bn)
-    flops = 2.0 * mp * kp * np_
+    flops = 2 * mp * kp * np_
     return pl.pallas_call(
         _matmul_kernel,
         grid=grid,
@@ -185,7 +176,7 @@ def _matmul_padded(x_q, w_q, row_scale, col_scale, bias, out_dtype,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -224,7 +215,7 @@ def int8_matmul(x_q, w_q, row_scale, col_scale, bias=None,
     int32 dot; pad rows/cols are sliced off).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     m, k = x_q.shape
     n = w_q.shape[-1]
     if bias is None:
@@ -299,7 +290,7 @@ def w8a8_dense(x, q: ActQTensor, bias=None, out_dtype=None,
         else:
             a_scale = q.act_scale
         x_q = quantize_act(x2, a_scale, qdtype)
-        compute = qdtype if _on_tpu() else jnp.float32
+        compute = qdtype if on_tpu() else jnp.float32
         acc = jax.lax.dot_general(
             x_q.astype(compute), q.data.astype(compute),
             (((1,), (0,)), ((), ())),
@@ -387,7 +378,7 @@ def _conv_padded(x_q, kernel, col_scale, bias, out_dtype,
     f = kernel.shape[-1]
     grid = (bsz, f // bf)
     kern = functools.partial(_conv_kernel, h=h, w=w)
-    flops = 2.0 * bsz * h * w * 9 * c * f
+    flops = 2 * bsz * h * w * 9 * c * f
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -400,7 +391,7 @@ def _conv_padded(x_q, kernel, col_scale, bias, out_dtype,
         out_specs=pl.BlockSpec((1, h, w, bf),
                                lambda bi, j: (bi, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, h, w, f), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -443,7 +434,7 @@ def int8_conv3x3(x_q, kernel, col_scale, bias, out_dtype=jnp.float32,
     activation scale × per-channel weight scale, pre-folded fp32
     (F,))."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     f = kernel.shape[-1]
     col = jnp.asarray(col_scale, jnp.float32).reshape(1, f)
     b = bias.astype(jnp.float32).reshape(1, f)
@@ -485,7 +476,7 @@ def gn_silu_conv3x3_w8a8(
                  * q.scale.reshape(f))
     if jnp.dtype(qdtype) != jnp.int8:   # fp8 leaf → XLA dot path
         h_q = quantize_act(h, a_scale, qdtype)
-        compute = qdtype if _on_tpu() else jnp.float32
+        compute = qdtype if on_tpu() else jnp.float32
         out = jax.lax.conv_general_dilated(
             h_q.astype(compute), q.data.astype(compute),
             window_strides=(1, 1), padding=((1, 1), (1, 1)),

@@ -27,30 +27,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from cassmantle_tpu.config import MeshConfig
 from cassmantle_tpu.utils.logging import get_logger
 
-# jax promoted shard_map out of jax.experimental across releases (and
-# renamed its replication-check kwarg check_rep -> check_vma); every
-# parallel module imports the resolved symbol from here so the whole
-# package works on either side of the move.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace + old kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(*args, check_vma=None, **kw):
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return _shard_map_compat(*args, **kw)
-
-
 def pcast_varying(x, axis_name: str):
-    """``jax.lax.pcast(x, axis, to="varying")`` where available — newer
-    jax's explicit constant->device-varying cast, needed to keep scan
-    carry types consistent under check_vma. Older jax has no pcast and
-    no varying-type tracking, so the cast is a no-op there."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis_name,), to="varying")
+    """Cast a constant to device-varying over ``axis_name``: keeps scan
+    carry types consistent under shard_map's check_vma."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")
+
 
 log = get_logger("mesh")
 

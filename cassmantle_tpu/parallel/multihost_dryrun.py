@@ -58,7 +58,6 @@ def _child() -> None:
         make_mesh,
         maybe_init_distributed,
         replicated,
-        shard_map,
     )
 
     assert maybe_init_distributed(), "coordinator env vars missing"
@@ -74,7 +73,7 @@ def _child() -> None:
     # 1) explicit collective across the cross-process dp axis
     ones = jax.make_array_from_process_local_data(
         batch_sharding(mesh), np.ones((local, 1), np.float32))
-    total = jax.jit(shard_map(
+    total = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(jnp.sum(x), "dp"),
         mesh=mesh, in_specs=P("dp"), out_specs=P()))(ones)
     assert float(total) == float(n_dev), float(total)
@@ -131,20 +130,16 @@ def run_multihost_dryrun(n_procs: int = 2, local_devices: int = 4,
     output (contains the OK marker)."""
     from cassmantle_tpu.utils.xla_flags import (
         COLLECTIVE_TIMEOUT_FLAGS,
-        _supported_optional_flags,
         virtual_device_flag,
     )
 
     port = _free_port()
     # children must NOT inherit the parent's XLA_FLAGS: a pre-existing
     # --xla_force_host_platform_device_count (e.g. conftest's 8) would
-    # win over ours by append_xla_flags' first-wins rule. The timeout
-    # flags go through the same supported-by-this-jaxlib probe as
-    # pin_cpu_platform — an unknown flag is FATAL in the children.
+    # win over ours by append_xla_flags' first-wins rule.
     base = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     flags = " ".join(
-        [virtual_device_flag(local_devices)]
-        + _supported_optional_flags(COLLECTIVE_TIMEOUT_FLAGS))
+        (virtual_device_flag(local_devices),) + COLLECTIVE_TIMEOUT_FLAGS)
     procs = []
     for pid in range(n_procs):
         env = dict(

@@ -26,8 +26,6 @@ from __future__ import annotations
 from typing import Callable, Sequence
 
 import jax
-
-from cassmantle_tpu.parallel.mesh import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -87,7 +85,7 @@ def pipeline_apply(
         (_, ys), _ = jax.lax.scan(tick, init, jnp.arange(M + S - 1))
         return ys[None]  # (1, M, mb, ...): stacked over pp outside
 
-    stacked = shard_map(
+    stacked = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(axis), P(*(None,) * xs.ndim)),

@@ -17,8 +17,6 @@ import functools
 from typing import Optional
 
 import jax
-
-from cassmantle_tpu.parallel.mesh import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -239,7 +237,7 @@ def zigzag_sharded_attention(
         _zigzag_local, axis_name=axis_name, scale=float(scale), n=n
     )
     spec = P(batch_axis, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )(q, k, v)
 
@@ -307,6 +305,6 @@ def ring_attention(
         causal=causal,
     )
     spec = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )(q, k, v)

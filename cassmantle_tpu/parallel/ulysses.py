@@ -22,8 +22,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from cassmantle_tpu.parallel.mesh import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -80,6 +78,6 @@ def ulysses_attention(
         causal=causal,
     )
     spec = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )(q, k, v)

@@ -1,7 +1,7 @@
 """Device-loss detection and serving-state rebuild (ISSUE 17, rung 3).
 
 A TPU runtime can die under a live server — preempted VM, wedged PCIe
-tunnel, driver crash. jax surfaces that as ``XlaRuntimeError`` (or a
+link, driver crash. jax surfaces that as ``XlaRuntimeError`` (or a
 transport error wrapping one) on the NEXT dispatch, and every buffer the
 process holds (params, staged-slot tensors, compiled-executable device
 state) is garbage from that point on. Without handling, each request

@@ -276,11 +276,7 @@ class SDXLPipeline:
                 eta=cfg.sampler.eta))
         # Params are jit ARGUMENTS (device buffers), not captured constants
         # (see Text2ImagePipeline note on compile payloads).
-        self._params = {
-            "clip": self.clip_params, "clip2": self.clip2_params,
-            "clip2_proj": self.clip2_proj,  # None -> empty pytree leaf
-            "unet": self.unet_params, "vae": self.vae_params,
-        }
+        self._publish_params()
 
         from cassmantle_tpu.serving.pipeline import dp_sharded_sampler
 
@@ -304,6 +300,22 @@ class SDXLPipeline:
         self._flops_lock = threading.Lock()
         self._flops_pending: set = set()
 
+    def _publish_params(self) -> None:
+        """See Text2ImagePipeline._publish_params: one tree for the
+        jits, replicated over the mesh when there is one."""
+        from cassmantle_tpu.serving.pipeline import replicate_on_mesh
+
+        self._params = replicate_on_mesh({
+            "clip": self.clip_params, "clip2": self.clip2_params,
+            "clip2_proj": self.clip2_proj,  # None -> empty pytree leaf
+            "unet": self.unet_params, "vae": self.vae_params,
+        }, self.mesh)
+        self.clip_params = self._params["clip"]
+        self.clip2_params = self._params["clip2"]
+        self.clip2_proj = self._params["clip2_proj"]
+        self.unet_params = self._params["unet"]
+        self.vae_params = self._params["vae"]
+
     def reload_params(self) -> None:
         """Device-loss rebuild (serving/device_recovery.py): re-run the
         boot load path and republish the tree (see
@@ -319,11 +331,7 @@ class SDXLPipeline:
             except Exception:
                 log.exception("staged server stop during reload failed")
         self._param_loader()
-        self._params = {
-            "clip": self.clip_params, "clip2": self.clip2_params,
-            "clip2_proj": self.clip2_proj,
-            "unet": self.unet_params, "vae": self.vae_params,
-        }
+        self._publish_params()
 
     # -- conditioning ------------------------------------------------------
 
@@ -335,7 +343,7 @@ class SDXLPipeline:
             [out1["penultimate"], out2["penultimate"]], axis=-1
         )
         pooled = out2["pooled"]
-        if self.clip2_proj is not None:  # static at trace time
+        if params["clip2_proj"] is not None:  # None leaf: static at trace
             pooled = pooled @ params["clip2_proj"]
         return context, pooled
 

@@ -856,10 +856,11 @@ class StagedImageServer:
                 metrics.inc("request.device_flops", unit_flops,
                             labels={"pipeline": "staged_denoise"})
                 service_s = now - u.t_admit
-                if service_s > 0:
+                peak = chip_peak_flops()
+                if service_s > 0 and peak is not None:
                     metrics.gauge(
                         "pipeline.mxu_utilization",
-                        unit_flops / service_s / chip_peak_flops(),
+                        unit_flops / service_s / peak,
                         labels={"pipeline": "staged_denoise"})
                 note_dispatch("staged_denoise")
             if u.ctx is not None and u.ctx.sampled:

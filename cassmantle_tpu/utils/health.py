@@ -3,9 +3,9 @@
 The reference's failure handling is per-call retry + skip-don't-crash
 (utils.py:43-61, backend.py:123-129); it has no health surface at all.
 Here the serving layer gets one: a tiny jitted probe computation runs on
-the default device with a wall-clock deadline (a wedged TPU tunnel or a
-dying chip makes device calls hang rather than raise — exactly the
-failure this detects), and the result is cached briefly so `/healthz`
+the default device with a wall-clock deadline (a wedged or dying chip
+makes device calls hang rather than raise — exactly the failure this
+detects), and the result is cached briefly so `/healthz`
 polling can't pile probes onto the device.
 """
 

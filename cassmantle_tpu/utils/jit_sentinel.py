@@ -98,6 +98,7 @@ _filters: list = []
 
 
 def _record_compile(name: str) -> None:
+    name = _normalize_fn_name(name)
     with _lock:
         n = _counts.get(name, 0) + 1
         _counts[name] = n
@@ -113,9 +114,10 @@ def _record_compile(name: str) -> None:
 
 
 def _normalize_fn_name(name: str) -> str:
-    """The elapsed-time record wraps the name as ``jit(<name>)`` where
-    the Compiling record uses the bare ``<name>`` — strip the wrapper
-    so both feeds key one per-function identity."""
+    """Both records name the function as ``jit(<name>)`` (jax 0.9.0;
+    the Compiling record used the bare ``<name>`` before): strip the
+    wrapper so counts, times and ``no_new_compiles(only=...)`` all key
+    the function's own ``__name__``."""
     if name.startswith("jit(") and name.endswith(")"):
         return name[4:-1]
     return name

@@ -54,11 +54,12 @@ def block_timer(name: str, *results, flops_est=None,
     dispatch — the prompt path's bucket grouping) plus a ``pipeline``
     label. The span then carries ``flops_est``/``mxu_utilization``
     attrs, ``request.device_flops`` accumulates the attributed FLOPs,
-    and ``pipeline.mxu_utilization{pipeline=}`` reports achieved-vs-
-    peak (flops / device-synchronized seconds / chip peak,
-    ``costmodel.chip_peak_flops``) — the "58% of ceiling" number, live
-    per dispatch. ``pipeline`` alone also marks a dispatch boundary for
-    the HBM highwater tracker (obs/device.py)."""
+    and, on a TPU, ``pipeline.mxu_utilization{pipeline=}`` reports
+    achieved-vs-peak (flops / device-synchronized seconds / the peak
+    ``costmodel.chip_peak_flops`` holds for this ``device_kind``; no
+    gauge off-TPU, an error for a TPU kind with no peak on record).
+    ``pipeline`` alone also marks a dispatch boundary for the HBM
+    highwater tracker (obs/device.py)."""
     from cassmantle_tpu.obs.trace import current_ctx, tracer
 
     sink: list = []
@@ -91,8 +92,9 @@ def block_timer(name: str, *results, flops_est=None,
             labels = {"pipeline": pipeline} if pipeline else None
             metrics.inc("request.device_flops", flops, labels=labels)
             attrs["flops_est"] = flops
-            if elapsed > 0:
-                mxu = flops / elapsed / chip_peak_flops()
+            peak = chip_peak_flops()
+            if elapsed > 0 and peak is not None:
+                mxu = flops / elapsed / peak
                 attrs["mxu_utilization"] = round(mxu, 6)
                 metrics.gauge("pipeline.mxu_utilization", mxu,
                               labels=labels)

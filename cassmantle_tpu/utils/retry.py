@@ -28,7 +28,7 @@ class RetryBudget:
     unbounded: under a persistent fault, every caller spends its full
     ``max_retries`` re-dialing the same dead thing, and the retry
     traffic itself becomes load (checkpoint re-reads in device
-    recovery, device dials behind a flaky tunnel). A shared budget
+    recovery, dials of a device that keeps failing). A shared budget
     makes the AGGREGATE bounded: each retry attempt spends a token,
     tokens refill at a fixed rate, and an empty bucket turns further
     retries into immediate give-ups (``retry.budget_exhausted``).

@@ -53,9 +53,9 @@ def test_missing_entry_flags():
 
 
 def test_fresh_error_over_measured_baseline_flags():
-    row = diff_entry("sd15", _entry(1.0), {"error": "tunnel died"})
+    row = diff_entry("sd15", _entry(1.0), {"error": "device died"})
     assert row["verdict"] == "error"
-    assert "tunnel died" in row["error"]
+    assert "device died" in row["error"]
 
 
 def test_pending_hardware_baseline_skipped():

@@ -57,6 +57,31 @@ def make_fabric(cfg, store=None, worker_id="worker-0", advertise=""):
                       heartbeat=False)
 
 
+@pytest.mark.asyncio
+async def test_shutdown_stops_the_serving_stack_after_the_rooms():
+    """A worker's one serving stack (the InferenceService queues and
+    their dispatch threads, server/app.py::build_fabric) is stopped by
+    RoomFabric.shutdown — after the rooms that feed it are drained,
+    before the store closes. It used to be left running at exit."""
+    cfg = make_cfg(num_rooms=1)
+    store = MemoryStore()
+    order = []
+
+    def factory(room, room_store):
+        return Game(cfg, room_store, FakeContentBackend(image_size=32),
+                    hash_embed, hash_similarity)
+
+    async def serving_stop():
+        order.append(("serving_stop", len(fabric._games)))
+
+    fabric = RoomFabric(cfg, store, factory, start_timers=False,
+                        heartbeat=False, serving_stop=serving_stop)
+    await fabric.startup()
+    assert len(fabric._games) == 1
+    await fabric.shutdown()
+    assert order == [("serving_stop", 0)]
+
+
 # -- directory ---------------------------------------------------------------
 
 def test_session_to_room_is_stable_and_process_independent():
@@ -428,6 +453,9 @@ async def test_replicated_store_close_lands_under_cancel_swallow():
                 raise
 
     rs._pump_task = asyncio.get_running_loop().create_task(stubborn_pump())
+    # let the stub reach its sleep: a task cancelled before its first
+    # step never runs its body, so there would be nothing to swallow
+    await asyncio.sleep(0)
     await asyncio.wait_for(rs.close(), timeout=5.0)
     assert swallowed[0] == 1
     assert rs._pump_task is None

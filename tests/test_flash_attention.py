@@ -190,3 +190,34 @@ def test_flash_cross_kill_switch(monkeypatch):
     assert calls, "switch '0' must mean enabled"
     np.testing.assert_allclose(np.asarray(on), np.asarray(off),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq_k", [BLOCK_Q, 77], ids=["self", "cross77"])
+def test_dispatch_per_batch_shard_matches_unsharded(seq_k):
+    """Inside ``batch_sharded_kernels`` (the dp serving mesh) each device
+    runs the kernel on its own batch rows through shard_map; attention
+    never mixes rows, so the result is the unsharded one. The kernels
+    run in interpret mode here; tests/test_tpu_compile.py asks the
+    chip's compiler for the same dispatch."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cassmantle_tpu.ops.attention import (
+        batch_sharded_kernels,
+        multi_head_attention,
+    )
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("dp",))
+    q, k, v = _rand_qkv(jax.random.PRNGKey(9), 4, BLOCK_Q, 2, 40,
+                        seq_k=seq_k)
+    rows = NamedSharding(mesh, P("dp"))
+
+    def attend(q, k, v):
+        with batch_sharded_kernels(mesh, "dp"):
+            return multi_head_attention(q, k, v, use_flash=True)
+
+    out = jax.jit(attend, out_shardings=rows)(
+        *(jax.device_put(t, rows) for t in (q, k, v)))
+    assert len(out.sharding.device_set) == 4
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(xla_attention(q, k, v)),
+        atol=2e-5, rtol=2e-5)

@@ -74,7 +74,10 @@ def test_hot_shapes_dispatch_to_kernel():
         (64, 64, 384, 384), (64, 64, 1024, 384),   # SD1.5 level 0 (+concat)
         (32, 32, 640, 640), (32, 32, 1024, 640),
         (16, 16, 1280, 1280), (8, 8, 2560, 1280),
-        (128, 128, 384, 384), (128, 128, 2560, 1280),  # SDXL-1024
+        # SDXL-1024: its widest skip-concats per level (960->320 at
+        # 128x128, 1920->640 at 64x64, 2560->1280 at 32x32)
+        (128, 128, 384, 384), (128, 128, 1024, 384),
+        (64, 64, 1920, 640), (32, 32, 2560, 1280),
     ]:
         # ShapeDtypeStructs: the gate is shape/dtype-only, no data needed
         x = jax.ShapeDtypeStruct((1, h, w, c), jnp.bfloat16)

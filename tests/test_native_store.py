@@ -237,7 +237,7 @@ async def test_snapshot_chunks_large_collections(tmp_path):
 
 @pytest.mark.asyncio
 async def test_lock_expired_in_hold_detected(store):
-    """The native client reports the same hazard taxonomy MemoryStore
+    """The native client reports the same hazard classification MemoryStore
     detects: a hold past its TTL that nobody reclaimed is an 'overrun'
     (UNLOCK :2); one another worker reacquired is 'expired_in_hold'
     (UNLOCK :0)."""

@@ -275,11 +275,60 @@ def test_cache_event_listener_mirrors_gauges():
     assert gauges["jit.cache_misses"] == after["misses"]
 
 
+# -- the chip's peak: one table keyed by device_kind, no default ------------
+
+PEAK_CASES = [
+    # (device_kind, peak bf16 FLOP/s or None = not in the table)
+    ("TPU v5 lite", 197e12),
+    ("TPU v9 imaginary", None),
+]
+
+
+@pytest.mark.parametrize("kind,peak", PEAK_CASES)
+def test_peak_table_is_keyed_by_device_kind(kind, peak):
+    """v5e's device_kind resolves to its published peak with the source
+    beside it; a kind the table does not hold is an error, never a
+    default (a 197 TFLOP/s guess for an unknown chip made every
+    utilization on it wrong without saying so)."""
+    if peak is None:
+        with pytest.raises(costmodel.UnknownDeviceKind, match=kind):
+            costmodel.peak_flops_for_kind(kind)
+        return
+    assert costmodel.peak_flops_for_kind(kind) == peak
+    assert "Google Cloud" in costmodel.CHIP_PEAKS[kind]["source"]
+
+
+def test_no_mxu_gauge_off_tpu():
+    """On the CPU there is no peak to divide by: an attributed dispatch
+    still counts its FLOPs but renders no utilization gauge or span
+    attr (a CPU ratio under a device metric's name is a wrong number,
+    not a small one)."""
+    from cassmantle_tpu.utils.profiling import block_timer
+
+    assert costmodel.chip_peak_flops() is None
+    before = metrics.counter_total("request.device_flops")
+    with block_timer("test.cpu_dispatch_s", flops_est=1e9,
+                     pipeline="cpu_only"):
+        pass
+    assert metrics.counter_total("request.device_flops") == before + 1e9
+    assert _pipeline_gauge("pipeline.mxu_utilization", "cpu_only") is None
+
+
 # -- roofline attribution: the warmed serving smoke (acceptance) ------------
 
 @pytest.fixture(scope="module")
 def tiny_cfg():
     return _tiny_config()
+
+
+@pytest.fixture
+def v5e_peak(monkeypatch):
+    """Steer the gauge's peak lookup to the v5e row, so the attribution
+    arithmetic below runs on the CPU host (which has no peak, and so
+    no gauge, of its own)."""
+    monkeypatch.setattr(
+        costmodel, "chip_peak_flops",
+        lambda: costmodel.peak_flops_for_kind("TPU v5 lite"))
 
 
 def _pipeline_gauge(name, pipeline):
@@ -294,7 +343,7 @@ def _spans_named(trace_id, name):
             if s["name"] == name]
 
 
-def test_t2i_dispatch_carries_flops_and_mxu(tiny_cfg):
+def test_t2i_dispatch_carries_flops_and_mxu(tiny_cfg, v5e_peak):
     """The acceptance smoke, image path: a warmed generate produces a
     stage span carrying flops_est attrs, a nonzero
     pipeline.mxu_utilization{pipeline=t2i} gauge, and a
@@ -375,7 +424,7 @@ def test_tier_variant_flops_resolve_in_background(tiny_cfg):
     assert got is not None and got > 0
 
 
-def test_prompt_dispatch_carries_flops(tiny_cfg):
+def test_prompt_dispatch_carries_flops(tiny_cfg, v5e_peak):
     from cassmantle_tpu.obs.trace import tracer
     from cassmantle_tpu.serving.pipeline import PromptGenerator
 
@@ -392,7 +441,7 @@ def test_prompt_dispatch_carries_flops(tiny_cfg):
     assert mxu is not None and mxu > 0
 
 
-def test_scorer_dispatch_carries_flops(tiny_cfg):
+def test_scorer_dispatch_carries_flops(tiny_cfg, v5e_peak):
     from cassmantle_tpu.obs.trace import tracer
     from cassmantle_tpu.ops.scorer import EmbeddingScorer
 

@@ -8,7 +8,7 @@ backends compute IDENTICAL results for the same command script. One
 table-driven script runs against each backend and the full result
 traces are compared: strings/TTL, hashes (incl. strtoll-lenient
 HINCRBY), sets, wrong-type read/write discipline, and the lock verbs
-with the ``:2`` overrun and tombstone-grace hazard taxonomy.
+with the ``:2`` overrun and tombstone-grace hazard classification.
 
 Divergences this matrix found (fixed in this round, pinned here):
 

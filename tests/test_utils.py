@@ -1,4 +1,10 @@
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
+import pytest
 
 from cassmantle_tpu.utils.codec import decode_jpeg, encode_jpeg, image_to_base64
 from cassmantle_tpu.utils.text import (
@@ -135,3 +141,75 @@ def test_retry_give_up_on_aborts_immediately():
 
     asyncio.run(run())
     assert len(calls) == 1
+
+
+# -- compile cache placement (utils/compile_cache.py) -----------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# run in a fresh process: placement is decided once per process, and
+# jax reads JAX_COMPILATION_CACHE_DIR at import
+_CACHE_PROBE = """
+import json, sys
+sys.path.insert(0, {repo!r})
+import jax
+set_in_code = []
+real_update = jax.config.update
+def recording_update(name, value):
+    if name == "jax_compilation_cache_dir":
+        set_in_code.append(value)
+    return real_update(name, value)
+jax.config.update = recording_update
+from cassmantle_tpu.utils.compile_cache import enable_compile_cache
+enable_compile_cache()
+print(json.dumps({{"set_in_code": set_in_code,
+                   "dir": jax.config.jax_compilation_cache_dir}}))
+"""
+
+
+@pytest.fixture(scope="module")
+def cache_placement(tmp_path_factory):
+    """{case: what a fresh process placed}, the three processes started
+    together: the variable set; unset from a foreign cwd; unset from
+    the checkout."""
+    outside = str(tmp_path_factory.mktemp("outside_cache"))
+    cases = {"env_set": (outside, REPO),
+             "unset_elsewhere": (None, str(tmp_path_factory.mktemp("cwd"))),
+             "unset_in_checkout": (None, REPO)}
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    procs = {}
+    for name, (env_dir, cwd) in cases.items():
+        env = dict(base) if env_dir is None else dict(
+            base, JAX_COMPILATION_CACHE_DIR=env_dir)
+        procs[name] = subprocess.Popen(
+            [sys.executable, "-c", _CACHE_PROBE.format(repo=REPO)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=env, cwd=cwd)
+    seen = {"outside": outside}
+    for name, proc in procs.items():
+        out, err = proc.communicate(timeout=120)
+        assert proc.returncode == 0, err[-2000:]
+        seen[name] = json.loads(out.splitlines()[-1])
+    return seen
+
+
+def test_compile_cache_dir_comes_from_the_environment(cache_placement):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself and NO code
+    sets a directory over it (the chip tool keeps a repo's compiles
+    from one call to the next only in the directory it names)."""
+    assert cache_placement["env_set"] == {
+        "set_in_code": [], "dir": cache_placement["outside"]}
+
+
+def test_compile_cache_dir_defaults_to_the_checkout(cache_placement):
+    want = os.path.join(REPO, ".jax_cache")
+    assert cache_placement["unset_elsewhere"] == {
+        "set_in_code": [want], "dir": want}
+
+
+def test_compile_cache_dir_is_the_same_in_every_process(cache_placement):
+    """The path is part of what a later process must agree on to hit:
+    it may not depend on the cwd (or anything else a process varies)."""
+    assert cache_placement["unset_elsewhere"]["dir"] == \
+        cache_placement["unset_in_checkout"]["dir"]

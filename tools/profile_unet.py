@@ -12,6 +12,7 @@ the TPU actually runs) to UNET_HLO.txt at the repo root.
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 
@@ -146,15 +147,22 @@ def vae_decode_cost(vae_cfg, image_size: int, batch: int):
     return total / batch, attn / batch, s_tokens
 
 
+# The analytic ceilings this tool prints, and the committed cost model's
+# ``chip_tflops``, are for one v5e chip: its row of the peak table.
+ANALYTIC_DEVICE_KIND = "TPU v5 lite"
+
+
 def print_encprop_accounting(encoder, decoder, total, vae_tf, vae_attn,
-                             s_tokens, sampler_cfg, chip_tflops=197e12):
+                             s_tokens, sampler_cfg):
     """The encprop analytic bound, from the same numbers the per-image
     TF figure came from: full forwards at the key steps of the
     configured schedule, decoder-only forwards elsewhere (CFG doubles
     both), plus the VAE decode — the PERF_NOTES 'Encoder propagation
     accounting' model."""
+    from cassmantle_tpu.obs.costmodel import peak_flops_for_kind
     from cassmantle_tpu.ops.ddim import encprop_key_indices
 
+    chip_tflops = peak_flops_for_kind(ANALYTIC_DEVICE_KIND)
     n = sampler_cfg.num_steps
     keys = len(encprop_key_indices(n, sampler_cfg.encprop_stride,
                                    sampler_cfg.encprop_dense_steps))
@@ -359,14 +367,9 @@ def _scorer_cost_entry(cfg, seq_len: int = 16) -> dict:
     }
 
 
-def emit_cost_model(path: str) -> dict:
-    """``--emit-cost-model``: write the machine-readable analytic cost
-    model (FLOPs + HBM-bytes proxy per pipeline/stage/bucket for the
-    PRODUCTION configs) the serving pipelines load at dispatch time
-    (obs/costmodel.py). Everything is shape-derived under eval_shape —
-    deterministic integers, no weights, runs on any backend in seconds —
-    so the committed ``data/cost_model.json`` doubles as a drift gate
-    (tests/test_obs_device.py regenerates and compares)."""
+def _cost_model_configs() -> dict:
+    """{entry name: (entry builder, config)} for the committed cost
+    model: the production configs and their preset variants."""
     import dataclasses
 
     from cassmantle_tpu.config import (
@@ -375,7 +378,6 @@ def emit_cost_model(path: str) -> dict:
         sdxl_config,
         w8a8_serving_config,
     )
-    from cassmantle_tpu.obs import costmodel
 
     # the SDXL W8A8 arm: production SDXL geometry with the quantized
     # UNet path armed (same knobs w8a8_serving_config sets for SD1.5)
@@ -386,34 +388,76 @@ def emit_cost_model(path: str) -> dict:
             unet=dataclasses.replace(sdxl_base.models.unet,
                                      fused_conv=True, conv_pad_to=128),
             unet_w8a8=True))
+    t2i = functools.partial(_image_cost_entry, "t2i")
+    sdxl = functools.partial(_image_cost_entry, "sdxl")
+    return {
+        "t2i": (t2i, FrameworkConfig()),
+        # the few-step consistency preset: same pipeline kind, the
+        # committed 4-step geometry (resolved by signature scan —
+        # obs/costmodel.py::committed_entry)
+        "t2i_lcm": (t2i, lcm_serving_config()),
+        "sdxl": (sdxl, sdxl_base),
+        "prompt": (_lm_cost_entry, FrameworkConfig()),
+        "scorer": (_scorer_cost_entry, FrameworkConfig()),
+        # W8A8 serving variants (ISSUE 20): same analytic FLOPs,
+        # weight-side HBM bytes halved at quantized sites — their
+        # signatures differ (the armed w8a8 state digests in), so
+        # quantized pipelines resolve these entries by scan
+        "t2i_w8a8": (t2i, w8a8_serving_config()),
+        "sdxl_w8a8": (sdxl, sdxl_w8a8),
+        "prompt_w8a8": (_lm_cost_entry, w8a8_serving_config()),
+    }
+
+
+def _cost_model_entry(name: str) -> dict:
+    builder, cfg = _cost_model_configs()[name]
+    return builder(cfg)
+
+
+def emit_cost_model(path: str) -> dict:
+    """``--emit-cost-model``: write the machine-readable analytic cost
+    model (FLOPs + HBM-bytes proxy per pipeline/stage/bucket for the
+    PRODUCTION configs) the serving pipelines load at dispatch time
+    (obs/costmodel.py). Everything is shape-derived under eval_shape —
+    deterministic integers, no weights, runs on any backend —
+    so the committed ``data/cost_model.json`` doubles as a drift gate
+    (tests/test_obs_device.py regenerates and compares).
+
+    The entries are traced in worker processes, side by side: tracing
+    five UNet pipelines is ~50 s of pure Python in one process, the
+    longest single test of a tier-1 window that is tight. Workers are
+    spawned onto the CPU backend (an accelerator belongs to the parent,
+    and shapes need none)."""
+    import json
+    import multiprocessing
+    from concurrent.futures import ProcessPoolExecutor
+
+    from cassmantle_tpu.obs import costmodel
+
+    names = list(_cost_model_configs())
+    parent_platforms = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"   # inherited at spawn
+    try:
+        with ProcessPoolExecutor(
+                max_workers=min(len(names), os.cpu_count() or 1),
+                mp_context=multiprocessing.get_context("spawn")) as pool:
+            pipelines = dict(zip(names, pool.map(_cost_model_entry, names)))
+    finally:
+        if parent_platforms is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = parent_platforms
     model = {
         "version": 1,
         "generated_by": "python tools/profile_unet.py --emit-cost-model",
-        "chip_tflops": costmodel.DEFAULT_CHIP_TFLOPS,
+        "chip_tflops": costmodel.peak_flops_for_kind(
+            ANALYTIC_DEVICE_KIND) / 1e12,
         "note": ("analytic dot/conv FLOPs (obs/costmodel.py trace_cost; "
                  "same math as --cost-table); hbm_bytes is a roofline "
                  "proxy (operand+result buffer bytes, fusion ignored — "
                  "an upper bound on true traffic)"),
-        "pipelines": {
-            "t2i": _image_cost_entry("t2i", FrameworkConfig()),
-            # the few-step consistency preset: same pipeline kind, the
-            # committed 4-step geometry (resolved by signature scan —
-            # obs/costmodel.py::committed_entry)
-            "t2i_lcm": _image_cost_entry("t2i", lcm_serving_config()),
-            "sdxl": _image_cost_entry("sdxl", sdxl_config()),
-            "prompt": _lm_cost_entry(FrameworkConfig()),
-            "scorer": _scorer_cost_entry(FrameworkConfig()),
-            # W8A8 serving variants (ISSUE 20): same analytic FLOPs,
-            # weight-side HBM bytes halved at quantized sites — their
-            # signatures differ (the armed w8a8 state digests in), so
-            # quantized pipelines resolve these entries by scan
-            "t2i_w8a8": _image_cost_entry("t2i", w8a8_serving_config()),
-            "sdxl_w8a8": _image_cost_entry("sdxl", sdxl_w8a8),
-            "prompt_w8a8": _lm_cost_entry(w8a8_serving_config()),
-        },
+        "pipelines": pipelines,
     }
-    import json
-
     with open(path, "w") as f:
         json.dump(model, f, indent=1, sort_keys=True)
         f.write("\n")
