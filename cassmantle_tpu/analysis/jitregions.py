@@ -10,7 +10,8 @@ invoked* without re-implementing the discovery.
 Recognized jit shapes (the ones the repo actually uses):
 
 - decorated: ``@jax.jit``, ``@jax.jit(...)``,
-  ``@partial(jax.jit, static_argnums=..., static_argnames=...)``;
+  ``@partial(jax.jit, static_argnums=..., static_argnames=...)``
+  (``named_jit`` reads as ``jax.jit`` throughout);
 - passed: ``jax.jit(f, ...)``, ``jax.jit(self.m, ...)``,
   ``jax.jit(partial(self.m, k), ...)`` — partial-bound leading
   positionals are treated as static (they key the jit cache);
@@ -30,7 +31,9 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from cassmantle_tpu.analysis.core import call_name, dotted_name
 
-JIT_NAMES = {"jax.jit", "jit"}
+# named_jit(f, "name", **jit_kwargs) is jax.jit under a fixed program
+# name (utils/profiling.py): same target position, same static kwargs
+JIT_NAMES = {"jax.jit", "jit", "named_jit"}
 JIT_WRAPPERS = {"dp_sharded_sampler"}
 PARTIAL_NAMES = {"partial", "functools.partial"}
 

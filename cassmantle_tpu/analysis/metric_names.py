@@ -94,24 +94,34 @@ def extract_sites(source: str, path: str) -> List[Tuple[str, str, int]]:
     receiver (the ``metrics`` global, ``self._registry``, …) plus
     ``block_timer(...)`` (utils/profiling.py's metric-emitting stage
     timer, linted as an ``observe`` so device-stage names can't drift
-    off the catalog)."""
+    off the catalog) and the two spellings of a timed host region,
+    which name the SPAN and observe the histogram ``<span>_s``:
+    ``host_span("a.b")`` and ``OrderedLock(..., wait_span="a.b")``."""
     sites = []
     tree = ast.parse(source, filename=path)
     for node in ast.walk(tree):
-        if not (isinstance(node, ast.Call) and node.args):
+        if not isinstance(node, ast.Call):
             continue
+        for kw in node.keywords:
+            span = _literal_name(kw.value) if kw.arg == "wait_span" else None
+            if span is not None:
+                sites.append((span + "_s", "observe", node.lineno))
+        if not node.args:
+            continue
+        suffix = ""
         if (isinstance(node.func, ast.Attribute)
                 and node.func.attr in _METHODS
                 and _is_registry_receiver(node.func.value)):
             method = node.func.attr
         elif (isinstance(node.func, ast.Name)
-                and node.func.id == "block_timer"):
+                and node.func.id in ("block_timer", "host_span")):
             method = "observe"
+            suffix = "_s" if node.func.id == "host_span" else ""
         else:
             continue
         name = _literal_name(node.args[0])
         if name is not None:
-            sites.append((name, method, node.lineno))
+            sites.append((name + suffix, method, node.lineno))
     return sites
 
 

@@ -15,7 +15,12 @@ PAPERS.md). This module is the minimal native tracer that answers it:
   (serving/queue.py);
 - device stages record **device-synchronized** spans through
   ``utils.profiling.block_timer`` (the timing blocks on the stage's
-  result arrays, so spans measure device work, not dispatch).
+  result arrays, so spans measure device work, not dispatch);
+- every span carries ``start_ns`` on the profiler's clock, and every
+  context-managed one (``Tracer.span``, ``block_timer``, ``host_span``)
+  is also a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  profiler session shows the program's spans beside the device's
+  operations.
 
 Finished spans land in a bounded per-trace ring (LRU eviction at
 ``capacity`` traces) queryable at ``/debugz?trace=<id>``.
@@ -288,6 +293,13 @@ class Tracer:
             "parent_id": parent_id,
             "name": name,
             "start_ts": start_wall,
+            # the same instant in whole nanoseconds: ``time.time`` is
+            # the realtime clock the profiler stamps host events with
+            # (an .xplane.pb holds offsets from its session's
+            # ``profile_start_time``), so a span measured after the
+            # fact (queue wait) lays over a device trace like the
+            # annotated ones (tests/test_profiler_clock.py pins it)
+            "start_ns": round(start_wall * 1e9),
             "duration_s": duration_s,
             "status": status,
         }
@@ -440,13 +452,19 @@ class Tracer:
             pctx = _current.get()
             ctx = self.child_ctx(pctx)
             parent_id = pctx.span_id if pctx is not None else None
+        # lazy: utils.profiling imports jax, this module's importers
+        # (the fake server, the analysis tools) need not
+        from cassmantle_tpu.utils.profiling import host_region
+
         handle = _SpanHandle(ctx, dict(attrs) if attrs else {})
         token = _current.set(ctx)
         start_wall = time.time()
         start = time.perf_counter()
         status = "ok"
         try:
-            yield handle
+            # the same region in a profiler session's host lines
+            with host_region(name):
+                yield handle
         except BaseException:
             status = "error"
             raise

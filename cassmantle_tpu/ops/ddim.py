@@ -107,6 +107,7 @@ def ddim_sample_deepcache(
     def pack(a):
         return a.reshape(n // 2, 2)
 
+    @jax.named_scope("denoise_step")
     def pair_step(x, per):
         t, a_t, a_prev = per
         eps, deep = denoise_full(x, t[0])
@@ -275,6 +276,7 @@ def encprop_sample(
 
     carry = spec["init"](latents)
     if dense:
+        @jax.named_scope("denoise_step")
         def dense_body(c, per):
             t, coefs_i = per[0], per[1:]
             c, _, _ = key_step(c, t, coefs_i)
@@ -288,6 +290,7 @@ def encprop_sample(
         def pack(a):
             return a[dense:stop].reshape(nseg, stride)
 
+        @jax.named_scope("denoise_step")
         def seg_body(c, per):
             seg_ts, seg_coefs = per[0], per[1:]
             return segment(c, seg_ts, seg_coefs), None
@@ -352,6 +355,7 @@ def ddim_sample(
         raise ValueError("eta > 0 requires an rng key")
     noise_rng = rng if rng is not None else jax.random.PRNGKey(0)
 
+    @jax.named_scope("denoise_step")
     def step(carry, per_step):
         x, key = carry
         t, a_t, a_prev = per_step

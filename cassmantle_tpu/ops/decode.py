@@ -17,7 +17,7 @@ from typing import Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from cassmantle_tpu.utils.profiling import annotate
+from cassmantle_tpu.utils.profiling import named_jit
 
 
 def make_apply_fns(model):
@@ -48,7 +48,7 @@ def make_apply_pair(model):
     return make_apply_fns(model)[:2]
 
 
-@partial(jax.jit, static_argnums=(0, 5, 6, 7, 8))
+@partial(named_jit, name="lm_decode", static_argnums=(0, 5, 6, 7, 8))
 def greedy_decode(
     model_apply_pair,          # (prefill_fn, decode_step_fn), static; both
                                # take ``params`` first so weights enter the
@@ -74,7 +74,9 @@ def greedy_decode(
     b, p = input_ids.shape
     max_len = p + max_new_tokens
 
-    last_logits, cache = prefill_fn(params, input_ids, prompt_len, max_len)
+    with jax.named_scope("lm_prefill"):
+        last_logits, cache = prefill_fn(params, input_ids, prompt_len,
+                                        max_len)
 
     positions = jnp.arange(max_len)[None, :]          # (1, L)
     prompt_valid = positions < prompt_len[:, None]     # (B, L)
@@ -90,6 +92,7 @@ def greedy_decode(
         return jnp.take_along_axis(
             k_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
 
+    @jax.named_scope("lm_decode_step")
     def step(carry, i):
         logits, cache, done = carry
         token = pick(logits, i)
@@ -285,7 +288,7 @@ def speculative_decode(
 
         # -- draft: gamma proposals continuing after y_first -----------
         if is_model_draft:
-            with annotate("spec_draft"):
+            with jax.named_scope("spec_draft"):
                 # cache-sync step: the previous chunk committed through
                 # position idx-1, but the draft's own scan last wrote
                 # kv for ITS tokens — on a rejection the slot at the
@@ -322,7 +325,7 @@ def speculative_decode(
         chunk_toks = jnp.concatenate([y_first[:, None], drafts], axis=1)
         valid = prompt_valid | (
             (positions >= p) & (positions <= idx + gamma))
-        with annotate("spec_verify"):
+        with jax.named_scope("spec_verify"):
             logits, new_cache = chunk_fn(params, chunk_toks, idx, cache,
                                          valid)
         preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, g1)

@@ -159,6 +159,9 @@ def _flash_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
             transcendentals=bh * sq * sk,
         ),
         interpret=interpret,
+        # what a device trace calls the kernel (the HLO instruction and
+        # a scope of its op_name), whatever the wrapper is named
+        name="flash_attention",
     )(q, k, v)
 
 

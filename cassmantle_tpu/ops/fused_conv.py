@@ -317,6 +317,7 @@ def _fused_bhwc(x, a, b, kernel, bias, interpret: bool,
             transcendentals=bsz * h * w * c,  # the sigmoid
         ),
         interpret=interpret,
+        name="fused_conv3x3",
     )(x, a[:, None, :], b[:, None, :], kernel, bias)
 
 

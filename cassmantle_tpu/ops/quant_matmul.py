@@ -186,6 +186,7 @@ def _matmul_padded(x_q, w_q, row_scale, col_scale, bias, out_dtype,
             transcendentals=0,
         ),
         interpret=interpret,
+        name="int8_matmul",
     )(x_q, w_q, row_scale, col_scale, bias)
 
 
@@ -401,6 +402,7 @@ def _conv_padded(x_q, kernel, col_scale, bias, out_dtype,
             transcendentals=0,
         ),
         interpret=interpret,
+        name="int8_conv3x3",
     )(x_q, kernel, col_scale, bias)
 
 

@@ -157,6 +157,7 @@ def consistency_sample(
     consistency_renoise). The final step's ᾱ_next is 1.0, so its
     update reduces exactly to the x0 estimate."""
 
+    @jax.named_scope("denoise_step")
     def step(x, per):
         t, ab, ab_next, c_skip, c_out = per
         eps = denoise(x, t)
@@ -222,6 +223,7 @@ def euler_sample(
     """
     x = latents if prescaled else latents * schedule.sigmas[0]
 
+    @jax.named_scope("denoise_step")
     def step(x, per_step):
         t, sigma, sigma_next = per_step
         x_vp = x / jnp.sqrt(1.0 + sigma * sigma)
@@ -306,6 +308,7 @@ def dpmpp_2m_sample(
     ``latents`` standard normal; x stays in VP space throughout.
     """
 
+    @jax.named_scope("denoise_step")
     def step(carry, per_step):
         x, m1 = carry
         t, alpha, sigma, c_skip, c_d0, c_d1 = per_step
@@ -351,6 +354,7 @@ def dpmpp_2m_sample_deepcache(
         x = c_skip * x + c_d0 * m0 + c_d1 * m1
         return x, m0
 
+    @jax.named_scope("denoise_step")
     def pair_step(carry, per):
         x, m1 = carry
         t, alpha, sigma, c_skip, c_d0, c_d1 = per

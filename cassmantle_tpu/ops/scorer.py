@@ -42,7 +42,7 @@ from cassmantle_tpu.utils.compile_cache import (
     param_cache_path,
 )
 from cassmantle_tpu.utils.logging import get_logger, metrics
-from cassmantle_tpu.utils.profiling import block_timer
+from cassmantle_tpu.utils.profiling import block_timer, named_jit
 from cassmantle_tpu.utils.tokenizers import Tokenizer, load_tokenizer
 
 log = get_logger("scorer")
@@ -107,10 +107,11 @@ class EmbeddingScorer:
         # embeddings — no extra dispatch or sync
 
         def encode_impl(params, ids, mask):
-            emb = model.apply(params, ids, mask)
+            with jax.named_scope("scorer_encode"):
+                emb = model.apply(params, ids, mask)
             return emb, finite_verdict(emb)
 
-        self._encode = jax.jit(encode_impl)
+        self._encode = named_jit(encode_impl, "scorer_encode")
         # roofline attribution (obs/costmodel.py): an encoder forward
         # costs ~2·N(params) FLOPs per token; resolved lazily from the
         # committed cost model (production MiniLM) or this tree

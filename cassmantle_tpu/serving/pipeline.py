@@ -50,14 +50,20 @@ from cassmantle_tpu.ops.decode import greedy_decode
 from cassmantle_tpu.serving import integrity
 from cassmantle_tpu.utils.locks import OrderedLock
 from cassmantle_tpu.utils.logging import get_logger, metrics
-from cassmantle_tpu.utils.profiling import annotate, block_timer
+from cassmantle_tpu.utils.profiling import (
+    block_timer,
+    host_span,
+    named_jit,
+)
 from cassmantle_tpu.utils.tokenizers import load_tokenizer
 
 log = get_logger("pipeline")
 
 
-def dp_sharded_sampler(sample_impl, mesh):
-    """Jit a ``(params, ids, uncond_ids, rng)`` sampler for the mesh.
+def dp_sharded_sampler(sample_impl, mesh, name: str):
+    """Jit a ``(params, ids, uncond_ids, rng)`` sampler for the mesh,
+    as the program ``name`` (``t2i_sample`` / ``sdxl_sample``: what a
+    device trace's ``XLA Modules`` line and every op's scope path say).
 
     Returns ``(jitted_fn, dp)``: with a mesh, token ids arrive sharded
     over the required ``dp`` axis and params replicate (GSPMD inserts
@@ -68,7 +74,7 @@ def dp_sharded_sampler(sample_impl, mesh):
     SDXL pipelines so the sharding/padding contract lives in one place.
     """
     if mesh is None:
-        return jax.jit(sample_impl), 1
+        return named_jit(sample_impl, name), 1
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from cassmantle_tpu.ops.attention import batch_sharded_kernels
@@ -80,8 +86,8 @@ def dp_sharded_sampler(sample_impl, mesh):
 
     batch = NamedSharding(mesh, P("dp"))
     repl = NamedSharding(mesh, P())
-    fn = jax.jit(
-        sharded_impl,
+    fn = named_jit(
+        sharded_impl, name,
         in_shardings=(repl, batch, batch, repl),
         out_shardings=batch,
     )
@@ -428,14 +434,15 @@ def note_encprop_counters(counts, n_images: int) -> None:
 
 
 def degraded_dispatch_variant(cache: dict, sampler_cfg, mesh,
-                              build_impl, log_):
+                              build_impl, log_, name: str):
     """Shared brownout-variant machinery for BOTH image pipelines
     (serving/overload.py, ISSUE 13): resolve the active tier into a
     degraded SamplerConfig, build that delta's sampler + schedules +
     jitted dispatch ONCE (cached by the (steps, stride, size) key — a
     tier change never recompiles in steady state), and fall back to
     full quality on any build failure. ``build_impl(scfg, sampler,
-    dc_schedule)`` returns the pipeline-specific sample impl; returns
+    dc_schedule)`` returns the pipeline-specific sample impl, jitted
+    under the pipeline's program ``name``; returns
     ``(sample_fn, scfg, encprop_counts)`` or None (tier 0 / no-op
     delta / unusable delta)."""
     from cassmantle_tpu.serving import overload
@@ -468,7 +475,7 @@ def degraded_dispatch_variant(cache: dict, sampler_cfg, mesh,
                        else make_sampler(scfg.kind, scfg.num_steps,
                                          eta=scfg.eta))
             fn, _ = dp_sharded_sampler(build_impl(scfg, sampler, dc),
-                                       mesh)
+                                       mesh, name)
             entry = (fn, scfg, counts)
             cache[key] = entry
         return entry
@@ -480,6 +487,11 @@ def degraded_dispatch_variant(cache: dict, sampler_cfg, mesh,
         log_.exception("brownout tier delta unusable for this config; "
                        "serving full quality")
         return None
+
+
+# pipeline.image_batch_size bounds: rows callers asked for per image
+# dispatch (1 a round today; cross-room batching, ROADMAP A5, moves it)
+IMAGE_BATCH_BUCKETS = (1, 2, 4, 8, 16, 32)
 
 
 def pad_prompts_to_dp(prompts: Sequence[str], dp: int):
@@ -713,7 +725,8 @@ class Text2ImagePipeline:
         # captured constants — capturing bakes ~4 GB of weights into the
         # HLO, blowing up compile payloads and compile-cache keys.
         self._publish_params()
-        self._sample, self.dp = dp_sharded_sampler(self._sample_impl, mesh)
+        self._sample, self.dp = dp_sharded_sampler(
+            self._sample_impl, mesh, "t2i_sample")
         # brownout actuation (serving/overload.py, ISSUE 13): degraded
         # sampler variants keyed by their (steps, stride, size) delta —
         # each TIER compiles once on first engagement and is reused
@@ -737,7 +750,9 @@ class Text2ImagePipeline:
         # deadlocked the CPU backend under some jaxlib builds).
         # Outermost hierarchy tier (docs/STATIC_ANALYSIS.md): held for
         # whole device dispatches, so nothing coarser may nest inside.
-        self._dispatch_lock = OrderedLock("pipeline.t2i_dispatch", rank=10)
+        self._dispatch_lock = OrderedLock(
+            "pipeline.t2i_dispatch", rank=10,
+            wait_span="pipeline.image_lock_wait")
         # stage-disaggregated serving (serving/stages.py): built lazily
         # on the first staged generate; the supervisor is wired by
         # InferenceService so per-stage watchdog health fuses into
@@ -850,20 +865,9 @@ class Text2ImagePipeline:
         return self._staged
 
     def _sample_impl(self, params, ids, uncond_ids, rng):
-        with annotate("clip_encode"):
-            ctx = self.clip.apply(params["clip"], ids)["hidden"]
-            uncond = self.clip.apply(params["clip"], uncond_ids)["hidden"]
-        lat = initial_latents(rng, ids.shape[0], self.cfg.sampler.image_size,
-                              self.vae_scale)
-        lat = spatially_shard_latents(lat, self.mesh)
-        with annotate("denoise_scan"):
-            final = run_cfg_denoise(
-                self.cfg.sampler, self.sample_latents, self._dc_schedule,
-                self.unet_apply, params["unet"], ctx, uncond, lat,
-            )
-        with annotate("vae_decode"):
-            decoded = self.vae.apply(params["vae"], final)
-        return postprocess_images(decoded)
+        return self._build_tier_impl(
+            self.cfg.sampler, self.sample_latents, self._dc_schedule)(
+                params, ids, uncond_ids, rng)
 
     def _tokenize(self, prompts: Sequence[str]) -> np.ndarray:
         return tokenize_clip_prompts(
@@ -874,23 +878,27 @@ class Text2ImagePipeline:
     # -- brownout actuation (serving/overload.py, ISSUE 13) ----------------
 
     def _build_tier_impl(self, scfg, sampler, dc):
-        """The SD1.5 sample impl bound to a degraded tier's config —
-        ``_sample_impl`` with (steps, stride, size) swapped."""
+        """The SD1.5 sample impl bound to a sampler config: the
+        pipeline's own (``_sample_impl``) or a degraded tier's, with
+        (steps, stride, size) swapped. The stage scopes are op
+        metadata: a device trace puts every operation under
+        ``clip_encode``, ``denoise_scan/denoise_step`` or
+        ``vae_decode``, then the Flax module path."""
 
         def impl(params, ids, uncond_ids, rng):
-            with annotate("clip_encode"):
+            with jax.named_scope("clip_encode"):
                 ctx = self.clip.apply(params["clip"], ids)["hidden"]
                 uncond = self.clip.apply(params["clip"],
                                          uncond_ids)["hidden"]
             lat = initial_latents(rng, ids.shape[0], scfg.image_size,
                                   self.vae_scale)
             lat = spatially_shard_latents(lat, self.mesh)
-            with annotate("denoise_scan"):
+            with jax.named_scope("denoise_scan"):
                 final = run_cfg_denoise(
                     scfg, sampler, dc, self.unet_apply,
                     params["unet"], ctx, uncond, lat,
                 )
-            with annotate("vae_decode"):
+            with jax.named_scope("vae_decode"):
                 decoded = self.vae.apply(params["vae"], final)
             return postprocess_images(decoded)
 
@@ -902,7 +910,7 @@ class Text2ImagePipeline:
         :func:`degraded_dispatch_variant`)."""
         return degraded_dispatch_variant(
             self._tier_fns, self.cfg.sampler, self.mesh,
-            self._build_tier_impl, log)
+            self._build_tier_impl, log, "t2i_sample")
 
     def _dispatch_flops(self, sample_fn, scfg, kind: str = "t2i",
                         signature=None):
@@ -997,35 +1005,41 @@ class Text2ImagePipeline:
             [scfg.negative_prompt] * len(padded)))
         rng = jax.random.PRNGKey(seed)
         per_image = self._dispatch_flops(sample_fn, scfg)
+        metrics.observe("pipeline.image_batch_size", n,
+                        buckets=IMAGE_BATCH_BUCKETS)
+        # the wait for the lock is its own span (the lock's wait_span);
         # block_timer = metric + device-synchronized trace span (the
         # whole CLIP->denoise->VAE jit is ONE XLA computation; its
-        # internal stages stay visible as profiler TraceAnnotations)
-        # + roofline attribution: flops_est on the span, live
+        # stages are named scopes inside it, in a device trace's op
+        # metadata) + roofline attribution: flops_est on the span, live
         # pipeline.mxu_utilization{pipeline="t2i"} vs the chip ceiling
         with self._dispatch_lock, block_timer(
                 "pipeline.t2i_s",
                 flops_est=(per_image * len(padded)) if per_image
                 else None,
-                pipeline="t2i"):
+                pipeline="t2i", attrs={"padded_rows": len(padded)}):
             fault_point("device.lost", peer="t2i")
             images = sample_fn(self._params, ids, uncond, rng)
             # the dispatch lock exists to serialize device work; blocking
             # on the result under it is the point
             # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
             images = jax.block_until_ready(images)
-        out = integrity.poison(np.asarray(images[:n]), peer="t2i")
-        # host-side sentinel on the already-transferred uint8 batch:
-        # NaN/zeroed latents decode to constant frames, which the
-        # degenerate-frame detector catches (the verdict stays OUT of
-        # the sample jit to preserve staged-vs-monolithic bit-parity)
-        integrity.enforce(np.ones(n, dtype=bool), pipeline="t2i",
-                          stage="sample", images=out, n=n)
-        metrics.inc("pipeline.images", n)
-        if degraded is not None:
-            metrics.inc("pipeline.brownout_images", n)
-        note_encprop_counters(ep_counts, n)
-        note_consistency_counter(scfg, n)
-        note_w8a8_counter(self.cfg.models, scfg, n)
+        # the round's host tail: device result ready -> generate returns
+        with host_span("pipeline.image_host"):
+            out = integrity.poison(np.asarray(images[:n]), peer="t2i")
+            # host-side sentinel on the already-transferred uint8
+            # batch: NaN/zeroed latents decode to constant frames, which
+            # the degenerate-frame detector catches (the verdict stays
+            # OUT of the sample jit to preserve staged-vs-monolithic
+            # bit-parity)
+            integrity.enforce(np.ones(n, dtype=bool), pipeline="t2i",
+                              stage="sample", images=out, n=n)
+            metrics.inc("pipeline.images", n)
+            if degraded is not None:
+                metrics.inc("pipeline.brownout_images", n)
+            note_encprop_counters(ep_counts, n)
+            note_consistency_counter(scfg, n)
+            note_w8a8_counter(self.cfg.models, scfg, n)
         return out
 
     # -- img2img ----------------------------------------------------------
@@ -1059,8 +1073,9 @@ class Text2ImagePipeline:
         ``k`` is static: one compiled graph per strength bucket."""
         from cassmantle_tpu.ops.samplers import make_img2img_sampler
 
-        ctx = self.clip.apply(params["clip"], ids)["hidden"]
-        uncond = self.clip.apply(params["clip"], uncond_ids)["hidden"]
+        with jax.named_scope("clip_encode"):
+            ctx = self.clip.apply(params["clip"], ids)["hidden"]
+            uncond = self.clip.apply(params["clip"], uncond_ids)["hidden"]
         denoise = make_cfg_denoiser(
             self.unet_apply, params["unet"], ctx, uncond,
             self.cfg.sampler.guidance_scale,
@@ -1077,9 +1092,18 @@ class Text2ImagePipeline:
             s.kind, s.num_steps, s.num_steps - k, eta=s.eta
         )
         noise = jax.random.normal(rng_noise, lat0.shape, lat0.dtype)
-        final = sample(denoise, prepare(lat0, noise))
-        decoded = self.vae.apply(params["vae"], final)
+        with jax.named_scope("denoise_scan"):
+            final = sample(denoise, prepare(lat0, noise))
+        with jax.named_scope("vae_decode"):
+            decoded = self.vae.apply(params["vae"], final)
         return postprocess_images(decoded)
+
+    def _i2i_fn(self, k: int):
+        """The ``i2i_sample`` program of strength bucket ``k``."""
+        if k not in self._i2i_fns:
+            self._i2i_fns[k] = named_jit(
+                partial(self._img2img_impl, k), "i2i_sample")
+        return self._i2i_fns[k]
 
     def generate_img2img(
         self,
@@ -1117,8 +1141,6 @@ class Text2ImagePipeline:
         self._ensure_encoder()
         steps = self.cfg.sampler.num_steps
         k = max(1, min(steps, int(round(strength * steps))))
-        if k not in self._i2i_fns:
-            self._i2i_fns[k] = jax.jit(partial(self._img2img_impl, k))
         imgf = jnp.asarray(
             np.asarray(images, dtype=np.float32) / 127.5 - 1.0
         )
@@ -1127,7 +1149,7 @@ class Text2ImagePipeline:
             [self.cfg.sampler.negative_prompt] * len(prompts)))
         params = dict(self._params, vae_enc=self.enc_params)
         with self._dispatch_lock, block_timer("pipeline.i2i_s"):
-            out = self._i2i_fns[k](
+            out = self._i2i_fn(k)(
                 params, ids, uncond, imgf, jax.random.PRNGKey(seed)
             )
             # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
@@ -1542,8 +1564,8 @@ class PromptGenerator:
                 with self._dispatch_lock, \
                         block_timer("decode.verify_s") as sink:
                     # draft + verify fuse into one device computation;
-                    # the in-jit spec_draft/spec_verify TraceAnnotations
-                    # split the two on the profiler path
+                    # the spec_draft/spec_verify named scopes split the
+                    # two in a device trace
                     tokens, gen_len, stats = speculative_decode(
                         (self._prefill, self._step, self._chunk),
                         self.params,

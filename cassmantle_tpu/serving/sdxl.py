@@ -54,7 +54,7 @@ from cassmantle_tpu.utils.compile_cache import (
     param_cache_path,
 )
 from cassmantle_tpu.utils.logging import get_logger, metrics
-from cassmantle_tpu.utils.profiling import annotate, block_timer
+from cassmantle_tpu.utils.profiling import block_timer, host_span
 from cassmantle_tpu.utils.tokenizers import load_tokenizer
 
 log = get_logger("sdxl")
@@ -280,13 +280,16 @@ class SDXLPipeline:
 
         from cassmantle_tpu.serving.pipeline import dp_sharded_sampler
 
-        self._sample, self.dp = dp_sharded_sampler(self._sample_impl, mesh)
+        self._sample, self.dp = dp_sharded_sampler(
+            self._sample_impl, mesh, "sdxl_sample")
         # one in-flight device batch per pipeline (see Text2ImagePipeline:
         # concurrent executions of one compiled computation have
         # deadlocked the CPU backend under some jaxlib builds)
         from cassmantle_tpu.utils.locks import OrderedLock
 
-        self._dispatch_lock = OrderedLock("pipeline.sdxl_dispatch", rank=11)
+        self._dispatch_lock = OrderedLock(
+            "pipeline.sdxl_dispatch", rank=11,
+            wait_span="pipeline.image_lock_wait")
         # stage-disaggregated serving (serving/stages.py); supervisor is
         # wired by InferenceService, same as the SD1.5 pipeline
         self.supervisor = None
@@ -362,31 +365,9 @@ class SDXLPipeline:
     # -- sampling ----------------------------------------------------------
 
     def _sample_impl(self, params, ids, uncond_ids, rng):
-        with annotate("sdxl_encode"):
-            ctx, pooled = self._encode(params, ids)
-            uncond_ctx, uncond_pooled = self._encode(params, uncond_ids)
-        b = ids.shape[0]
-        time_ids = self._time_ids(b)
-        add = jnp.concatenate([pooled, time_ids], axis=-1)
-        uncond_add = jnp.concatenate([uncond_pooled, time_ids], axis=-1)
-        lat = initial_latents(rng, b, self.cfg.sampler.image_size,
-                              self.vae_scale)
-        from cassmantle_tpu.serving.pipeline import (
-            run_cfg_denoise,
-            spatially_shard_latents,
-        )
-
-        lat = spatially_shard_latents(lat, self.mesh)
-        with annotate("sdxl_denoise_scan"):
-
-            final = run_cfg_denoise(
-                self.cfg.sampler, self.sample_latents, self._dc_schedule,
-                self.unet_apply, params["unet"], ctx, uncond_ctx, lat,
-                addition_embeds=add, uncond_addition_embeds=uncond_add,
-            )
-        with annotate("sdxl_vae_decode"):
-            decoded = self.vae.apply(params["vae"], final)
-        return postprocess_images(decoded)
+        return self._build_tier_impl(
+            self.cfg.sampler, self.sample_latents, self._dc_schedule)(
+                params, ids, uncond_ids, rng)
 
     def _tokenize(self, prompts: Sequence[str]) -> np.ndarray:
         from cassmantle_tpu.serving.pipeline import tokenize_clip_prompts
@@ -444,16 +425,18 @@ class SDXLPipeline:
         return self._staged
 
     def _build_tier_impl(self, scfg, sampler, dc):
-        """The SDXL sample impl bound to a degraded tier's config —
-        ``_sample_impl`` with (steps, stride, size) swapped, the
-        micro-conditioning time_ids tracking the downshifted size."""
+        """The SDXL sample impl bound to a sampler config: the
+        pipeline's own (``_sample_impl``) or a degraded tier's, with
+        (steps, stride, size) swapped and the micro-conditioning
+        time_ids tracking the size. Stage scopes as in the SD1.5
+        pipeline, under the same names."""
         from cassmantle_tpu.serving.pipeline import (
             run_cfg_denoise,
             spatially_shard_latents,
         )
 
         def impl(params, ids, uncond_ids, rng):
-            with annotate("sdxl_encode"):
+            with jax.named_scope("clip_encode"):
                 ctx, pooled = self._encode(params, ids)
                 uctx, upooled = self._encode(params, uncond_ids)
             b = ids.shape[0]
@@ -463,14 +446,14 @@ class SDXLPipeline:
             lat = initial_latents(rng, b, scfg.image_size,
                                   self.vae_scale)
             lat = spatially_shard_latents(lat, self.mesh)
-            with annotate("sdxl_denoise_scan"):
+            with jax.named_scope("denoise_scan"):
                 final = run_cfg_denoise(
                     scfg, sampler, dc, self.unet_apply,
                     params["unet"], ctx, uctx, lat,
                     addition_embeds=add,
                     uncond_addition_embeds=uadd,
                 )
-            with annotate("sdxl_vae_decode"):
+            with jax.named_scope("vae_decode"):
                 decoded = self.vae.apply(params["vae"], final)
             return postprocess_images(decoded)
 
@@ -486,7 +469,7 @@ class SDXLPipeline:
 
         return degraded_dispatch_variant(
             self._tier_fns, self.cfg.sampler, self.mesh,
-            self._build_tier_impl, log)
+            self._build_tier_impl, log, "sdxl_sample")
 
     def _dispatch_flops(self, sample_fn, scfg):
         """Per-image analytic FLOPs (obs/costmodel.py): the shared
@@ -514,7 +497,9 @@ class SDXLPipeline:
         request rides the stage graph (see Text2ImagePipeline.generate);
         meshed serving stays monolithic."""
         from cassmantle_tpu.serving.pipeline import (
+            IMAGE_BATCH_BUCKETS,
             note_consistency_counter,
+            note_encprop_counters,
             note_w8a8_counter,
         )
 
@@ -538,29 +523,31 @@ class SDXLPipeline:
             [scfg.negative_prompt] * len(padded)))
         rng = jax.random.PRNGKey(seed)
         per_image = self._dispatch_flops(sample_fn, scfg)
-        # metric + device-synchronized trace span in one, with roofline
-        # attribution (flops_est attr + live mxu vs the chip ceiling)
+        metrics.observe("pipeline.image_batch_size", n,
+                        buckets=IMAGE_BATCH_BUCKETS)
+        # lock wait, device-synchronized dispatch and host tail: the
+        # same three spans as Text2ImagePipeline.generate
         with self._dispatch_lock, block_timer(
                 "pipeline.sdxl_s",
                 flops_est=(per_image * len(padded)) if per_image
                 else None,
-                pipeline="sdxl"):
+                pipeline="sdxl", attrs={"padded_rows": len(padded)}):
             fault_point("device.lost", peer="sdxl")
             images = sample_fn(self._params, ids, uncond, rng)
             # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
             images = jax.block_until_ready(images)
-        out = integrity.poison(np.asarray(images[:n]), peer="sdxl")
-        # host-side degenerate-frame sentinel on the transferred uint8
-        # batch (the verdict stays OUT of the sample jit to preserve
-        # staged-vs-monolithic bit-parity — see Text2ImagePipeline)
-        integrity.enforce(np.ones(n, dtype=bool), pipeline="sdxl",
-                          stage="sample", images=out, n=n)
-        metrics.inc("pipeline.sdxl_images", n)
-        if degraded is not None:
-            metrics.inc("pipeline.brownout_images", n)
-        from cassmantle_tpu.serving.pipeline import note_encprop_counters
-
-        note_encprop_counters(ep_counts, n)
-        note_consistency_counter(scfg, n)
-        note_w8a8_counter(self.cfg.models, scfg, n)
+        with host_span("pipeline.image_host"):
+            out = integrity.poison(np.asarray(images[:n]), peer="sdxl")
+            # host-side degenerate-frame sentinel on the transferred
+            # uint8 batch (the verdict stays OUT of the sample jit to
+            # preserve staged-vs-monolithic bit-parity — see
+            # Text2ImagePipeline)
+            integrity.enforce(np.ones(n, dtype=bool), pipeline="sdxl",
+                              stage="sample", images=out, n=n)
+            metrics.inc("pipeline.sdxl_images", n)
+            if degraded is not None:
+                metrics.inc("pipeline.brownout_images", n)
+            note_encprop_counters(ep_counts, n)
+            note_consistency_counter(scfg, n)
+            note_w8a8_counter(self.cfg.models, scfg, n)
         return out

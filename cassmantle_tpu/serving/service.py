@@ -13,6 +13,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 from cassmantle_tpu.config import FrameworkConfig
+from cassmantle_tpu.obs.trace import current_ctx, tracer
 from cassmantle_tpu.ops.blur import device_blur
 from cassmantle_tpu.ops.scorer import EmbeddingScorer
 from cassmantle_tpu.serving import integrity
@@ -32,7 +33,7 @@ from cassmantle_tpu.serving.queue import (
     QueueFull,
 )
 from cassmantle_tpu.serving.supervisor import ServingSupervisor
-from cassmantle_tpu.utils.logging import get_logger
+from cassmantle_tpu.utils.logging import get_logger, metrics
 
 log = get_logger("service")
 
@@ -259,7 +260,17 @@ class InferenceService:
         the prompt queue: N rounds generating concurrently become one
         (N<=8)-row decode batch. Image generation still runs per round
         in the executor. Queue overload degrades to the backend's own
-        single-prompt decode (skip-don't-crash)."""
+        single-prompt decode (skip-don't-crash).
+
+        The round is one trace, whoever calls: ``round.content`` is a
+        child of the ambient span (the engine's ``round.generate``) or
+        a root of its own, and the queue's, the lock's and the
+        pipelines' spans land under it (docs/OBSERVABILITY.md)."""
+        with tracer.span("round.content", root=current_ctx() is None), \
+                metrics.timer("round.content_s"):
+            return await self._generate_content(seed, is_seed)
+
+    async def _generate_content(self, seed: str, is_seed: bool):
         text = None
         if hasattr(self.backend, "prompt_gen"):
             try:

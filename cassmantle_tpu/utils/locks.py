@@ -98,14 +98,22 @@ class OrderedLock:
     ``name`` identifies the lock in violations and the observed-order
     graph (instances sharing a name share an ordering identity);
     ``rank`` places it in the documented hierarchy — None means "order
-    learned from observation only".
+    learned from observation only". ``wait_span`` names the wait for
+    this lock: every acquisition is then timed from the request until
+    the lock is held, to histogram ``<wait_span>_s``, a span in the
+    ambient trace and a profiler annotation (utils/profiling.py
+    ``host_span``; 0 when the lock is free). Only a lock whose wait is
+    a term of a request's latency sets it — the image pipelines'
+    dispatch lock — so no other acquisition pays the clock reads.
     """
 
-    __slots__ = ("name", "rank", "_inner")
+    __slots__ = ("name", "rank", "wait_span", "_inner")
 
-    def __init__(self, name: str, rank: Optional[int] = None) -> None:
+    def __init__(self, name: str, rank: Optional[int] = None,
+                 wait_span: Optional[str] = None) -> None:
         self.name = name
         self.rank = rank
+        self.wait_span = wait_span
         self._inner = threading.Lock()
 
     def __repr__(self) -> str:
@@ -165,7 +173,14 @@ class OrderedLock:
             # check BEFORE blocking on the inner lock: the violation
             # must raise instead of deadlocking the test that seeds it
             self._check(_held())
-        acquired = self._inner.acquire(blocking, timeout)
+        if self.wait_span is None:
+            acquired = self._inner.acquire(blocking, timeout)
+        else:
+            # lazy import: utils.profiling pulls in jax
+            from cassmantle_tpu.utils.profiling import host_span
+
+            with host_span(self.wait_span):
+                acquired = self._inner.acquire(blocking, timeout)
         if acquired:
             _held().append(self)
         return acquired

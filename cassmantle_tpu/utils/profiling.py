@@ -1,52 +1,100 @@
-"""JAX profiler helpers: trace capture + per-stage device timing.
+"""Host regions and device-synchronized stage timing, on one clock.
 
-The reference has no tracing at all (SURVEY.md §5.1). We wrap
-``jax.profiler`` so any serving stage can be captured to a TensorBoard trace
-directory, and provide a ``block_timer`` that synchronizes on device results
-so timings measure device work, not dispatch.
+The reference has no tracing at all (SURVEY.md §5.1). A region of host
+code that matters to a request is written to three records at once: a
+histogram in ``metrics``, a span in the request's trace (obs/trace.py,
+when one is ambient) and a ``jax.profiler.TraceAnnotation`` of the same
+name, so that a profiler session (``POST /debug/trace``) shows the
+program's own spans on the host lines beside the device's operations.
+``block_timer`` additionally synchronizes on device results, so its
+timings measure device work, not dispatch.
+
+Device time is named from INSIDE the compiled programs by
+``jax.named_scope`` (stage scopes such as ``denoise_step``; Flax adds a
+scope per module call) and by ``name=`` on the Pallas calls: both are
+op metadata, which a device trace carries on every event. A host
+annotation opened inside a jit-traced function fires once, at trace
+time, and leaves nothing in the program — never open one there.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
-from typing import Iterator, Optional
+from typing import Callable, Iterator, Optional
 
 import jax
 
-from cassmantle_tpu.utils.logging import get_logger, metrics
+from cassmantle_tpu.utils.logging import metrics
 
-log = get_logger("profiling")
+
+def host_region(name: str):
+    """Name a region of HOST code in the profiler's trace. The one door
+    to ``TraceAnnotation``: ``Tracer.span``, ``host_span`` and
+    ``block_timer`` come through here (with no profiler session active
+    a TraceMe is a flag test)."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def named_jit(fn: Callable, name: str, **jit_kwargs):
+    """``jax.jit(fn)`` under a fixed program name: the trace's ``XLA
+    Modules`` line and every ``op_name`` (``jit(<name>)/...``) carry it,
+    whatever function object reached the jit."""
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
+
+
+def _record_ambient_span(name: str, start_wall: float, duration_s: float,
+                         attrs: dict) -> None:
+    """A finished region as a child span of the ambient trace; nothing
+    without one (a bare call mints no orphan root trace)."""
+    from cassmantle_tpu.obs.trace import current_ctx, tracer
+
+    ctx = current_ctx()
+    if ctx is not None and ctx.sampled:
+        tracer.record_span(
+            name, tracer.child_ctx(ctx), parent_id=ctx.span_id,
+            start_wall=start_wall, duration_s=duration_s, attrs=attrs)
 
 
 @contextlib.contextmanager
-def trace(log_dir: Optional[str]) -> Iterator[None]:
-    """Capture a jax.profiler trace if log_dir is set; no-op otherwise."""
-    if not log_dir:
-        yield
-        return
-    with jax.profiler.trace(log_dir):
-        yield
-    log.info("profiler trace written to %s", log_dir)
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Name a region in the device trace (shows up in TensorBoard)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+def host_span(name: str) -> Iterator[None]:
+    """Time a host region (a wait, a copy, a check) to histogram
+    ``<name>_s``, span ``<name>`` and the profiler annotation
+    ``<name>``."""
+    start_wall = time.time()
+    start = time.perf_counter()
+    try:
+        with host_region(name):
+            yield
+    finally:
+        elapsed = time.perf_counter() - start
+        # tools/check_metrics.py lints the literal at the call site
+        # (host_span("a.b") emits the histogram a.b_s)
+        metrics.observe(name + "_s", elapsed)
+        _record_ambient_span(name, start_wall, elapsed, {})
 
 
 @contextlib.contextmanager
 def block_timer(name: str, *results, flops_est=None,
-                pipeline: Optional[str] = None) -> Iterator[list]:
+                pipeline: Optional[str] = None,
+                attrs: Optional[dict] = None) -> Iterator[list]:
     """Time a region to metrics, blocking on listed device arrays at exit.
 
     Also records a **device-synchronized stage span** into the active
-    trace (obs/trace.py) when one is ambient: the block-until-ready at
-    exit means the span's duration covers the device work, not just
-    dispatch — these are the per-stage spans a request trace shows for
-    scorer encodes, prompt decodes, and image generations.
+    trace (obs/trace.py) when one is ambient, carrying ``attrs``, and
+    opens the profiler annotation ``name`` around the region: the
+    block-until-ready at exit means both cover the device work, not
+    just dispatch — these are the per-stage spans a request trace shows
+    for scorer encodes, prompt decodes, and image generations. On the
+    host clock a stage that was dispatched behind another program
+    includes its time in the device's queue.
 
     Roofline attribution (ISSUE 14): callers that know their dispatch's
     analytic FLOPs (obs/costmodel.py) pass ``flops_est`` (a float, or a
@@ -60,21 +108,22 @@ def block_timer(name: str, *results, flops_est=None,
     gauge off-TPU, an error for a TPU kind with no peak on record).
     ``pipeline`` alone also marks a dispatch boundary for the HBM
     highwater tracker (obs/device.py)."""
-    from cassmantle_tpu.obs.trace import current_ctx, tracer
-
     sink: list = []
     start_wall = time.time()
     start = time.perf_counter()
     ok = False
     try:
-        yield sink
-        ok = True
+        with host_region(name):
+            try:
+                yield sink
+                ok = True
+            finally:
+                for r in list(results) + sink:
+                    jax.block_until_ready(r)
     finally:
-        for r in list(results) + sink:
-            jax.block_until_ready(r)
         elapsed = time.perf_counter() - start
         metrics.observe(name, elapsed)
-        attrs = {"device_synced": True}
+        attrs = dict(attrs or {}, device_synced=True)
         flops = None
         # attribution only for dispatches that COMPLETED: a body that
         # raised (OOM, chaos injection) did not do its analytic FLOPs,
@@ -104,9 +153,4 @@ def block_timer(name: str, *results, flops_est=None,
             from cassmantle_tpu.obs.device import note_dispatch
 
             note_dispatch(pipeline)
-        ctx = current_ctx()
-        if ctx is not None and ctx.sampled:
-            tracer.record_span(
-                name, tracer.child_ctx(ctx), parent_id=ctx.span_id,
-                start_wall=start_wall, duration_s=elapsed,
-                attrs=attrs)
+        _record_ambient_span(name, start_wall, elapsed, attrs)

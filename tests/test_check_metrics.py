@@ -56,6 +56,18 @@ def test_extractor_covers_block_timer_stage_names():
             "pipeline.sdxl_s", "pipeline.prompt_s"} <= all_names
 
 
+def test_extractor_covers_timed_host_regions():
+    """host_span and a lock's wait_span name the SPAN; the histogram
+    they observe, ``<span>_s``, lints like any other."""
+    sites = extract_sites(
+        "with host_span('pipeline.image_host'):\n    pass\n"
+        "lock = OrderedLock('pipeline.t2i_dispatch', rank=10,\n"
+        "                   wait_span='pipeline.image_lock_wait')\n",
+        "<test>")
+    assert ("pipeline.image_host_s", "observe", 1) in sites
+    assert ("pipeline.image_lock_wait_s", "observe", 3) in sites
+
+
 def test_wildcard_matching_rules():
     assert _name_matches("circuit.*.*", "circuit.<name>.opened")
     assert _name_matches("score.batches", "<queue>.batches")

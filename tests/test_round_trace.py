@@ -1,0 +1,174 @@
+"""A round is one trace, whoever calls it (ISSUE 26).
+
+``InferenceService.generate_content`` opens ``round.content``: the queue's
+wait/batch spans, the prompt decode, the wait for the image pipeline's
+dispatch lock, the image dispatch and the host tail all land under it,
+each beside the histogram observed at the same place — the terms a
+benchmark adds up to a round's time. Tiny test size, real pipelines.
+"""
+
+import asyncio
+import threading
+
+import pytest
+
+from cassmantle_tpu.config import test_config as tiny_config
+from cassmantle_tpu.obs.trace import tracer
+from cassmantle_tpu.utils.logging import metrics
+
+# span -> parent span, as docs/OBSERVABILITY.md draws the round's tree
+ROUND_TREE = {
+    "prompt.queue_wait": "round.content",
+    "prompt.batch": "round.content",
+    "prompt.batch_service": "round.content",
+    "pipeline.prompt_s": "prompt.batch",
+    "pipeline.image_lock_wait": "round.content",
+    "pipeline.t2i_s": "round.content",
+    "pipeline.image_host": "round.content",
+}
+ROUND_HISTOGRAMS = (
+    "round.content_s", "prompt.queue_wait_s", "prompt.batch_size",
+    "pipeline.prompt_s", "pipeline.image_lock_wait_s", "pipeline.t2i_s",
+    "pipeline.image_batch_size", "pipeline.image_host_s")
+
+
+def hist_count(name: str) -> int:
+    totals = metrics.hist_totals(name)
+    return totals[2] if totals is not None else 0
+
+
+def hist_sum(name: str) -> float:
+    return sum(total for n, _l, _b, _c, total, _count
+               in metrics.dump_state()["hists"] if n == name)
+
+
+@pytest.fixture(scope="module")
+def backend():
+    """The tiny pipelines, built (and compiled on first use) once."""
+    from cassmantle_tpu.serving.pipeline import TPUContentBackend
+
+    return TPUContentBackend(tiny_config())
+
+
+@pytest.fixture()
+def all_traces_kept():
+    stats = tracer.stats()
+    tracer.configure(sample_rate=1.0)
+    yield
+    tracer.configure(sample_rate=stats["sample_rate"])
+
+
+def one_round(backend, ambient_root: bool):
+    """(new trace ids, histogram counts before, after) of one round
+    through the content backend the Game owns."""
+    from cassmantle_tpu.serving.service import InferenceService
+
+    async def run():
+        service = InferenceService(tiny_config(), backend=backend)
+        try:
+            generate = service.content_backend.generate
+            if ambient_root:
+                with tracer.span("round.generate", root=True):
+                    return await generate("The storm over the harbor", True)
+            return await generate("The storm over the harbor", True)
+        finally:
+            await service.stop()
+
+    known = set(tracer.trace_ids())
+    before = {h: hist_count(h) for h in ROUND_HISTOGRAMS}
+    content = asyncio.run(run())
+    assert content.prompt_text and content.image is not None
+    after = {h: hist_count(h) for h in ROUND_HISTOGRAMS}
+    return [t for t in tracer.trace_ids() if t not in known], before, after
+
+
+@pytest.mark.parametrize("ambient_root", [False, True],
+                         ids=["called_bare", "under_round_generate"])
+def test_a_round_is_one_trace_with_the_tables_spans(
+        backend, all_traces_kept, ambient_root):
+    new_traces, before, after = one_round(backend, ambient_root)
+    # bare, round.content is the root; under the engine's
+    # round.generate it joins that trace — one trace either way
+    assert len(new_traces) == 1
+    spans = tracer.get_trace(new_traces[0])
+    by_id = {s["span_id"]: s for s in spans}
+    by_name = {s["name"]: s for s in spans}
+    tree = dict(ROUND_TREE,
+                **{"round.content":
+                   "round.generate" if ambient_root else None})
+    if ambient_root:
+        tree["round.generate"] = None
+    assert sorted(s["name"] for s in spans) == sorted(tree)
+    for name, parent in tree.items():
+        span = by_name[name]
+        assert span["trace_id"] == new_traces[0]
+        got = by_id[span["parent_id"]]["name"] if span["parent_id"] else None
+        assert got == parent, (name, got)
+        assert span["start_ns"] == round(span["start_ts"] * 1e9)
+    assert by_name["pipeline.t2i_s"]["attrs"]["padded_rows"] == 1
+    # the terms lie inside the round, in the round's order
+    inside = by_name["round.content"]
+    end_ns = inside["start_ns"] + inside["duration_s"] * 1e9
+    order = ["prompt.queue_wait", "pipeline.prompt_s",
+             "pipeline.image_lock_wait", "pipeline.t2i_s",
+             "pipeline.image_host"]
+    starts = [by_name[n]["start_ns"] for n in order]
+    assert starts == sorted(starts)
+    assert inside["start_ns"] <= starts[0]
+    last = by_name[order[-1]]
+    assert last["start_ns"] + last["duration_s"] * 1e9 <= end_ns + 1e6
+    # one observation in each histogram, at the same place
+    for hist in ROUND_HISTOGRAMS:
+        assert after[hist] - before[hist] == 1, hist
+
+
+@pytest.mark.parametrize("contended", [False, True],
+                         ids=["uncontended", "contended"])
+def test_lock_wait_is_observed_once_per_acquisition(backend, contended):
+    """The image dispatch lock times the request for it until it is
+    held: near 0 when free, the holder's remaining time when not."""
+    lock = backend.t2i._dispatch_lock
+    assert lock.wait_span == "pipeline.image_lock_wait"
+    hist = "pipeline.image_lock_wait_s"
+    if not contended:
+        count, total = hist_count(hist), hist_sum(hist)
+        with lock:
+            pass
+        assert hist_count(hist) == count + 1
+        assert hist_sum(hist) - total < 0.05
+        return
+    holding, release = threading.Event(), threading.Event()
+
+    def holder():
+        with lock:
+            holding.set()
+            release.wait(5.0)
+
+    thread = threading.Thread(target=holder)
+    timer = threading.Timer(0.2, release.set)
+    thread.start()
+    try:
+        assert holding.wait(5.0)
+        # from here: the holder's own acquisition is counted
+        count, total = hist_count(hist), hist_sum(hist)
+        timer.start()
+        with lock:                            # waits for the holder
+            pass
+    finally:
+        release.set()
+        thread.join(5.0)
+        timer.join(5.0)
+    assert hist_count(hist) == count + 1
+    assert 0.15 < hist_sum(hist) - total < 5.0
+
+
+def test_only_a_lock_that_names_its_wait_is_timed():
+    from cassmantle_tpu.utils.locks import OrderedLock
+
+    count = hist_count("pipeline.image_lock_wait_s")
+    plain = OrderedLock("test.plain")
+    assert plain.wait_span is None
+    with plain:
+        pass
+    assert hist_count("pipeline.image_lock_wait_s") == count
+    assert metrics.hist_totals("test.plain_s") is None
