@@ -9,10 +9,10 @@ NHWC, fp32 by default (the VAE is the most precision-sensitive stage; its
 FLOPs are a rounding error next to 50 UNet steps — though at SDXL-1024 the
 decode is 10.47 TF/image, which the decode-side kernels below attack).
 Attention in the mid block is single-head over H·W tokens, routed through
-ops.attention like every other attention site — on TPU that now dispatches
-the wide-head flash variant (ops/flash_attention.py::flash_wide_ok,
-512-blocks) instead of materializing the S=16,384 score matrix in HBM at
-SDXL's 128² latent. ``VAEConfig.fused_conv`` additionally routes every
+ops.attention like every other attention site — on TPU that dispatches
+the flash kernel (ops/flash_attention.py::flash_plan: a self-attention
+site of one 512-wide head) instead of materializing the S=16,384 score
+matrix in HBM at SDXL's 128² latent. ``VAEConfig.fused_conv`` additionally routes every
 ResBlock's GN→SiLU→conv3x3 pair through the fused Pallas kernel.
 """
 

@@ -8,16 +8,21 @@ attention code at all — its models live behind the HF Inference API
 remote API with local TPU compute" north star.
 
 Dispatch policy:
-- TPU + no mask + seq long enough to tile → Pallas flash attention
-  (blockwise online-softmax, O(N) memory; ops/flash_attention.py),
-  per batch shard inside a :func:`batch_sharded_kernels` region;
+- TPU + no mask + a shape ``flash_plan`` takes (a query axis long enough
+  to tile; K/V that tile or are short enough to pad) → Pallas flash
+  attention (blockwise online-softmax, O(N) memory;
+  ops/flash_attention.py), per batch shard inside a
+  :func:`batch_sharded_kernels` region;
 - otherwise → jnp.einsum attention, which XLA fuses well on its own.
+Every site counts itself under ``attention.dispatch{path=...}`` when its
+program is traced.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import os
 from functools import partial
 from typing import Optional
 
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from cassmantle_tpu.ops.platform import on_tpu
+from cassmantle_tpu.utils.logging import metrics
 
 # When set, every attention site uses the plain XLA path — used when
 # tracing for a non-TPU device (e.g. CPU-side param init) while the default
@@ -148,6 +154,7 @@ def multi_head_attention(
             )
 
             mesh, axis_name, batch_axis = cp
+            _count_dispatch("ring")
             return zigzag_sharded_attention(
                 q, k, v, mesh, axis_name=axis_name, scale=scale,
                 batch_axis=batch_axis,
@@ -168,46 +175,36 @@ def multi_head_attention(
         use_flash = on_tpu() and mask is None
     if use_flash and mask is None:
         from cassmantle_tpu.ops.flash_attention import (
-            flash_attention_ok,
-            flash_cross_ok,
-            flash_wide_ok,
+            flash_attention,
+            flash_plan,
         )
 
-        if flash_attention_ok(q, k):
-            from cassmantle_tpu.ops.flash_attention import flash_attention
-
+        # one kernel for every shape it takes: self-attention over image
+        # tokens (the VAE mid block's single 512-wide head included) and
+        # ragged-S_k cross-attention (UNet text context, S_k=77: K/V pad
+        # into the kernel, pad columns masked)
+        plan = flash_plan(q, k)
+        if plan is not None and not (
+                plan.kind == "flash_cross" and _no_flash_cross()):
+            _count_dispatch(plan.kind)
             return _flash_per_batch_shard(
-                partial(flash_attention, scale=scale), q, k, v)
-        if flash_wide_ok(q, k):
-            # wide-head self-attention (the VAE mid block: single head
-            # over H·W tokens at full channel width — S=16k, D=512 at
-            # SDXL decode): same kernel at 512-blocks so the fat head
-            # fits VMEM; the XLA path would materialize the (S, S)
-            # score matrix in HBM.
-            from cassmantle_tpu.ops.flash_attention import (
-                WIDE_BLOCK,
-                flash_attention,
-            )
-
-            return _flash_per_batch_shard(
-                partial(flash_attention, scale=scale,
-                        block_q=WIDE_BLOCK, block_k=WIDE_BLOCK), q, k, v)
-        if flash_cross_ok(q, k):
-            import os
-
-            # ragged-S_k cross-attention (UNet text context, S_k=77):
-            # K/V pad into the kernel, pad columns masked by kv_len.
-            # CASSMANTLE_NO_FLASH_CROSS=1 is the operator kill switch —
-            # one env var reverts every cross site to the XLA path if
-            # this newer kernel misbehaves on some TPU generation,
-            # without touching the proven self-attention flash path.
-            if os.environ.get(
-                    "CASSMANTLE_NO_FLASH_CROSS", ""
-            ).lower() in ("", "0", "false", "no", "off"):
-                from cassmantle_tpu.ops.flash_attention import (
-                    flash_cross_attention,
-                )
-
-                return _flash_per_batch_shard(
-                    partial(flash_cross_attention, scale=scale), q, k, v)
+                partial(flash_attention, scale=scale, plan=plan), q, k, v)
+    _count_dispatch("xla")
     return xla_attention(q, k, v, mask=mask, scale=scale)
+
+
+def _no_flash_cross() -> bool:
+    """CASSMANTLE_NO_FLASH_CROSS=1 is the operator kill switch — one env
+    var reverts every ragged cross-attention site to the XLA path if the
+    kernel misbehaves there on some TPU generation, without touching the
+    self-attention sites."""
+    return os.environ.get("CASSMANTLE_NO_FLASH_CROSS", "").lower() not in (
+        "", "0", "false", "no", "off")
+
+
+def _count_dispatch(path: str) -> None:
+    """``attention.dispatch{path=...}``: one count a site each time a
+    program is traced (this code runs at trace time only), so a reader
+    sees how many sites of a program took the kernel, by kind, and how
+    many fell to XLA."""
+    metrics.inc("attention.dispatch", labels={"path": path})

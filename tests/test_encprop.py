@@ -435,26 +435,28 @@ def test_fused_vae_kill_switch(monkeypatch):
 
 def test_flash_vae_attention_parity_and_gate():
     """The VAE mid block's single-head, full-channel-width attention
-    (D past the main flash kernel's head bound) dispatches the
-    wide-head 512-block variant; numeric parity vs the XLA path, and
-    the gate must not shadow the main kernel's shapes."""
+    takes the flash kernel as a self-attention site of one wide head;
+    numeric parity vs the XLA path, and the gate keeps ragged
+    sequences off the kernel."""
     from cassmantle_tpu.ops.attention import multi_head_attention
-    from cassmantle_tpu.ops.flash_attention import (
-        flash_attention_ok,
-        flash_wide_ok,
-    )
+    from cassmantle_tpu.ops.flash_attention import flash_plan
 
     q = jax.random.normal(jax.random.PRNGKey(1), (1, 512, 1, 320),
                           jnp.float32)
-    assert flash_wide_ok(q, q) and not flash_attention_ok(q, q)
+    assert flash_plan(q, q).kind == "flash_self"
     ref = multi_head_attention(q, q, q, use_flash=False)
     out = multi_head_attention(q, q, q, use_flash=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
-    # narrow heads stay on the main kernel's path; ragged S stays XLA
+    # production width and narrow heads are the same plan kind; a
+    # ragged S stays XLA, and so does a head wider than the kernel's tile
+    q_wide = jnp.zeros((1, 4096, 1, 512))
     q_narrow = jnp.zeros((1, 1024, 1, 64))
-    assert not flash_wide_ok(q_narrow, q_narrow)
+    assert (flash_plan(q_wide, q_wide).kind
+            == flash_plan(q_narrow, q_narrow).kind == "flash_self")
     q_ragged = jnp.zeros((1, 500, 1, 320))
-    assert not flash_wide_ok(q_ragged, q_ragged)
+    assert flash_plan(q_ragged, q_ragged) is None
+    q_fat = jnp.zeros((1, 1024, 1, 2048))
+    assert flash_plan(q_fat, q_fat) is None
 

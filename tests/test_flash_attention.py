@@ -8,11 +8,13 @@ import pytest
 
 from cassmantle_tpu.ops.attention import xla_attention
 from cassmantle_tpu.ops.flash_attention import (
-    BLOCK_K,
-    BLOCK_Q,
+    MIN_SEQ,
+    FlashPlan,
     flash_attention,
-    flash_attention_ok,
+    flash_plan,
 )
+
+S = 1024  # one level-1 sequence: every plan below tiles it
 
 
 def _rand_qkv(key, batch, seq, heads, dim, dtype=jnp.float32, seq_k=None):
@@ -24,19 +26,22 @@ def _rand_qkv(key, batch, seq, heads, dim, dtype=jnp.float32, seq_k=None):
     return q, k, v
 
 
-def test_ok_predicate():
-    q, k, _ = _rand_qkv(jax.random.PRNGKey(0), 1, BLOCK_Q, 2, 64)
-    assert flash_attention_ok(q, k)
+def test_plan_predicate():
+    q, k, _ = _rand_qkv(jax.random.PRNGKey(0), 1, S, 2, 64)
+    assert flash_plan(q, k).kind == "flash_self"
     q2, k2, _ = _rand_qkv(jax.random.PRNGKey(0), 1, 77, 2, 64)
-    assert not flash_attention_ok(q2, k2)  # not block-divisible
-    q3 = q[0]
-    assert not flash_attention_ok(q3, k[0])  # needs batch dim
+    assert flash_plan(q2, k2) is None  # query axis does not tile
+    assert flash_plan(q[0], k[0]) is None  # needs batch dim
 
 
 @pytest.mark.parametrize("seq,heads,dim", [
-    (BLOCK_Q, 2, 64),          # single block
-    (2 * BLOCK_Q, 1, 40),      # SD1.5 head_dim at level 0, 2 k-blocks
-    (4 * BLOCK_Q, 2, 80),      # multi-block, SD1.5 level-1 head_dim
+    (MIN_SEQ, 2, 64),     # shortest sequence the kernel takes
+    (S, 8, 40),           # SD1.5 level 0 heads, H·D = 320
+    (S, 8, 80),           # SD1.5 level 1 heads, H·D = 640
+    (S, 10, 64),          # SDXL level 1 heads
+    (S, 20, 64),          # SDXL level 2 heads, H·D = 1280
+    (S, 1, 512),          # the VAE mid block's one wide head
+    (2 * S, 1, 40),       # several q blocks
 ])
 def test_flash_matches_xla(seq, heads, dim):
     q, k, v = _rand_qkv(jax.random.PRNGKey(1), 2, seq, heads, dim)
@@ -47,10 +52,27 @@ def test_flash_matches_xla(seq, heads, dim):
     )
 
 
+@pytest.mark.parametrize("block_q,block_k", [
+    (256, 1024),    # one K/V block: the softmax is whole, no running state
+    (512, 512),     # two K/V blocks: online softmax, head state in scratch
+    (1024, 256),    # four K/V blocks under one q block
+])
+def test_flash_blocks_agree(block_q, block_k):
+    """Whatever blocks a plan names, the result is the reference's: the
+    single-block body and the online-softmax body are one arithmetic."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(10), 2, S, 8, 40)
+    out = flash_attention(
+        q, k, v, interpret=True,
+        plan=FlashPlan("flash_self", block_q, block_k))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(xla_attention(q, k, v)),
+        atol=2e-5, rtol=2e-5)
+
+
 def test_flash_cross_lengths():
     """Sq != Sk (both block-divisible)."""
     q, k, v = _rand_qkv(
-        jax.random.PRNGKey(2), 1, BLOCK_Q, 2, 64, seq_k=2 * BLOCK_K
+        jax.random.PRNGKey(2), 1, S, 2, 64, seq_k=2 * S
     )
     ref = xla_attention(q, k, v)
     out = flash_attention(q, k, v, interpret=True)
@@ -59,12 +81,16 @@ def test_flash_cross_lengths():
     )
 
 
-def test_flash_bf16():
+@pytest.mark.parametrize("heads,dim,seq_k", [
+    (2, 64, None), (8, 40, None), (8, 40, 77)],
+    ids=["self_2x64", "self_8x40", "cross77_8x40"])
+def test_flash_bf16(heads, dim, seq_k):
     q, k, v = _rand_qkv(
-        jax.random.PRNGKey(3), 1, BLOCK_Q, 2, 64, dtype=jnp.bfloat16
-    )
+        jax.random.PRNGKey(3), 1, S, heads, dim, dtype=jnp.bfloat16,
+        seq_k=seq_k)
     ref = xla_attention(q, k, v)
     out = flash_attention(q, k, v, interpret=True)
+    assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(
         np.asarray(out, dtype=np.float32),
         np.asarray(ref, dtype=np.float32),
@@ -72,12 +98,15 @@ def test_flash_bf16():
     )
 
 
-def test_flash_extreme_logits_stable():
-    """Online softmax must survive large logit magnitudes."""
-    q, k, v = _rand_qkv(jax.random.PRNGKey(4), 1, BLOCK_Q, 1, 64)
+@pytest.mark.parametrize("plan", [
+    None, FlashPlan("flash_self", 512, 256)], ids=["whole", "online"])
+def test_flash_extreme_logits_stable(plan):
+    """The softmax must survive large logit magnitudes, in one K/V block
+    and across several."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(4), 1, S, 2, 64)
     q = q * 30.0
     ref = xla_attention(q, k, v)
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v, interpret=True, plan=plan)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4
@@ -103,62 +132,50 @@ def test_causal_decode_alignment():
         np.asarray(tail), np.asarray(full[:, -3:]), atol=1e-6, rtol=1e-6)
 
 
-def test_flash_cross_ragged_kv_matches_xla():
+@pytest.mark.parametrize("heads,dim", [(8, 40), (8, 80)],
+                         ids=["hd320", "hd640"])
+@pytest.mark.parametrize("seq_k", [77, 128, 7, 130])
+def test_flash_cross_ragged_kv_matches_xla(seq_k, heads, dim):
     """Ragged-S_k cross-attention (the UNet's text context, S_k=77):
-    K/V pad into one block and the kernel's kv_len mask makes the
+    K/V pad into 128-wide blocks and the kernel's kv_len mask makes the
     result EXACT vs the XLA reference — pad columns contribute
-    nothing to the softmax."""
-    from cassmantle_tpu.ops.flash_attention import (
-        flash_cross_attention,
-        flash_cross_ok,
-    )
-
-    for sk in (77, 7, 130):
-        q, k, v = _rand_qkv(jax.random.PRNGKey(5), 2, BLOCK_Q, 2, 40,
-                            seq_k=sk)
-        assert flash_cross_ok(q, k), sk
-        out = flash_cross_attention(q, k, v, interpret=True)
-        ref = xla_attention(q, k, v)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5,
-            err_msg=f"{sk=}")
-
-
-def test_flash_cross_ok_predicate():
-    from cassmantle_tpu.ops.flash_attention import (
-        CROSS_BLOCK_K,
-        flash_cross_ok,
-    )
-
-    q, k, _ = _rand_qkv(jax.random.PRNGKey(6), 1, BLOCK_Q, 2, 64,
-                        seq_k=77)
-    assert flash_cross_ok(q, k)
-    # short ALIGNED S_k (128..896) also belongs here: too small for the
-    # plain kernel's 1024-blocks, still worth keeping out of HBM
-    q2, k2, _ = _rand_qkv(jax.random.PRNGKey(6), 1, BLOCK_Q, 2, 64,
-                          seq_k=CROSS_BLOCK_K)
-    assert flash_cross_ok(q2, k2)
-    from cassmantle_tpu.ops.flash_attention import flash_cross_attention
-
-    out = flash_cross_attention(q2, k2, k2, interpret=True)
+    nothing to the softmax. 128 needs no pad and no mask; 130 pads into
+    a second block."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 2, S, heads, dim,
+                        seq_k=seq_k)
+    assert flash_plan(q, k).kind == "flash_cross"
+    out = flash_attention(q, k, v, interpret=True)
+    ref = xla_attention(q, k, v)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(xla_attention(q2, k2, k2)),
-        atol=2e-5, rtol=2e-5)
-    # full-block K/V stays with the plain kernel
-    q4, k4, _ = _rand_qkv(jax.random.PRNGKey(6), 1, BLOCK_Q, 2, 64)
-    assert not flash_cross_ok(q4, k4)
+        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def test_flash_cross_plan_predicate():
+    q, k, _ = _rand_qkv(jax.random.PRNGKey(6), 1, S, 2, 64, seq_k=77)
+    assert flash_plan(q, k).kind == "flash_cross"
+    # short ALIGNED S_k (128..384) also belongs here: too small to be
+    # worth whole blocks of its own, still worth keeping out of HBM
+    q2, k2, _ = _rand_qkv(jax.random.PRNGKey(6), 1, S, 2, 64, seq_k=128)
+    assert flash_plan(q2, k2) == FlashPlan("flash_cross", S, 128)
+    # K/V that tile are self-attention's plan, whatever S_q is
+    q4, k4, _ = _rand_qkv(jax.random.PRNGKey(6), 1, S, 2, 64)
+    assert flash_plan(q4, k4).kind == "flash_self"
     # short query axis -> XLA path
     q3, k3, _ = _rand_qkv(jax.random.PRNGKey(6), 1, 64, 2, 64, seq_k=77)
-    assert not flash_cross_ok(q3, k3)
+    assert flash_plan(q3, k3) is None
+    # K/V too long to pad, too ragged to tile -> XLA path
+    q5, k5, _ = _rand_qkv(jax.random.PRNGKey(6), 1, S, 2, 64, seq_k=1100)
+    assert flash_plan(q5, k5) is None
+    with pytest.raises(ValueError, match="no flash plan"):
+        flash_attention(q5, k5, k5, interpret=True)
 
 
 def test_dispatcher_routes_ragged_cross_attention():
     """multi_head_attention with use_flash=True and ragged K/V must hit
-    the cross kernel (numerics equal XLA) rather than falling back."""
+    the kernel (numerics equal XLA) rather than falling back."""
     from cassmantle_tpu.ops.attention import multi_head_attention
 
-    q, k, v = _rand_qkv(jax.random.PRNGKey(7), 1, BLOCK_Q, 2, 40,
-                        seq_k=77)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(7), 1, S, 2, 40, seq_k=77)
     out = multi_head_attention(q, k, v, use_flash=True)
     ref = xla_attention(q, k, v)
     np.testing.assert_allclose(
@@ -167,32 +184,34 @@ def test_dispatcher_routes_ragged_cross_attention():
 
 def test_flash_cross_kill_switch(monkeypatch):
     """CASSMANTLE_NO_FLASH_CROSS reverts ragged cross-attention to the
-    XLA path (operator insurance for a misbehaving kernel). Routing is
-    asserted directly: the cross kernel must not be INVOKED when the
-    switch is set ('0' and unset mean on), since the two paths are
-    parity-equal by design and output comparison can't see routing."""
+    XLA path (operator insurance for a misbehaving kernel) and leaves
+    self-attention on the kernel. Routing is asserted directly: the
+    kernel must not be INVOKED for a cross site when the switch is set
+    ('0' and unset mean on), since the two paths are parity-equal by
+    design and output comparison can't see routing."""
     import cassmantle_tpu.ops.flash_attention as fa_mod
     from cassmantle_tpu.ops.attention import multi_head_attention
 
     calls = []
-    real = fa_mod.flash_cross_attention
+    real = fa_mod.flash_attention
     monkeypatch.setattr(
-        fa_mod, "flash_cross_attention",
-        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+        fa_mod, "flash_attention",
+        lambda *a, **kw: calls.append(kw["plan"].kind) or real(*a, **kw))
 
-    q, k, v = _rand_qkv(jax.random.PRNGKey(8), 1, BLOCK_Q, 2, 40,
-                        seq_k=77)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), 1, S, 2, 40, seq_k=77)
     monkeypatch.setenv("CASSMANTLE_NO_FLASH_CROSS", "1")
     off = multi_head_attention(q, k, v, use_flash=True)
-    assert not calls, "kill switch set but cross kernel was invoked"
+    assert not calls, "kill switch set but the kernel took a cross site"
+    multi_head_attention(q, q, q, use_flash=True)
+    assert calls == ["flash_self"], "the switch is for cross sites only"
     monkeypatch.setenv("CASSMANTLE_NO_FLASH_CROSS", "0")  # conventional re-enable
     on = multi_head_attention(q, k, v, use_flash=True)
-    assert calls, "switch '0' must mean enabled"
+    assert calls[-1] == "flash_cross", "switch '0' must mean enabled"
     np.testing.assert_allclose(np.asarray(on), np.asarray(off),
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("seq_k", [BLOCK_Q, 77], ids=["self", "cross77"])
+@pytest.mark.parametrize("seq_k", [S, 77], ids=["self", "cross77"])
 def test_dispatch_per_batch_shard_matches_unsharded(seq_k):
     """Inside ``batch_sharded_kernels`` (the dp serving mesh) each device
     runs the kernel on its own batch rows through shard_map; attention
@@ -207,8 +226,7 @@ def test_dispatch_per_batch_shard_matches_unsharded(seq_k):
     )
 
     mesh = Mesh(np.asarray(jax.devices()[:4]), ("dp",))
-    q, k, v = _rand_qkv(jax.random.PRNGKey(9), 4, BLOCK_Q, 2, 40,
-                        seq_k=seq_k)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(9), 4, S, 2, 40, seq_k=seq_k)
     rows = NamedSharding(mesh, P("dp"))
 
     def attend(q, k, v):

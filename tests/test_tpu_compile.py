@@ -60,17 +60,18 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _flash_self(b, s, h, d, block=None):
-    blocks = {} if block is None else {"block_q": block, "block_k": block}
-    return (lambda q, k, v: fa.flash_attention(
-        q, k, v, interpret=False, **blocks),
-        [((b, s, h, d), BF16)] * 3)
+def _flash(b, s, h, d, s_k=None):
+    """The flash kernel as the model reaches it: q/k/v in the projections'
+    (B, S, H·D) layout, split into heads by a free reshape, under the
+    shape rule's own plan (``s_k``: ragged text context, padded in)."""
+    def attend(q, k, v):
+        q, k, v = (t.reshape(t.shape[:-1] + (h, d)) for t in (q, k, v))
+        assert fa.flash_plan(q, k) is not None
+        return fa.flash_attention(q, k, v, interpret=False).reshape(
+            (b, s, h * d))
 
-
-def _flash_cross(b, s, h, d, s_k=77):
-    return (lambda q, k, v: fa.flash_cross_attention(
-        q, k, v, interpret=False),
-        [((b, s, h, d), BF16)] + [((b, s_k, h, d), BF16)] * 2)
+    return (attend, [((b, s, h * d), BF16)]
+            + [((b, s_k or s, h * d), BF16)] * 2)
 
 
 def _fused_conv(b, hw, c, f, pad):
@@ -102,14 +103,33 @@ UNALIGNED_DMA = pytest.mark.xfail(
            "turns fused_conv on pads to 128 (ROADMAP A3)")
 
 KERNEL_CASES = {
-    # the three default-path flash kernels at SD1.5-512 widths: UNet
-    # self-attention at levels 0/1, ragged text cross-attention
-    # (S_k=77) at the same levels, the VAE mid-block's wide head
-    "flash_self_l0": _flash_self(8, 4096, 8, 40),
-    "flash_self_l1": _flash_self(8, 1024, 8, 80),
-    "flash_cross77_l0": _flash_cross(8, 4096, 8, 40),
-    "flash_cross77_l1": _flash_cross(8, 1024, 8, 80),
-    "flash_wide_vae_mid": _flash_self(4, 4096, 1, 512, block=fa.WIDE_BLOCK),
+    # the one flash kernel at every site it serves, at CFG batch 2 (one
+    # image) and batch 8 (the 4-image bucket). SD1.5-512: UNet
+    # self-attention at levels 0/1, ragged text cross-attention (S_k=77)
+    # at the same levels, the VAE mid block's one wide head (batch 1
+    # and 4: no CFG in the decoder)
+    "flash_self_l0": _flash(8, 4096, 8, 40),
+    "flash_self_l1": _flash(8, 1024, 8, 80),
+    "flash_cross77_l0": _flash(8, 4096, 8, 40, s_k=77),
+    "flash_cross77_l1": _flash(8, 1024, 8, 80, s_k=77),
+    "flash_wide_vae_mid": _flash(4, 4096, 1, 512),
+    "flash_self_l0_b2": _flash(2, 4096, 8, 40),
+    "flash_self_l1_b2": _flash(2, 1024, 8, 80),
+    "flash_cross77_l0_b2": _flash(2, 4096, 8, 40, s_k=77),
+    "flash_cross77_l1_b2": _flash(2, 1024, 8, 80, s_k=77),
+    "flash_wide_vae_mid_b1": _flash(1, 4096, 1, 512),
+    # SDXL-1024: 10 heads of 64 over 4096 tokens, 20 heads of 64 over
+    # 1024, the VAE's head over 16,384
+    "flash_sdxl_self_h10": _flash(8, 4096, 10, 64),
+    "flash_sdxl_self_h20": _flash(8, 1024, 20, 64),
+    "flash_sdxl_cross77_h10": _flash(8, 4096, 10, 64, s_k=77),
+    "flash_sdxl_cross77_h20": _flash(8, 1024, 20, 64, s_k=77),
+    "flash_sdxl_wide_vae_mid": _flash(4, 16384, 1, 512),
+    "flash_sdxl_self_h10_b2": _flash(2, 4096, 10, 64),
+    "flash_sdxl_self_h20_b2": _flash(2, 1024, 20, 64),
+    "flash_sdxl_cross77_h10_b2": _flash(2, 4096, 10, 64, s_k=77),
+    "flash_sdxl_cross77_h20_b2": _flash(2, 1024, 20, 64, s_k=77),
+    "flash_sdxl_wide_vae_mid_b1": _flash(1, 16384, 1, 512),
     # fused GN+SiLU+conv3x3 (fusedconv/w8a8 presets, conv_pad_to=128):
     # the four level shapes, then the widest skip-concat at each end
     "fused_h64_c320": _fused_conv(8, 64, 320, 320, 128),

@@ -633,14 +633,15 @@ def main():
           f"bytes={bytes_/1e9:.2f} GB -> {bytes_/dt/1e9:.0f} GB/s")
 
     # flash vs XLA attention A/B per UNet resolution — self-attn AND the
-    # S_k=77 cross-attn site (ragged-KV flash: ops/flash_attention.py::
-    # flash_cross_attention). Rows whose shape a kernel won't take fall
-    # back to the XLA path inside the dispatcher — label them so the
-    # A/B can't lie.
-    from cassmantle_tpu.ops.flash_attention import (
-        flash_attention_ok,
-        flash_cross_ok,
-    )
+    # S_k=77 cross-attn site (ragged K/V padded into the same kernel:
+    # ops/flash_attention.py::flash_plan). Rows whose shape the kernel
+    # won't take fall back to the XLA path inside the dispatcher — label
+    # them by the plan's kind so the A/B can't lie.
+    from cassmantle_tpu.ops.flash_attention import flash_plan
+
+    def label(q, k):
+        plan = flash_plan(q, k)
+        return "xla-fallback" if plan is None else plan.kind
 
     for (s, heads, d) in [(4096, 8, 40), (1024, 8, 80), (256, 8, 160),
                           (64, 8, 160)]:
@@ -649,13 +650,12 @@ def main():
             q, k, v, use_flash=True))
         xa = jax.jit(lambda q, k, v: attn_mod.multi_head_attention(
             q, k, v, use_flash=False))
-        flabel = "flash" if flash_attention_ok(q, q) else "xla-fallback"
+        flabel = label(q, q)
         tf_ = timeit(fa, q, q, q)
         tx = timeit(xa, q, q, q)
         # cross-attn: kv len 77 (flash_cross vs XLA)
         k77 = jax.random.normal(rng, (batch, 77, heads, d), jnp.bfloat16)
-        clabel = ("flash-cross" if flash_cross_ok(q, k77)
-                  else "xla-fallback")
+        clabel = label(q, k77)
         tfc = timeit(fa, q, k77, k77)
         txc = timeit(xa, q, k77, k77)
         print(f"S={s:5d} D={d:3d}: {flabel}={tf_*1e6:8.1f} us  "
