@@ -20,6 +20,10 @@ benchmark's own weights:
 Each has its limit in the configuration's file (``limits``), set between
 the program's readings and the control's (PERF.md section 2). The control
 is the same code with ``precision_mode("fp8")``.
+
+The prompt LM's reference and the image's trajectory are the functions the
+configuration's file names (``named``): no family of LM and no kind of
+sampler is known here.
 """
 
 from __future__ import annotations
@@ -29,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import reference as ref
+from .manifest import resolve
 
 
 def _tree(trees: dict, prefix: str):
@@ -39,9 +44,34 @@ def _tree(trees: dict, prefix: str):
     raise KeyError(f"no weights booked for {prefix!r}: {sorted(trees)}")
 
 
-def reference_trees(trees: dict, sizes: dict) -> dict:
+def named(config: dict, sizes: dict) -> dict:
+    """What a configuration's file gives by name, resolved at set-up.
+
+    ``prompt_lm``: ``sizes`` (the key of the LM's sizes under ``sizes``),
+    ``weights`` (the name its tree is booked under) and ``reference``, a
+    ``module:function`` with the contract ``f(params, ids (B, S),
+    positions (B, S), sizes) -> logits (B, S, V)``.
+    ``image_trajectory``: sampler kind -> ``module:function`` with the
+    contract ``f(guided, x_T, sampler) -> x_0``; reference.py holds one
+    of each and says what they owe.
+    A sampler kind for which the file names none is an error here, not
+    another kind's reference under its name."""
+    lm = config["prompt_lm"]
+    kind = sizes["sampler"]["kind"]
+    if kind not in config["image_trajectory"]:
+        raise SystemExit(
+            f"the configuration's file names no image trajectory for "
+            f"sampler kind {kind!r}: it has "
+            f"{sorted(config['image_trajectory'])}")
+    return {"lm_sizes": sizes[lm["sizes"]], "lm_weights": lm["weights"],
+            "lm_logits": resolve(lm["reference"]),
+            "trajectory": resolve(config["image_trajectory"][kind])}
+
+
+def reference_trees(trees: dict, sizes: dict, names: dict) -> dict:
     out = {"clip_text": _tree(trees, "clip_text"),
-           "vae": _tree(trees, "vae"), "gpt2": _tree(trees, "gpt2"),
+           "vae": _tree(trees, "vae"),
+           "lm": _tree(trees, names["lm_weights"]),
            "minilm": _tree(trees, "minilm")}
     if "clip_text_2" in sizes:
         out["clip_text_2"] = _tree(trees, "clip_text_2")
@@ -54,8 +84,10 @@ def reference_trees(trees: dict, sizes: dict) -> dict:
 class Reference:
     """Jitted pieces of the plain reference in one precision mode."""
 
-    def __init__(self, trees: dict, sizes: dict, mode: str = "f32") -> None:
+    def __init__(self, trees: dict, sizes: dict, names: dict,
+                 mode: str = "f32") -> None:
         self.trees, self.sizes, self.mode = trees, sizes, mode
+        self.names = names
         self._jits: dict = {}
 
     def _jit(self, name, fn):
@@ -102,16 +134,13 @@ class Reference:
         x = jax.random.normal(jax.random.PRNGKey(seed),
                               (1, lat_hw, lat_hw, 4), ref.F32)
 
-        def step(p, x, t, a_t, a_prev, context, addition):
-            eps = ref.unet(p, jnp.concatenate([x, x]), jnp.full((2,), t),
-                           context, sz["unet"], addition=addition)
-            eps = eps[:1] + s["guidance_scale"] * (eps[1:] - eps[:1])
-            return ref.ddim_step(x, eps, a_t, a_prev)
+        def guided(x, t):
+            eps = ref.unet(self.trees["unet"], jnp.concatenate([x, x]),
+                           jnp.full((2,), t), context, sz["unet"],
+                           addition=addition)
+            return eps[:1] + s["guidance_scale"] * (eps[1:] - eps[:1])
 
-        step = self._jit("unet_step", step)
-        for t, a_t, a_prev in zip(*ref.ddim_schedule(s["num_steps"])):
-            x = step(self.trees["unet"], x, t, a_t, a_prev, context,
-                     addition)
+        x = self._jit("trajectory", self.names["trajectory"])(guided, x, s)
         decode = self._jit("vae", lambda p, z: ref.to_uint8(
             ref.vae_decode(p, z, sz["vae"])))
         return np.asarray(decode(self.trees["vae"], x))[0]
@@ -128,9 +157,9 @@ class Reference:
         positions = np.asarray(
             [list(range(n_p)) + [bucket + i for i in range(n_g)]
              + [0] * pad], np.int32)
-        fn = self._jit("gpt2", lambda p, i, q: ref.gpt2_logits(
-            p, i, q, self.sizes["gpt2"]))
-        logits = fn(self.trees["gpt2"], ids, positions)[0]
+        fn = self._jit("lm", lambda p, i, q: self.names["lm_logits"](
+            p, i, q, self.names["lm_sizes"]))
+        logits = fn(self.trees["lm"], ids, positions)[0]
         return logits[n_p - 1: n_p - 1 + n_g]
 
     # -- scorer ---------------------------------------------------------------
@@ -143,12 +172,12 @@ class Reference:
 
 # -- what is compared ---------------------------------------------------------
 
-def lm_case(sizes: dict, text: str, tokens, length: int):
+def lm_case(sizes: dict, names: dict, text: str, tokens, length: int):
     """(prompt tokens, served tokens that were really decoded, bucket): the
     served path truncates the prompt, pads it to its bucket and decodes
     token i at position bucket + i; past an end-of-text the tokens are
     forced, not decoded, and are left out."""
-    g, s = sizes["gpt2"], sizes["sampler"]
+    g, s = names["lm_sizes"], sizes["sampler"]
     limit = g["max_positions"] - s["max_new_tokens"] - 1
     prompt = [t % g["vocab_size"] for t in ref.byte_tokens(text)[-limit:]]
     bucket = next((b for b in sizes["lm_prompt_buckets"]
@@ -181,15 +210,16 @@ def sample(items: list, n: int, rng, keep_last: bool = True) -> list:
     return [items[i] for i in sorted(picked)]
 
 
-def compare(book, window, trees: dict, sizes: dict, plan: dict, seed: int,
-            served=None) -> dict:
+def compare(book, window, trees: dict, sizes: dict, names: dict, plan: dict,
+            seed: int, served=None) -> dict:
     """name -> value for every number this cell compares. ``served`` is
     None for a run's own check (the book's records are the served side);
     the control passes a Reference in a lower precision, put in the
     program's place on the same inputs."""
     t0, t1 = window
     rng = np.random.RandomState(seed % (2 ** 32))
-    refm = Reference(reference_trees(trees, sizes), sizes, "f32")
+    refm = Reference(reference_trees(trees, sizes, names), sizes, names,
+                     "f32")
     ctrl = served
     out: dict = {}
 
@@ -209,7 +239,8 @@ def compare(book, window, trees: dict, sizes: dict, plan: dict, seed: int,
     rows.sort(key=lambda r: len(r[0]) + r[2])  # the longest comes last
     gaps = []
     for text, toks, length in sample(rows, plan["decodes"], rng):
-        prompt, served_toks, bucket = lm_case(sizes, text, toks, length)
+        prompt, served_toks, bucket = lm_case(sizes, names, text, toks,
+                                              length)
         logits = refm.lm_logits(prompt, served_toks, bucket)
         if ctrl is not None:
             served_toks = np.asarray(

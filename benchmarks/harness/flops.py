@@ -26,9 +26,10 @@ def _count(fn, *args) -> float:
     return tally[0]
 
 
-def image_flops(trees: dict, sizes: dict) -> dict:
-    """FLOPs of one image: text towers on (prompt, negative prompt),
-    ``num_steps`` UNet forwards at CFG batch 2, one VAE decode."""
+def image_flops(trees: dict, sizes: dict, names: dict) -> dict:
+    """FLOPs of one image: text towers on (prompt, negative prompt), the
+    UNet forwards the configuration's trajectory makes, each at the CFG
+    batch it makes it at, one VAE decode."""
     s = sizes["sampler"]
     lat = ref.latent_hw(sizes)
     ids = jax.ShapeDtypeStruct((2, s["prompt_pad_len"]), jnp.int32)
@@ -38,31 +39,49 @@ def image_flops(trees: dict, sizes: dict) -> dict:
         out["clip"] += _count(lambda p, i: ref.clip_text(
             p, i, sizes["clip_text_2"]), _shapes(trees["clip_text_2"]), ids)
     u = sizes["unet"]
-    x = jax.ShapeDtypeStruct((2, lat, lat, 4), jnp.float32)
-    t = jax.ShapeDtypeStruct((2,), jnp.int32)
-    ctx = jax.ShapeDtypeStruct((2, s["prompt_pad_len"], u["context_dim"]),
-                               jnp.float32)
-    add = (jax.ShapeDtypeStruct((2, u["addition_embed_dim"]), jnp.float32)
-           if u.get("addition_embed_dim") else None)
-    out["unet_step"] = _count(
-        lambda p, x, t, c, a: ref.unet(p, x, t, c, u, addition=a),
-        _shapes(trees["unet"]), x, t, ctx, add)
+    forward: dict = {}  # CFG batch -> FLOPs of one UNet forward
+
+    def unet_forward(batch: int) -> float:
+        if batch not in forward:
+            x = jax.ShapeDtypeStruct((batch, lat, lat, 4), jnp.float32)
+            t = jax.ShapeDtypeStruct((batch,), jnp.int32)
+            ctx = jax.ShapeDtypeStruct(
+                (batch, s["prompt_pad_len"], u["context_dim"]), jnp.float32)
+            add = (jax.ShapeDtypeStruct((batch, u["addition_embed_dim"]),
+                                        jnp.float32)
+                   if u.get("addition_embed_dim") else None)
+            forward[batch] = _count(
+                lambda p, x, t, c, a: ref.unet(p, x, t, c, u, addition=a),
+                _shapes(trees["unet"]), x, t, ctx, add)
+        return forward[batch]
+
+    def guided(x, t):
+        # the trajectory says how many forwards it makes, and at which
+        # batch, by making them: each costs a forward at twice its rows
+        ref._add(unet_forward(2 * x.shape[0]))
+        return x
+
+    out["unet_step"] = unet_forward(2)
+    out["trajectory"] = _count(
+        lambda x: names["trajectory"](guided, x, s),
+        jax.ShapeDtypeStruct((1, lat, lat, 4), jnp.float32))
     out["vae"] = _count(
         lambda p, z: ref.vae_decode(p, z, sizes["vae"]),
         _shapes(trees["vae"]),
         jax.ShapeDtypeStruct((1, lat, lat, 4), jnp.float32))
-    out["image"] = out["clip"] + s["num_steps"] * out["unet_step"] + out["vae"]
+    out["image"] = out["clip"] + out["trajectory"] + out["vae"]
     return out
 
 
-def lm_flops(trees: dict, sizes: dict, prompt_tokens: int,
+def lm_flops(trees: dict, names: dict, prompt_tokens: int,
              new_tokens: int) -> float:
     """One full causal forward over prompt + generated tokens: what the
     prefill and the cached decode steps compute between them."""
     n = prompt_tokens + new_tokens
     ids = jax.ShapeDtypeStruct((1, n), jnp.int32)
-    return _count(lambda p, i, q: ref.gpt2_logits(p, i, q, sizes["gpt2"]),
-                  _shapes(trees["gpt2"]), ids, ids)
+    return _count(
+        lambda p, i, q: names["lm_logits"](p, i, q, names["lm_sizes"]),
+        _shapes(trees["lm"]), ids, ids)
 
 
 def scorer_row_flops(trees: dict, sizes: dict) -> float:

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 
@@ -12,6 +13,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 def load_json(*parts: str) -> dict:
     with open(os.path.join(ROOT, *parts)) as f:
         return json.load(f)
+
+
+def resolve(target: str):
+    """The object a file names as ``module:attribute``."""
+    module, _, attr = target.partition(":")
+    return getattr(importlib.import_module(module), attr)
 
 
 def load_manifest() -> dict:

@@ -30,6 +30,7 @@ HI = jax.lax.Precision.HIGHEST
 
 _MODE = "f32"
 _TALLY = None  # a list [flops] while counting
+_SITES = None  # a list of attention sites (B, H, S_q, S_k, D) while counting
 _BLOCKS = None  # {(function, mode, static args): jitted} while block by block
 
 
@@ -56,6 +57,18 @@ def count_flops():
         yield _TALLY
     finally:
         _TALLY = before
+
+
+@contextlib.contextmanager
+def list_attention():
+    """Collect (B, H, S_q, S_k, D) of every attention product traced
+    inside, in order (use under ``jax.eval_shape``: nothing runs)."""
+    global _SITES
+    before, _SITES = _SITES, []
+    try:
+        yield _SITES
+    finally:
+        _SITES = before
 
 
 @contextlib.contextmanager
@@ -179,6 +192,8 @@ def attention(q, k, v, heads: int, mask=None):
     k = _operand(k).reshape(b, sk, heads, d)
     v = _operand(v).reshape(b, sk, heads, d)
     _add(4.0 * b * heads * sq * sk * d)
+    if _SITES is not None:
+        _SITES.append((b, heads, sq, sk, d))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HI) * d ** -0.5
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(F32).min)
@@ -442,10 +457,26 @@ def ddim_step(x, eps, a_t, a_prev):
     return jnp.sqrt(a_prev) * x0 + jnp.sqrt(1.0 - a_prev) * eps
 
 
+def ddim_trajectory(guided, x, sampler):
+    """x_T (B, h, w, 4) -> x_0 under ``sizes.sampler``: deterministic DDIM,
+    one guided forward a step. A configuration's file names a trajectory
+    under ``image_trajectory`` by its sampler kind; ``guided(x, t)`` is the
+    reference's UNet under classifier-free guidance, one forward at batch
+    2B a call, and the calls a trajectory makes are what the FLOP count
+    of an image multiplies a forward by (flops.image_flops)."""
+    for t, a_t, a_prev in zip(*ddim_schedule(sampler["num_steps"])):
+        x = ddim_step(x, guided(x, t), a_t, a_prev)
+    return x
+
+
 # -- GPT-2 --------------------------------------------------------------------
 
 def gpt2_logits(params, ids, positions, sz):
-    """ids, positions (B, S) -> logits (B, S, V); causal, no padding."""
+    """ids, positions (B, S) -> logits (B, S, V); causal, no padding. The
+    contract of a prompt LM's reference, which a configuration's file
+    names under ``prompt_lm``: float32, every product's operands through
+    ``_operand`` and its FLOPs through ``_add``, so that the fp8 control
+    and the FLOP count come with it."""
     p = params["params"]
     seq = ids.shape[1]
     wte = p["wte"]["embedding"].astype(F32)

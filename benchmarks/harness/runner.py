@@ -120,22 +120,21 @@ class Run:
     def build(self):
         config = self.cell.config
         self.cfg = framework_config(config, self.rehearsal)
+        running = program_sizes(self.cfg, config)
         if self.rehearsal:
-            # the tiny test size: the program's own numbers, with the two
-            # sizes only the benchmark's side of the file knows
-            self.sizes = program_sizes(self.cfg)
-            self.sizes["lm_prompt_buckets"] = config["sizes"][
-                "lm_prompt_buckets"]
+            # the tiny test size: the program's own numbers under the
+            # keys the file states
+            self.sizes = running
             self.sizes["minilm"]["seq_len"] = min(
-                config["sizes"]["minilm"]["seq_len"],
+                self.sizes["minilm"]["seq_len"],
                 self.sizes["minilm"]["max_positions"])
         else:
             self.sizes = config["sizes"]
-        if not self.rehearsal:
-            wrong = check_sizes(config["sizes"], program_sizes(self.cfg))
+            wrong = check_sizes(config["sizes"], running)
             if wrong:
                 raise SystemExit("the configuration's file and the program "
                                  "disagree:\n  " + "\n  ".join(wrong))
+        self.names = cmp.named(config, self.sizes)
         mark("building the serving stack")
         self.weights = WeightBook(self.seed)
         self.service = build_service(self.cfg, self.book, self.weights)
@@ -361,14 +360,13 @@ def device_block() -> dict:
             "memory_peak_bytes": max(peaks) if peaks else None}
 
 
-def read_layer_metrics(cell: Cell, ctx: dict, counts_only: bool) -> dict:
-    """Each per-layer metric through its reader; a reader that finds
-    nothing to read returns None and the metric is left out. A rehearsal
-    reads the program's counts and nothing that is a time or a share of
-    the device."""
+def read_layer_metrics(cell: Cell, ctx: dict, sources=None) -> dict:
+    """Each per-layer metric through its reader, of the ``sources`` given
+    or of all; a reader that finds nothing to read returns None and the
+    metric is left out."""
     out = {}
     for metric in cell.per_layer:
-        if counts_only and metric["source"] != "program_counter":
+        if sources is not None and metric["source"] not in sources:
             continue
         spec = cell.reader_spec(metric["name"])
         reader = importlib.import_module(
@@ -389,20 +387,29 @@ def run_window(cell: Cell, seed: int, seconds: float, trace: bool,
     res = run.results(measured)
     res["setup_s"] = measured["t_open"] - t_start
     res["device"] = device_block()
-    ctx = dict(res, cell=cell, sizes=run.sizes,
+    ctx = dict(res, cell=cell, sizes=run.sizes, names=run.names,
                device_kind=res["device"]["kind"],
-               trees=cmp.reference_trees(run.weights.trees, run.sizes))
+               trees=cmp.reference_trees(run.weights.trees, run.sizes,
+                                         run.names))
     if trace:
         from . import trace as trace_mod
 
         mark("reading the trace")
         res["trace"] = ctx["trace"] = trace_mod.reduce_xplane(TRACE_DIR)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    res["layer"] = (read_layer_metrics(cell, ctx, rehearsal) if trace
-                    else {})
+    # a rehearsal reads the program's counts and nothing that is a time or
+    # a share of the device; an untraced run also the program's spans,
+    # which cost it nothing, for its notes: where a run reads far off,
+    # they say whether rounds, images or waits grew
+    if rehearsal:
+        sources = {"program_counter"}
+    else:
+        sources = None if trace else {"program_counter", "program_span"}
+    res["layer"] = read_layer_metrics(cell, ctx, sources)
     mark("metrics read; freeing the program's state")
     # the program's state goes before the reference comes
-    res.update(book=run.book, sizes=run.sizes, trees=run.weights.trees,
+    res.update(book=run.book, sizes=run.sizes, names=run.names,
+               trees=run.weights.trees,
                span=(measured["t_open"], measured["t_close"]))
     run.service = run.backend = None
     gc.collect()
@@ -414,7 +421,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     """The whole run; returns the result line as a dict."""
     res = run_window(cell, seed, seconds, trace, rehearsal, t_start)
     values = cmp.compare(res["book"], res["span"], res["trees"],
-                         res["sizes"], cell.config["check"], seed)
+                         res["sizes"], res["names"], cell.config["check"],
+                         seed)
     values["compiles_in_window"] = res["compiles_in_window"]
     limits = dict(cell.config["limits"], compiles_in_window=0)
     correct, checks = cmp.verdict(
@@ -451,5 +459,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
         "failure_counters", "round_errors", "text_fallbacks",
         "score_depth_at_close",
         "generator_late_p95_ms", "score_p50_ms") if res.get(k) is not None}
+    if not trace and not rehearsal:
+        line["notes"]["spans"] = {n: m["value"]
+                                  for n, m in res["layer"].items()}
     line["checks"] = checks
     return line

@@ -4,12 +4,13 @@ with the benchmark's recorders at the two seams the program offers."""
 from __future__ import annotations
 
 import dataclasses
-import importlib
 import threading
 import time
 
 import jax
 import numpy as np
+
+from .manifest import resolve
 
 
 class Abandoned(Exception):
@@ -71,53 +72,52 @@ def record_decodes(prompt_gen, book: Book) -> None:
 
 def framework_config(config: dict, rehearsal: bool):
     """The FrameworkConfig a configuration file names."""
-    target = config["rehearsal_factory" if rehearsal else "factory"]
-    module, _, attr = target.partition(":")
-    cfg = getattr(importlib.import_module(module), attr)()
+    cfg = resolve(config["rehearsal_factory" if rehearsal else "factory"])()
     for group, fields in config.get("overrides", {}).items():
         cfg = cfg.replace(**{group: dataclasses.replace(
             getattr(cfg, group), **fields)})
     return cfg
 
 
-def program_sizes(cfg) -> dict:
-    """The program's configuration in the shape of a config file's
-    ``sizes``: what the reference is checked against, never what it uses."""
-    m, s = cfg.models, cfg.sampler
+def program_sizes(cfg, config: dict) -> dict:
+    """The program's configuration under the keys the file's ``sizes``
+    state, each read from the program's dataclass by that name: what the
+    file is checked against, and what a rehearsal at the tiny size runs
+    on. A group is the attribute of ``cfg.models`` of its name (the prompt
+    LM's: the one ``prompt_lm.program_config`` names; ``sampler``:
+    ``cfg.sampler``). A stated key the program lacks is an error, unless
+    the file lists it under ``own_sizes`` as the benchmark's own: those
+    are taken from the file."""
+    lm = config["prompt_lm"]
+    own = set(config.get("own_sizes", ()))
 
-    def fields(obj, names):
-        out = {}
-        for n in names:
-            v = getattr(obj, n)
-            out[n] = list(v) if isinstance(v, tuple) else v
-        return out
+    def read(obj, key: str, path: str):
+        if not hasattr(obj, key):
+            raise SystemExit(
+                f"the configuration's file states {path}, which the "
+                f"program's {type(obj).__name__} lacks; if it is the "
+                f"benchmark's own, list it under own_sizes")
+        value = getattr(obj, key)
+        return list(value) if isinstance(value, tuple) else value
 
-    sizes = {
-        "clip_text": fields(m.clip_text, (
-            "vocab_size", "hidden_size", "intermediate_size", "num_layers",
-            "num_heads", "max_positions", "hidden_act")),
-        "unet": fields(m.unet, (
-            "base_channels", "channel_mults", "attention_levels",
-            "transformer_depth", "blocks_per_level", "num_heads",
-            "context_dim", "time_embed_dim", "addition_embed_dim", "dtype")),
-        "vae": fields(m.vae, (
-            "base_channels", "channel_mults", "blocks_per_level",
-            "scaling_factor", "dtype")),
-        "gpt2": fields(m.gpt2, (
-            "vocab_size", "hidden_size", "num_layers", "num_heads",
-            "max_positions", "dtype")),
-        "minilm": fields(m.minilm, (
-            "vocab_size", "hidden_size", "intermediate_size", "num_layers",
-            "num_heads", "max_positions", "dtype")),
-        "sampler": fields(s, (
-            "kind", "num_steps", "guidance_scale", "eta", "image_size",
-            "negative_prompt", "max_new_tokens", "prompt_pad_len",
-            "text_temperature")),
-        "param_dtype": m.param_dtype,
-    }
-    if m.clip_text_2 is not None:
-        sizes["clip_text_2"] = fields(m.clip_text_2, tuple(
-            sizes["clip_text"]))
+    def holder(group: str):
+        if group == "sampler":
+            return cfg.sampler
+        return read(cfg.models, lm["program_config"]
+                    if group == lm["sizes"] else group, group)
+
+    sizes = {}
+    for group, stated in config["sizes"].items():
+        if group in own:
+            sizes[group] = stated
+        elif isinstance(stated, dict):
+            obj = holder(group)
+            sizes[group] = {
+                key: value if f"{group}.{key}" in own
+                else read(obj, key, f"{group}.{key}")
+                for key, value in stated.items()}
+        else:
+            sizes[group] = read(cfg.models, group, group)
     return sizes
 
 
@@ -125,8 +125,6 @@ def check_sizes(stated: dict, running: dict, path: str = "") -> list:
     """Every size the file states has to be the one that runs."""
     wrong = []
     for key, want in stated.items():
-        if key not in running:
-            continue  # benchmark-side sizes (seq_len, buckets)
         have = running[key]
         if isinstance(want, dict):
             wrong += check_sizes(want, have, f"{path}{key}.")

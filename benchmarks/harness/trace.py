@@ -1,15 +1,18 @@
-"""From a profiler trace to busy time, idle share, top operations and what
-the host was doing in the longest idle gaps.
+"""From a profiler trace to busy time, idle share, top operations, what
+the host was doing in the longest idle gaps, and the table of device
+instructions that the per-layer readers draw on.
 
-The reduction works on plain intervals (start_ns, duration_ns, name) so a
-test can hand it a trace it wrote itself; ``load_xplane`` turns the
-profiler's ``.xplane.pb`` into those intervals.
+The reduction works on plain intervals (start_ns, duration_ns, name[, hlo,
+scope]) so a test can hand it a trace it wrote itself; ``load_xplane``
+turns the profiler's ``.xplane.pb`` into those intervals.
 """
 
 from __future__ import annotations
 
 import glob
 import os
+
+from .xplane import SCOPE_STAT, load_planes
 
 
 def union_ns(intervals) -> list:
@@ -24,27 +27,42 @@ def union_ns(intervals) -> list:
 
 
 def reduce_trace(device_ops, host_spans, window=None, top: int = 10) -> dict:
-    """device_ops / host_spans: lists of (start_ns, duration_ns, name).
+    """host_spans: (start_ns, duration_ns, name); device_ops the same,
+    or with the event's whole HLO text and its scope after the name.
 
     ``window`` (start_ns, end_ns) defaults to the span of the device ops.
     Busy is the union of device-op intervals clipped to the window; a gap
     is a maximal idle stretch inside it, named after the host span that
-    covers most of it ("no_benchmark_span" when none does)."""
+    covers most of it ("no_benchmark_span" when none does).
+
+    ``instructions`` is for the readers, not for the result line: seconds
+    and calls of every device instruction, keyed by its short name with
+    what tells one call site from another, the event's whole HLO text (its
+    result and operand shapes) and its scope. It counts the calls that ran
+    whole inside the window, each with its whole time, so that seconds
+    over calls is the time of a call."""
     if not device_ops:
         return {}
     if window is None:
-        window = (min(s for s, _, _ in device_ops),
-                  max(s + d for s, d, _ in device_ops))
+        window = (min(op[0] for op in device_ops),
+                  max(op[0] + op[1] for op in device_ops))
     w0, w1 = window
-    clipped = [(max(s, w0), min(s + d, w1)) for s, d, _ in device_ops
-               if s + d > w0 and s < w1]
+    clipped = [(max(op[0], w0), min(op[0] + op[1], w1)) for op in device_ops
+               if op[0] + op[1] > w0 and op[0] < w1]
     busy = union_ns(clipped)
     busy_ns = sum(e - s for s, e in busy)
     by_op: dict = {}
-    for s, d, name in device_ops:
+    by_instruction: dict = {}
+    for s, d, name, *site in device_ops:
         lo, hi = max(s, w0), min(s + d, w1)
-        if hi > lo and name not in CONTAINERS:
-            by_op[name] = by_op.get(name, 0) + (hi - lo)
+        if hi <= lo or name in CONTAINERS:
+            continue
+        by_op[name] = by_op.get(name, 0) + (hi - lo)
+        if hi - lo == d:
+            hlo, scope = (site + ["", ""])[:2]
+            row = by_instruction.setdefault((name, hlo, scope), [0, 0])
+            row[0] += d
+            row[1] += 1
     gaps, cursor = [], w0
     for s, e in busy + [[w1, w1]]:
         if s > cursor:
@@ -70,6 +88,10 @@ def reduce_trace(device_ops, host_spans, window=None, top: int = 10) -> dict:
         "device_ops": ranked(by_op),
         "idle_gaps": ranked(by_gap),
         "n_device_ops": len(device_ops),
+        "instructions": [
+            {"name": name, "hlo": hlo, "scope": scope, "seconds": ns / 1e9,
+             "calls": calls}
+            for (name, hlo, scope), (ns, calls) in by_instruction.items()],
     }
 
 
@@ -91,27 +113,24 @@ HOST_SPAN_PREFIX = "bench."
 
 def load_xplane(trace_dir: str):
     """(per-device op lists, host spans) of the newest trace under
-    ``trace_dir``."""
-    from jax.profiler import ProfileData
-
+    ``trace_dir``; a device op is (start_ns, duration_ns, short name,
+    whole HLO text, scope)."""
     paths = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    data = ProfileData.from_file(paths[-1])
     devices, host = {}, []
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            ops = devices.setdefault(plane.name, [])
-            for line in plane.lines:
-                if line.name in OP_LINES:
-                    ops += [(int(ev.start_ns), int(ev.duration_ns),
-                             short_name(ev.name)) for ev in line.events]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host += [(int(ev.start_ns), int(ev.duration_ns), ev.name)
-                         for ev in line.events
-                         if ev.name.startswith(HOST_SPAN_PREFIX)]
+    for plane in load_planes(paths[-1]):
+        if plane["name"].startswith("/device:TPU:"):
+            ops = devices.setdefault(plane["name"], [])
+            for line in plane["lines"]:
+                if line["name"] in OP_LINES:
+                    ops += [(s, d, short_name(n), n, st.get(SCOPE_STAT, ""))
+                            for s, d, n, st in line["events"]]
+        elif plane["name"].startswith("/host:"):
+            for line in plane["lines"]:
+                host += [(s, d, n) for s, d, n, _st in line["events"]
+                         if n.startswith(HOST_SPAN_PREFIX)]
     return devices, host
 
 

@@ -9,10 +9,10 @@ from benchmarks.harness import flops, peaks
 def read(ctx: dict, args: dict):
     if ctx["rounds"] <= 0:
         return None
-    sizes, trees = ctx["sizes"], ctx["trees"]
-    per_round = flops.image_flops(trees, sizes)["image"] + flops.lm_flops(
-        trees, sizes, args["lm_prompt_tokens"],
-        sizes["sampler"]["max_new_tokens"])
+    sizes, trees, names = ctx["sizes"], ctx["trees"], ctx["names"]
+    per_round = flops.image_flops(trees, sizes, names)["image"] \
+        + flops.lm_flops(trees, names, args["lm_prompt_tokens"],
+                         sizes["sampler"]["max_new_tokens"])
     total = per_round * ctx["rounds"]
     device_rows = ctx["window"].counter("scorer.embed_cache_misses")
     if device_rows:

@@ -57,10 +57,10 @@ def play_run(guess_cell):
     kept = {}
     real = cmp.compare
 
-    def keeping(book, window, trees, sizes, plan, seed, **kw):
+    def keeping(book, window, trees, sizes, names, plan, seed, **kw):
         kept.update(book=book, window=window, trees=trees, sizes=sizes,
-                    plan=plan, seed=seed)
-        return real(book, window, trees, sizes, plan, seed, **kw)
+                    names=names, plan=plan, seed=seed)
+        return real(book, window, trees, sizes, names, plan, seed, **kw)
 
     cmp.compare = keeping
     try:
@@ -79,11 +79,12 @@ def test_the_control_is_not_correct(play_run, guess_cell):
     it, below."""
     line, kept = play_run
     assert line["correct"] is True and line["counts"]["guess_calls"] > 10
-    sizes = kept["sizes"]
-    control = cmp.Reference(cmp.reference_trees(kept["trees"], sizes),
-                            sizes, "fp8")
+    sizes, names = kept["sizes"], kept["names"]
+    control = cmp.Reference(
+        cmp.reference_trees(kept["trees"], sizes, names), sizes, names,
+        "fp8")
     values = cmp.compare(kept["book"], kept["window"], kept["trees"], sizes,
-                         kept["plan"], kept["seed"], served=control)
+                         names, kept["plan"], kept["seed"], served=control)
     correct, checks = cmp.verdict(
         values, guess_cell.config["limits"],
         cmp.required_numbers(guess_cell.config, guess_cell.traffic))

@@ -1,4 +1,5 @@
-"""The six per-layer metrics that read the round's spans (PR 26): each
+"""The per-layer metrics that read the round's spans (PR 26) and the
+kernel's share of its roofline (PR 28): each
 entry of BENCHMARK.json resolves to its file and reader, and a rehearsal
 of the cell reads the one that is a count."""
 
@@ -28,6 +29,8 @@ ROUND_METRICS = {
                          "hist_mean", "pipeline.image_batch_size"),
     "flash_kernel_pct": ("%", "lower", "device_trace", "kernels",
                          "trace_op_pct", "flash_attention"),
+    "flash_roofline_pct": ("%", "higher", "device_trace", "kernels",
+                           "attention_roofline", "flash_attention"),
 }
 
 
@@ -52,7 +55,7 @@ def test_the_new_entries_are_appended_after_the_accepted_five():
     names = [m["name"] for m in M["per_layer"]]
     assert names[:5] == ["image_ms", "lm_ms", "prompt_batch_mean",
                          "mfu.round", "device_idle_pct"]
-    assert names[5:11] == list(ROUND_METRICS)
+    assert names[5:12] == list(ROUND_METRICS)
 
 
 def test_a_rehearsal_reads_the_image_batch_count():

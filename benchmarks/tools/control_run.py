@@ -42,13 +42,13 @@ def main() -> int:
         res = run_window(cell, seed, args.seconds, False, args.platform_cpu,
                          time.perf_counter())
         common = (res["book"], res["span"], res["trees"], res["sizes"],
-                  cell.config["check"], seed)
+                  res["names"], cell.config["check"], seed)
         t0 = time.perf_counter()
         program = cmp.compare(*common)
         t1 = time.perf_counter()
         control = cmp.compare(*common, served=cmp.Reference(
-            cmp.reference_trees(res["trees"], res["sizes"]), res["sizes"],
-            "fp8"))
+            cmp.reference_trees(res["trees"], res["sizes"], res["names"]),
+            res["sizes"], res["names"], "fp8"))
         limits = cell.config["limits"]
         required = cmp.required_numbers(cell.config, cell.traffic)
         print(json.dumps({

@@ -20,7 +20,7 @@ sys.path.insert(0, ROOT)
 def main() -> int:
     import jax
 
-    from benchmarks.harness import compare, flops
+    from benchmarks.harness import compare, flops, rooflines
     from benchmarks.harness.manifest import load_json
     from benchmarks.harness.stack import framework_config
     from benchmarks.harness.weights import WeightBook
@@ -48,11 +48,14 @@ def main() -> int:
     pipeline.PromptGenerator(cfg, None)
     scorer.EmbeddingScorer(cfg.models.minilm, table=None)
     sizes = config["sizes"]
-    trees = compare.reference_trees(book.trees, sizes)
-    out = flops.image_flops(trees, sizes)
+    names = compare.named(config, sizes)
+    trees = compare.reference_trees(book.trees, sizes, names)
+    out = flops.image_flops(trees, sizes, names)
     out["lm_24_prompt_96_new"] = flops.lm_flops(
-        trees, sizes, 24, sizes["sampler"]["max_new_tokens"])
+        trees, names, 24, sizes["sampler"]["max_new_tokens"])
     out["scorer_row"] = flops.scorer_row_flops(trees, sizes)
+    out["attention_sites_b_sq_sk_hd"] = sorted(
+        rooflines.attention_sites(trees, sizes, names))
     out["params"] = {k: sum(int(x.size) for x in jax.tree_util.tree_leaves(v))
                      for k, v in trees.items()}
     print(json.dumps(out, indent=1))

@@ -2,8 +2,8 @@
 """`lm_logit_gap` read on many seeds in one process, at the cell's own LM
 size and without the image path (a full run is 2 minutes a seed).
 
-For each seed: GPT-2 weights from the seed, then every title of the mix's
-seed file and the stand-in text decoded through the program's own
+For each seed: the prompt LM's weights from the seed, then every title of
+the mix's seed file and the stand-in text decoded through the program's own
 ``PromptGenerator.decode_ids_batch`` (the call the prompt queue's handler
 makes, the same compiled programs, batches of 1, 2 and 4). Per prompt, three
 readings against the float32 reference:
@@ -55,11 +55,10 @@ def main() -> int:
 
     cell = Cell(load_manifest(), args.workload)
     cfg = framework_config(cell.config, args.platform_cpu)
-    sizes = cell.config["sizes"]
-    if args.platform_cpu:
-        sizes = dict(program_sizes(cfg),
-                     lm_prompt_buckets=sizes["lm_prompt_buckets"])
-    vocab = sizes["gpt2"]["vocab_size"]
+    sizes = (program_sizes(cfg, cell.config) if args.platform_cpu
+             else cell.config["sizes"])
+    names = cmp.named(cell.config, sizes)
+    vocab = names["lm_sizes"]["vocab_size"]
     texts = tr.lines(cell.traffic["seed_file"]) + [STAND_IN]
     served_by = None
     for seed in (int(s) for s in args.seeds.split(",")):
@@ -77,13 +76,14 @@ def main() -> int:
             tokens += list(np.asarray(t))
             lengths += list(np.asarray(k))
             i += n
-        tree = {"gpt2": book.trees["gpt2"]}
-        f32 = cmp.Reference(tree, sizes, "f32")
-        fp8 = cmp.Reference(tree, sizes, "fp8")
+        tree = {"lm": book.trees[names["lm_weights"]]}
+        f32 = cmp.Reference(tree, sizes, names, "f32")
+        fp8 = cmp.Reference(tree, sizes, names, "fp8")
         rows = {"program": [], "control_fp8": [], "wrong_low": [],
                 "wrong_high": [], "served": []}
         for text, toks, length in zip(texts, tokens, lengths):
-            prompt, served, bucket = cmp.lm_case(sizes, text, toks, length)
+            prompt, served, bucket = cmp.lm_case(sizes, names, text, toks,
+                                                 length)
             served = np.asarray(served)
             logits = f32.lm_logits(prompt, served, bucket)
             first = np.asarray(
