@@ -174,6 +174,67 @@ class MistralConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Qwen3NextConfig:
+    """Qwen3-Next-class causal LM (models/qwen3_next.py): three Gated
+    DeltaNet (linear attention) layers to one gated full-attention layer,
+    a sparse expert block in every layer. Field names are the published
+    ``config.json``'s; defaults are Qwen3-Next-80B-A3B-Instruct's widths.
+
+    ``experts_held`` / ``first_expert`` say which of the ``num_experts``
+    routed experts this chip holds (expert parallelism: the router keeps
+    its published width and top-k, the layer computes its own experts'
+    part). ``vocab_size`` is the rows of the vocabulary held here.
+    ``tiny()`` is the CPU-test variant.
+    """
+
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    full_attention_interval: int = 4
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 10000000.0
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    max_position_embeddings: int = 262144
+    experts_held: int = 512
+    first_expert: int = 0
+    dtype: str = "bfloat16"
+
+    @property
+    def max_positions(self) -> int:
+        """The name the serving layer knows the position limit by."""
+        return self.max_position_embeddings
+
+    def is_full_attention(self, layer: int) -> bool:
+        return (layer + 1) % self.full_attention_interval == 0
+
+    @staticmethod
+    def tiny() -> "Qwen3NextConfig":
+        """One period, 8 experts top-2, all held."""
+        return Qwen3NextConfig(
+            vocab_size=300, hidden_size=32, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=8, linear_value_head_dim=8,
+            num_experts=8, num_experts_per_tok=2, moe_intermediate_size=16,
+            shared_expert_intermediate_size=16,
+            max_position_embeddings=128, experts_held=8, dtype="float32",
+        )
+
+
+@dataclasses.dataclass(frozen=True)
 class MiniLMConfig:
     """all-MiniLM-L6-v2-class sentence encoder for guess scoring."""
 
@@ -198,6 +259,10 @@ class ModelZooConfig:
     # generates story episodes with it instead of GPT-2 (the reference's
     # actual LLM family, backend.py:25).
     mistral: Optional[MistralConfig] = None
+    # Optional Qwen3-Next-class prompt LM (linear + full attention layers,
+    # sparse experts); when set it is the prompt LM. Weights-only int8,
+    # W8A8 and speculative decode are refused for it (serving/pipeline.py).
+    qwen3_next: Optional[Qwen3NextConfig] = None
     minilm: MiniLMConfig = dataclasses.field(default_factory=MiniLMConfig)
     # Directory holding safetensors checkpoints; None -> deterministic
     # random-init (fixed PRNG) so the full pipeline runs without artifacts.
@@ -845,6 +910,24 @@ def lcm_serving_config() -> FrameworkConfig:
         sampler=SamplerConfig(consistency=True, num_steps=4))
 
 
+def qwen3next_game_config() -> FrameworkConfig:
+    """The game with a Qwen3-Next-class story model and a distilled
+    few-step image model: one chip's share of a deployment in which four
+    chips share each layer of Qwen3-Next-80B-A3B-Instruct (experts and
+    vocabulary divided over the four, everything else replicated; further
+    layers on further hosts as pipeline stages). Held here: two periods of
+    (linear, linear, linear, full) at the published widths, experts
+    [0, 128) of 512, vocabulary rows [0, 37984) of 151936: 3.67 B
+    parameters. The image side is ``lcm_serving_config``'s sampler, so
+    that the prompt LM is most of a round's device time."""
+
+    return FrameworkConfig(
+        models=ModelZooConfig(qwen3_next=Qwen3NextConfig(
+            num_hidden_layers=8, experts_held=128, first_expert=0,
+            vocab_size=37984)),
+        sampler=SamplerConfig(consistency=True, num_steps=4))
+
+
 def deepcache_serving_config() -> FrameworkConfig:
     """DDIM-50 with deep-feature reuse (SamplerConfig.deepcache): the
     full 50-step trajectory at ~60% of the UNet compute — alternate
@@ -890,6 +973,16 @@ def test_config() -> FrameworkConfig:
         game=GameConfig(time_per_prompt=2.0, lock_timeout=5.0,
                         acquire_timeout=0.5),
     )
+
+
+def test_qwen3next_config() -> FrameworkConfig:
+    """``qwen3next_game_config`` at the CPU-test size."""
+
+    base = test_config()
+    return base.replace(
+        models=dataclasses.replace(base.models,
+                                   qwen3_next=Qwen3NextConfig.tiny()),
+        sampler=dataclasses.replace(base.sampler, consistency=True))
 
 
 def test_sdxl_config() -> FrameworkConfig:

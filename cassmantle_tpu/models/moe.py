@@ -17,6 +17,11 @@ static shapes, so XLA can lay the expert dim out across the mesh:
 
 ``MoEMLP`` drops in anywhere a TransformerMLP fits; ``expert_specs`` gives
 the ``P("ep", ...)`` param specs for mesh placement.
+
+``HeldExperts`` is the serving-side expert layer (models/qwen3_next.py):
+top-k routing over the published number of experts, told which contiguous
+range of them this chip holds, no capacity and no drop; it computes its
+own experts' part of the result and says what it routed.
 """
 
 from __future__ import annotations
@@ -93,6 +98,131 @@ class MoEMLP(nn.Module):
         )
         self.sow("aux_loss", "load_balance", e * jnp.sum(me * ce))
         return out.reshape(b, s, d).astype(x.dtype)
+
+
+class HeldExperts(nn.Module):
+    """Top-k routed SwiGLU experts of which ``experts_held`` live here,
+    ids ``[first_expert, first_expert + experts_held)``, plus an optional
+    sigmoid-gated shared expert: x (T, D) -> (out (T, D) float32, stats).
+
+    The router scores all ``num_experts`` in float32 and keeps the
+    ``top_k`` best, weights renormalised over the k when
+    ``norm_topk_prob``; an assignment to an absent expert adds nothing
+    (another chip's part). No assignment is dropped: ``dense=True``
+    runs every held expert over every token and combines by the routing
+    weights (prefill: with hundreds of tokens every expert is touched
+    anyway); ``dense=False`` walks the assignments that landed here, one
+    expert's weights a step (decode: a handful of rows touch a handful
+    of the held experts, and the step is bound by the weights it reads).
+    ``real`` (T,) marks tokens that count: padding is neither computed in
+    the walk nor counted in ``stats`` (``assignments``,
+    ``assignments_held``, ``experts_touched``, ``load`` (experts_held,))."""
+
+    num_experts: int
+    experts_held: int
+    first_expert: int
+    top_k: int
+    intermediate: int
+    shared_intermediate: int = 0
+    norm_topk_prob: bool = True
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array, real: jax.Array, dense: bool):
+        t, d = x.shape
+        held_n, k, f = self.experts_held, self.top_k, self.intermediate
+        hi = jax.lax.Precision.HIGHEST
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        x32 = x.astype(jnp.float32)
+        xb = x.astype(self.dtype)
+
+        with jax.named_scope("moe_router"):
+            router = self.param("router", nn.initializers.lecun_normal(),
+                                (d, self.num_experts), jnp.float32)
+            probs = jax.nn.softmax(jnp.dot(
+                x32, router.astype(jnp.float32), precision=hi), axis=-1)
+            top_p, top_i = jax.lax.top_k(probs, k)              # (T, k)
+            if self.norm_topk_prob:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            local = top_i - self.first_expert
+            here = (local >= 0) & (local < held_n) & real[:, None]
+            local = jnp.clip(local, 0, held_n - 1)
+            load = jnp.zeros((held_n,), jnp.int32).at[local.reshape(-1)].add(
+                here.reshape(-1).astype(jnp.int32))
+            stats = {
+                "assignments": jnp.sum(real).astype(jnp.int32) * k,
+                "assignments_held": jnp.sum(here).astype(jnp.int32),
+                "experts_touched": jnp.sum(load > 0).astype(jnp.int32),
+                "load": load,
+            }
+
+        with jax.named_scope("moe_experts"):
+            gate_up = self.param("gate_up", init, (held_n, d, 2 * f),
+                                 jnp.float32).astype(self.dtype)
+            down = self.param("down", init, (held_n, f, d),
+                              jnp.float32).astype(self.dtype)
+            if dense:
+                combine = jnp.zeros((t, held_n), jnp.float32).at[
+                    jnp.arange(t)[:, None], local].add(
+                        jnp.where(here, top_p, 0.0))
+                gu = jnp.einsum("td,edf->tef", xb, gate_up,
+                                preferred_element_type=jnp.float32)
+                h = nn.silu(gu[..., :f]) * gu[..., f:] * combine[..., None]
+                out = jnp.einsum("tef,efd->td", h.astype(self.dtype), down,
+                                 preferred_element_type=jnp.float32)
+            else:
+                out = self._walk(xb, gate_up, down, local.reshape(-1),
+                                 top_p.reshape(-1), here.reshape(-1))
+
+        if self.shared_intermediate:
+            with jax.named_scope("moe_shared"):
+                fs = self.shared_intermediate
+                dense_init = nn.initializers.lecun_normal()
+                s_gate_up = self.param("shared_gate_up", dense_init,
+                                       (d, 2 * fs), jnp.float32)
+                s_down = self.param("shared_down", dense_init, (fs, d),
+                                    jnp.float32)
+                s_gate = self.param("shared_gate", dense_init, (d, 1),
+                                    jnp.float32)
+                gu = jnp.dot(xb, s_gate_up.astype(self.dtype),
+                             preferred_element_type=jnp.float32)
+                h = nn.silu(gu[:, :fs]) * gu[:, fs:]
+                shared = jnp.dot(h.astype(self.dtype),
+                                 s_down.astype(self.dtype),
+                                 preferred_element_type=jnp.float32)
+                out = out + shared * jax.nn.sigmoid(jnp.dot(
+                    x32, s_gate.astype(jnp.float32), precision=hi))
+        return out, stats
+
+    def _walk(self, xb, gate_up, down, expert, weight, here):
+        """One assignment a step, those that landed here first and in
+        their row's own order, so that a row's sum does not depend on its
+        company; the trip count is the number that landed."""
+        f = self.intermediate
+        top_k = expert.shape[0] // xb.shape[0]
+        order = jnp.argsort(~here, stable=True)
+
+        def body(i, acc):
+            slot = order[i]
+            row = slot // top_k
+            e = expert[slot]
+            gu = jnp.dot(
+                jax.lax.dynamic_slice_in_dim(xb, row, 1, axis=0),
+                jax.lax.dynamic_index_in_dim(gate_up, e, keepdims=False),
+                preferred_element_type=jnp.float32)
+            h = nn.silu(gu[:, :f]) * gu[:, f:]
+            y = jnp.dot(h.astype(self.dtype),
+                        jax.lax.dynamic_index_in_dim(down, e,
+                                                     keepdims=False),
+                        preferred_element_type=jnp.float32)
+            return jax.lax.dynamic_update_slice_in_dim(
+                acc, jax.lax.dynamic_slice_in_dim(acc, row, 1, axis=0)
+                + weight[slot] * y, row, axis=0)
+
+        return jax.lax.fori_loop(
+            0, jnp.sum(here), body,
+            jnp.zeros((xb.shape[0], down.shape[-1]), jnp.float32))
 
 
 def expert_specs(params) -> dict:

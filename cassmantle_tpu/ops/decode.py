@@ -27,8 +27,8 @@ def make_apply_fns(model):
     (params threaded first so weights stay traced jit arguments)."""
     cls = type(model)
 
-    def prefill(params, ids, prompt_len, max_len):
-        return model.apply(params, ids, prompt_len, max_len,
+    def prefill(params, ids, prompt_len, max_len, *row_mask):
+        return model.apply(params, ids, prompt_len, max_len, *row_mask,
                            method=cls.prefill)
 
     def decode_step(params, token, index, cache, valid):
@@ -48,7 +48,8 @@ def make_apply_pair(model):
     return make_apply_fns(model)[:2]
 
 
-@partial(named_jit, name="lm_decode", static_argnums=(0, 5, 6, 7, 8))
+@partial(named_jit, name="lm_decode", static_argnums=(0, 5, 6, 7, 8),
+         static_argnames=("cache_stats",))
 def greedy_decode(
     model_apply_pair,          # (prefill_fn, decode_step_fn), static; both
                                # take ``params`` first so weights enter the
@@ -62,8 +63,17 @@ def greedy_decode(
     eos_token: int,
     temperature: float = 0.0,
     top_k: int = 40,
-) -> Tuple[jax.Array, jax.Array]:
+    row_mask=None,             # (B,) True = a request, False = batch padding
+    cache_stats=None,          # static: final cache -> tree of counters
+):
     """Returns (generated (B, max_new_tokens), gen_len (B,)).
+
+    The cache is whatever tree the model's ``prefill`` returns; the scan
+    only carries it. A model whose cache counts what it did (a sparse
+    LM's routing, models/qwen3_next.py) names ``cache_stats``: its
+    ``prefill`` is then told which rows are requests (``row_mask``) and
+    the counters of the final cache come back as a third result, with
+    the tokens and so without a host sync of their own.
 
     ``temperature=0`` (default) is exact greedy argmax — the reference's
     hosted text-generation call decodes greedily (no sampling params,
@@ -75,8 +85,9 @@ def greedy_decode(
     max_len = p + max_new_tokens
 
     with jax.named_scope("lm_prefill"):
-        last_logits, cache = prefill_fn(params, input_ids, prompt_len,
-                                        max_len)
+        last_logits, cache = prefill_fn(
+            params, input_ids, prompt_len, max_len,
+            *(() if cache_stats is None else (row_mask,)))
 
     positions = jnp.arange(max_len)[None, :]          # (1, L)
     prompt_valid = positions < prompt_len[:, None]     # (B, L)
@@ -111,7 +122,7 @@ def greedy_decode(
         return (logits, cache, done), emitted
 
     init_done = jnp.zeros((b,), dtype=bool)
-    (_, _, _), tokens = jax.lax.scan(
+    (_, cache, _), tokens = jax.lax.scan(
         step, (last_logits, cache, init_done), jnp.arange(max_new_tokens)
     )
     tokens = tokens.T  # (B, max_new_tokens)
@@ -121,7 +132,9 @@ def greedy_decode(
         jnp.argmax(is_eos, axis=1),
         jnp.int32(max_new_tokens),
     )
-    return tokens, gen_len
+    if cache_stats is None:
+        return tokens, gen_len
+    return tokens, gen_len, cache_stats(cache)
 
 
 # -- speculative decoding ---------------------------------------------------

@@ -16,7 +16,14 @@ import os
 import random
 import threading
 from functools import partial, wraps
-from typing import List, Optional, Sequence
+from typing import (
+    Callable,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -329,6 +336,27 @@ def note_consistency_counter(sampler_cfg, n_images: int) -> None:
     if sampler_cfg.consistency and not consistency_disabled():
         metrics.inc("pipeline.consistency_steps",
                     sampler_cfg.num_steps * n_images)
+
+
+def note_moe_counters(routed) -> None:
+    """Publish what a sparse prompt LM's expert layers routed in the
+    dispatches just synced (``cache_stats`` trees, ops/decode.py; they
+    came back with the tokens, so this transfer waits for nothing):
+    ``moe.assignments`` (real rows x tokens x layers x top-k),
+    ``moe.assignments_held`` (those that landed on an expert held here),
+    ``moe.experts_touched`` (held experts with at least one real token,
+    summed over the expert layers' calls) and the gauge
+    ``moe.load_max_over_mean`` over the held experts of a dispatch."""
+    if not routed:
+        return
+    # ONE transfer for every dispatch of the batch, already computed
+    trees = jax.device_get(routed)
+    for name in ("assignments", "assignments_held", "experts_touched"):
+        metrics.inc("moe." + name, sum(t[name].item() for t in trees))
+    load = trees[-1]["load"]
+    if load.sum() > 0:
+        metrics.gauge("moe.load_max_over_mean",
+                      load.max().item() / load.mean().item())
 
 
 def note_w8a8_counter(models_cfg, sampler_cfg, n_images: int) -> None:
@@ -1162,22 +1190,65 @@ class Text2ImagePipeline:
         return out
 
 
+def _dense_params(tree) -> int:
+    from cassmantle_tpu.obs import costmodel
+
+    return costmodel.params_count(tree)
+
+
+class LMFamily(NamedTuple):
+    """One prompt-LM family as ``PromptGenerator`` needs it: ``name`` (the
+    attribute of ``cfg.models`` that holds its config, and what its
+    tokenizer and checkpoint are called), how to build the model, how to
+    convert a checkpoint (None: no converter, ``weights_dir`` is refused),
+    which options it serves, the parameters a token's forward touches
+    (``active_params(tree, mcfg)``: all of them for a dense LM) and the
+    counters its cache carries (``ops/decode.py`` ``cache_stats``)."""
+
+    name: str
+    model: Callable
+    converter: Optional[Callable] = None
+    quantized: bool = True       # lm_int8 / lm_w8a8
+    speculative: bool = True     # needs decode_chunk and a cache that rolls back
+    active_params: Callable = lambda tree, mcfg: _dense_params(tree)
+    cache_stats: Optional[Callable] = None
+
+
+def _lm_families() -> Tuple[LMFamily, ...]:
+    """The families in the order they are looked for: the first whose
+    config attribute is set on ``cfg.models`` is the prompt LM; GPT-2,
+    always set, comes last."""
+    from cassmantle_tpu.models import qwen3_next
+    from cassmantle_tpu.models.mistral import MistralLM
+    from cassmantle_tpu.models.weights import convert_mistral
+
+    return (
+        LMFamily("qwen3_next", qwen3_next.Qwen3NextLM,
+                 quantized=False, speculative=False,
+                 active_params=qwen3_next.active_params,
+                 cache_stats=qwen3_next.cache_stats),
+        LMFamily("mistral", MistralLM,
+                 lambda m: lambda t: convert_mistral(t, m.num_layers)),
+        LMFamily("gpt2", GPT2LM,
+                 lambda m: lambda t: convert_gpt2(t, m.num_layers,
+                                                  m.hidden_size)),
+    )
+
+
 class PromptGenerator:
     """Story-episode text generation: greedy decode, bucketed.
 
-    The LM family is config-selected: GPT-2 by default, or a
-    Mistral-7B-class model (RoPE/GQA/sliding-window — the reference's
-    actual prompt model, backend.py:25) when ``cfg.models.mistral`` is
-    set. Both expose the same prefill/decode_step contract, so the scan
-    in ops/decode.py drives either."""
+    The LM family is config-selected (``_lm_families``): GPT-2 by default,
+    a Mistral-7B-class model (the reference's actual prompt model,
+    backend.py:25) when ``cfg.models.mistral`` is set, a Qwen3-Next-class
+    sparse model with linear-attention layers when ``cfg.models.qwen3_next``
+    is. All expose the same prefill/decode_step contract, so the scan in
+    ops/decode.py drives each."""
 
     PROMPT_BUCKETS = (32, 64, 128, 256)
 
     def __init__(self, cfg: FrameworkConfig,
                  weights_dir: Optional[str] = None) -> None:
-        from cassmantle_tpu.models.mistral import MistralLM
-        from cassmantle_tpu.models.weights import convert_mistral
-
         enable_compile_cache()
         self.cfg = cfg
         self._decode_calls = 0  # auto-advancing sampling key (decode_ids)
@@ -1189,21 +1260,29 @@ class PromptGenerator:
         assert not (cfg.models.lm_int8 and cfg.models.lm_w8a8), (
             "lm_w8a8 and lm_int8 are mutually exclusive: both rewrite "
             "the same kernel leaves")
-        if cfg.models.mistral is not None:
-            m = cfg.models.mistral
-            self.model = MistralLM(m)
-            self.tokenizer = load_tokenizer(
-                weights_dir, "mistral", m.vocab_size
-            )
-            loader = ("mistral.safetensors",
-                      lambda t: convert_mistral(t, m.num_layers), "mistral")
-        else:
-            m = cfg.models.gpt2
-            self.model = GPT2LM(m)
-            self.tokenizer = load_tokenizer(weights_dir, "gpt2", m.vocab_size)
-            loader = ("gpt2.safetensors",
-                      lambda t: convert_gpt2(t, m.num_layers, m.hidden_size),
-                      "gpt2")
+        family = next(f for f in _lm_families()
+                      if getattr(cfg.models, f.name) is not None)
+        m = getattr(cfg.models, family.name)
+        if not family.quantized and (cfg.models.lm_int8
+                                     or cfg.models.lm_w8a8):
+            raise ValueError(
+                f"lm_int8 / lm_w8a8 are not served for {family.name}: its "
+                f"expert weights do not go through the quantized kernels")
+        if not family.speculative and cfg.spec_decode.mode != "off":
+            raise ValueError(
+                f"speculative decode is not served for {family.name}: a "
+                f"rejected draft would need its recurrent state rolled back")
+        if family.converter is None and weights_dir:
+            raise ValueError(
+                f"no checkpoint converter for {family.name}: it runs on "
+                f"seeded weights only (weights_dir={weights_dir!r})")
+        self.family = family
+        self.model = family.model(m)
+        self.tokenizer = load_tokenizer(weights_dir, family.name,
+                                        m.vocab_size)
+        loader = (f"{family.name}.safetensors",
+                  family.converter(m) if family.converter else None,
+                  family.name)
         self.mcfg = m
         self._weights_dir = weights_dir
         self._int8_path = (
@@ -1330,7 +1409,8 @@ class PromptGenerator:
                 "prompt",
                 costmodel.lm_signature(
                     self.mcfg, w8a8=lm_w8a8_armed(self.cfg.models)),
-                tracer=lambda: 2.0 * costmodel.params_count(self.params),
+                tracer=lambda: 2.0 * self.family.active_params(
+                    self.params, self.mcfg),
             ) or 0.0
         return self._flops_per_token
 
@@ -1363,7 +1443,7 @@ class PromptGenerator:
             "draft and target must share a tokenizer/vocab "
             f"({d.vocab_size} vs {self.mcfg.vocab_size}) — speculative "
             "acceptance compares token ids directly")
-        if cfg.models.mistral is None and d == cfg.models.gpt2:
+        if self.family.name == "gpt2" and d == cfg.models.gpt2:
             # self-draft degenerate: reuse the target's (possibly
             # quantized) apply fns and params — no second tree
             self._spec_draft = ModelDraft(self._prefill, self._step)
@@ -1522,6 +1602,7 @@ class PromptGenerator:
         out_tokens = np.zeros((len(rows), max_new), dtype=np.int32)
         out_len = np.zeros((len(rows),), dtype=np.int32)
         spec_stats = []
+        routed = []  # a sparse LM's routing counters, one tree a dispatch
         dispatch_flops = 0.0
         self._decode_flops_tls.value = 0.0  # failed decodes attr nothing
         self._decode_invalid_tls.value = ()
@@ -1583,8 +1664,9 @@ class PromptGenerator:
                     sink.append(tokens)  # device-synchronized span
                 spec_stats.append(stats)
             else:
+                stats_fn = self.family.cache_stats
                 with self._dispatch_lock:
-                    tokens, gen_len = greedy_decode(
+                    tokens, gen_len, *stats = greedy_decode(
                         (self._prefill, self._step),
                         self.params,
                         jnp.asarray(ids),
@@ -1594,7 +1676,10 @@ class PromptGenerator:
                         eos,
                         self.cfg.sampler.text_temperature,
                         self.cfg.sampler.text_top_k,
+                        **(dict(row_mask=jnp.asarray(np.arange(n_pad) < n),
+                                cache_stats=stats_fn) if stats_fn else {}),
                     )
+                routed += stats
             # one sync per DISPATCHED bucket group (not per row): each
             # group is a separate device computation whose result must
             # land before its rows scatter into the output
@@ -1619,6 +1704,7 @@ class PromptGenerator:
                 # gpt2_w8a8 bench A/B's proof the path engaged)
                 metrics.inc("pipeline.w8a8_dispatches")
         self._record_spec_stats(spec_stats)
+        note_moe_counters(routed)
         self._decode_flops_tls.value = dispatch_flops
         self._decode_invalid_tls.value = tuple(sorted(bad_members))
         return jnp.asarray(out_tokens), jnp.asarray(out_len)

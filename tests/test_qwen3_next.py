@@ -1,0 +1,370 @@
+"""Qwen3-Next-family prompt LM at the tiny size (one period, 8 experts,
+top-2): the program against its plain reference
+(benchmarks/references/qwen3_next.py) on seeded weights, through the
+cache, under bucket padding and batch company; the expert layer drops
+nothing and its shares add up to the whole layer; PromptGenerator serves
+the family and refuses what it does not serve.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.references import qwen3_next as plain
+from cassmantle_tpu import config as configs
+from cassmantle_tpu.config import (
+    Qwen3NextConfig,
+    SpecDecodeConfig,
+    qwen3next_game_config,
+)
+from cassmantle_tpu.models.moe import HeldExperts
+from cassmantle_tpu.models.qwen3_next import (
+    Qwen3NextLM,
+    active_params,
+    cache_stats,
+)
+from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
+
+TINY = Qwen3NextConfig.tiny()
+#: logits are of order 4. float32 differs by summation order alone: the
+#: worst logit counts. bfloat16 rounds every matmul's activations (weights
+#: are the same bits), and at this size (top-2 of 8 experts, 32 wide) a
+#: rounding that swaps an expert at a near-tie moves a position's logits by
+#: order 1, and every later position's through the recurrent state: the
+#: median position counts, which a wrong layer moves as far as any other
+TOLERANCE = {"float32": 1e-4, "bfloat16": 0.15}
+
+
+def error(got, want, dtype: str) -> float:
+    worst = np.abs(np.asarray(got) - np.asarray(want)).max(axis=-1)
+    return float(worst.max() if dtype == "float32" else np.median(worst))
+
+
+def sizes_of(cfg) -> dict:
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def lm(request):
+    """(model, params, sizes) in one storage dtype; the tree is cast as
+    the serving path casts it."""
+    cfg = dataclasses.replace(TINY, dtype=request.param)
+    model = Qwen3NextLM(cfg)
+    params = model.init(jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(request.param), params)
+    return model, params, sizes_of(cfg)
+
+
+def decode_through_cache(model, params, prompts, generated, bucket):
+    """Logits (rows, n_gen, V) that predict each generated token: prefill
+    of the right-padded bucket, then cached steps at ``bucket + i``."""
+    rows, n_gen = len(prompts), generated.shape[1]
+    ids = np.full((rows, bucket), 258, np.int32)
+    lens = np.asarray([len(p) for p in prompts], np.int32)
+    for r, prompt in enumerate(prompts):
+        ids[r, :len(prompt)] = prompt
+    max_len = bucket + n_gen
+    prefill, step = (jax.jit(f, static_argnums=3) if i == 0 else jax.jit(f)
+                     for i, f in enumerate(make_apply_pair(model)))
+    logits, cache = prefill(params, jnp.asarray(ids), jnp.asarray(lens),
+                            max_len)
+    out = [logits]
+    positions = np.arange(max_len)[None, :]
+    for i in range(n_gen - 1):
+        valid = (positions < lens[:, None]) | (
+            (positions >= bucket) & (positions <= bucket + i))
+        logits, cache = step(params, jnp.asarray(generated[:, i]),
+                             jnp.int32(bucket + i), cache, jnp.asarray(valid))
+        out.append(logits)
+    return np.stack([np.asarray(x) for x in out], axis=1), cache
+
+
+def reference_of_row(params, sizes, prompt, generated, bucket, **kw):
+    ids = np.concatenate([prompt, generated])[None]
+    positions = np.concatenate(
+        [np.arange(len(prompt)), bucket + np.arange(len(generated))])[None]
+    logits = jax.jit(lambda p, i, q: plain.qwen3next_logits(
+        p, i, q, sizes, **kw))(params, jnp.asarray(ids),
+                               jnp.asarray(positions))
+    return np.asarray(logits)[0, len(prompt) - 1:-1]
+
+
+PROMPTS = [np.arange(5, 12), np.arange(40, 52), np.arange(90, 93)]
+GENERATED = np.random.RandomState(0).randint(0, 256, (3, 6))
+
+
+def test_full_forward_against_the_plain_reference(lm):
+    model, params, sizes = lm
+    ids = np.random.RandomState(1).randint(0, 256, (2, 20))
+    positions = np.broadcast_to(np.arange(20), (2, 20))
+    got = jax.jit(model.apply)(params, jnp.asarray(ids))
+    want = jax.jit(lambda p, i, q: plain.qwen3next_logits(p, i, q, sizes))(
+        params, jnp.asarray(ids), jnp.asarray(positions))
+    assert float(jnp.abs(want).max()) > 1.0
+    assert error(got, want, sizes["dtype"]) < TOLERANCE[sizes["dtype"]]
+
+
+def test_prefill_then_decode_through_the_cache_against_the_reference(lm):
+    """Rows of different ``prompt_len`` share one bucket; each row's
+    logits are the full forward's over its own tokens, the generated ones
+    at positions ``bucket + i``."""
+    model, params, sizes = lm
+    got, _ = decode_through_cache(model, params, PROMPTS, GENERATED, 16)
+    for r, prompt in enumerate(PROMPTS):
+        want = reference_of_row(params, sizes, prompt, GENERATED[r], 16)
+        assert error(got[r], want, sizes["dtype"]) \
+            < TOLERANCE[sizes["dtype"]], r
+
+
+@pytest.mark.parametrize("part", ["conv_window", "decay"])
+def test_the_reference_without_a_part_of_the_layer_disagrees(lm, part):
+    """What the comparison has to be able to see: the convolution's
+    window or the decay taken out of the reference moves its logits far
+    past the tolerance."""
+    model, params, sizes = lm
+    got, _ = decode_through_cache(model, params, PROMPTS[:1],
+                                  GENERATED[:1], 16)
+
+    def without(p, x, positions, *, d):
+        return plain.layer(p, x, positions, d, False, **{part: False})
+
+    want = reference_of_row(params, sizes, PROMPTS[0], GENERATED[0], 16,
+                            linear_layer=without)
+    assert error(got[0], want, sizes["dtype"]) \
+        > 4 * TOLERANCE[sizes["dtype"]]
+
+
+def test_a_row_does_not_depend_on_its_company(lm):
+    """The same row alone, among other rows, and beside another row: same
+    rows, same logits (the property ``decode_ids_batch`` documents)."""
+    model, params, sizes = lm
+    # other shapes, other summation orders; no expert changes hands
+    tol = {"float32": 1e-4, "bfloat16": 0.04}[sizes["dtype"]]
+    together, _ = decode_through_cache(model, params, PROMPTS, GENERATED, 16)
+    for r, prompt in enumerate(PROMPTS):
+        alone, _ = decode_through_cache(model, params, [prompt],
+                                        GENERATED[r:r + 1], 16)
+        assert np.abs(alone[0] - together[r]).max() < tol, r
+    other = [PROMPTS[0], np.arange(200, 215)]
+    swapped, _ = decode_through_cache(model, params, other, GENERATED[:2], 16)
+    assert np.abs(swapped[0] - together[0]).max() < tol
+
+
+def test_pads_change_no_state(lm):
+    """A linear layer's recurrent state and convolution window after
+    prefill are the row's own at its ``prompt_len``: the same in a wider
+    bucket and whatever the pad positions hold."""
+    model, params, _ = lm
+
+    def linear_states(bucket, pad_id):
+        ids = np.full((1, bucket), pad_id, np.int32)
+        ids[0, :7] = PROMPTS[0]
+        logits, cache = jax.jit(make_apply_pair(model)[0], static_argnums=3)(
+            params, jnp.asarray(ids), jnp.asarray([7]), bucket + 4)
+        states = [e for i, e in enumerate(cache["layers"])
+                  if not TINY.is_full_attention(i)]
+        return np.asarray(logits), states
+
+    base_logits, base = linear_states(8, 258)
+    for bucket, pad_id in [(8, 7), (32, 258), (32, 0)]:
+        logits, states = linear_states(bucket, pad_id)
+        np.testing.assert_allclose(logits, base_logits, atol=2e-5)
+        for (s0, w0), (s1, w1) in zip(base, states):
+            np.testing.assert_allclose(np.asarray(s1), np.asarray(s0),
+                                       atol=1e-6)
+            np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
+                                       atol=1e-6)
+    assert len(base) == 3 and float(np.abs(np.asarray(base[0][0])).max()) > 0
+
+
+# -- the expert layer ---------------------------------------------------------
+
+def expert_layer(**kw):
+    args = dict(num_experts=8, experts_held=8, first_expert=0, top_k=2,
+                intermediate=16, shared_intermediate=16, dtype=jnp.float32)
+    return HeldExperts(**dict(args, **kw))
+
+
+def reference_block(params, x, **kw):
+    fields = dict(num_experts=8, num_experts_per_tok=2,
+                  moe_intermediate_size=16,
+                  shared_expert_intermediate_size=16, norm_topk_prob=True,
+                  experts_held=8, first_expert=0)
+    d = plain.Dims(**{k: dict(fields, **kw).get(k) for k in
+                      plain.Dims._fields})
+    return np.asarray(plain.sparse_block(params["params"], x, d))
+
+
+@pytest.fixture(scope="module")
+def whole_layer():
+    layer = expert_layer()
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, 32))
+    params = layer.init(jax.random.PRNGKey(6), x, jnp.ones((24,), bool), True)
+    return layer, params, x
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "walk"])
+def test_no_assignment_is_dropped_under_a_router_biased_to_one_expert(
+        whole_layer, dense):
+    """Every token's first choice is expert 3 (a Switch layer with a
+    capacity would drop most of them): all are computed, and counted."""
+    layer, params, x = whole_layer
+    router = np.asarray(params["params"]["router"]).copy()
+    x_biased = np.asarray(x).copy()
+    x_biased[:, 0] = 3.0
+    router[0, :] = 0.0
+    router[0, 3] = 50.0
+    biased = {"params": dict(params["params"], router=jnp.asarray(router))}
+    real = jnp.ones((24,), bool)
+    out, stats = layer.apply(biased, jnp.asarray(x_biased), real, dense)
+    want = reference_block(biased, jnp.asarray(x_biased))
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
+    assert int(stats["load"][3]) == 24
+    assert int(stats["assignments"]) == int(stats["assignments_held"]) == 48
+    assert int(stats["load"].sum()) == 48
+
+
+def test_the_walk_and_the_dense_form_agree_and_padding_is_not_counted(
+        whole_layer):
+    layer, params, x = whole_layer
+    real = jnp.arange(24) < 20
+    dense, stats_d = layer.apply(params, x, real, True)
+    walk, stats_w = layer.apply(params, x, real, False)
+    np.testing.assert_allclose(np.asarray(walk)[:20], np.asarray(dense)[:20],
+                               atol=2e-5)
+    for name in stats_d:
+        np.testing.assert_array_equal(np.asarray(stats_d[name]),
+                                      np.asarray(stats_w[name]))
+    assert int(stats_d["assignments"]) == 40
+    assert int(stats_d["experts_touched"]) == int(
+        (np.asarray(stats_d["load"]) > 0).sum())
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "walk"])
+def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, dense):
+    """The share test: four chips hold two experts each; their parts, with
+    the shared expert (which every chip computes alike) counted once, add
+    up to what the plain reference gives for the whole layer."""
+    _, params, x = whole_layer
+    p = params["params"]
+    real = jnp.ones((24,), bool)
+    want = reference_block(params, x)
+    without_shared = reference_block(
+        {"params": dict(p, gate_up=p["gate_up"][:0], down=p["down"][:0])},
+        x, experts_held=0)
+    total, held = np.zeros_like(want), 0
+    for first in (0, 2, 4, 6):
+        share = {"params": dict(p, gate_up=p["gate_up"][first:first + 2],
+                                down=p["down"][first:first + 2])}
+        part, stats = expert_layer(experts_held=2, first_expert=first).apply(
+            share, x, real, dense)
+        # the reference, given the same share, gives the same part
+        np.testing.assert_allclose(
+            np.asarray(part), reference_block(
+                share, x, experts_held=2, first_expert=first), atol=2e-5)
+        total += np.asarray(part) - without_shared
+        held += int(stats["assignments_held"])
+        assert int(stats["assignments"]) == 48
+    np.testing.assert_allclose(total + without_shared, want, atol=5e-5)
+    assert held == 48
+    assert np.abs(without_shared).max() > 0.01
+
+
+# -- the serving path ---------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def generator():
+    from cassmantle_tpu.serving.pipeline import PromptGenerator
+
+    return PromptGenerator(configs.test_qwen3next_config())
+
+
+def counters(prefix="moe."):
+    from cassmantle_tpu.utils.logging import metrics
+
+    return {name: value for name, _labels, value
+            in metrics.dump_state()["counters"] if name.startswith(prefix)}
+
+
+def test_prompt_generator_serves_the_family_and_publishes_its_routing(
+        generator):
+    before = counters()
+    texts = generator.generate_batch(
+        ["The quiet harbor at dawn", "A",
+         "Clockwork birds over the old city walls and far beyond"])
+    assert len(texts) == 3 and all(isinstance(t, str) and t for t in texts)
+    after = counters()
+    delta = {k: after[k] - before.get(k, 0.0) for k in after}
+    # real rows only: (24 + 1 + 54 prompt tokens + 3 rows x 8 new tokens)
+    # x 4 layers x top-2; the batch bucket's padding rows are not counted
+    tokens = 24 + 1 + 54 + 3 * 8
+    assert delta["moe.assignments"] == tokens * 4 * 2
+    assert delta["moe.assignments_held"] == delta["moe.assignments"]
+    assert 0 < delta["moe.experts_touched"] <= delta["moe.assignments_held"]
+
+
+def test_batched_rows_decode_as_they_would_alone(generator):
+    texts = ["The quiet harbor at dawn", "Salt wind",
+             "Clockwork birds over the old city walls"]
+    together, _ = generator.decode_ids_batch(texts)
+    for i, text in enumerate(texts):
+        alone, _ = generator.decode_ids_batch([text])
+        np.testing.assert_array_equal(np.asarray(alone[0]),
+                                      np.asarray(together[i]))
+
+
+def test_greedy_decode_hands_back_the_cache_counters(generator):
+    model, params = generator.model, generator.params
+    ids = jnp.asarray(np.full((2, 32), 65, np.int32))
+    lens = jnp.asarray([5, 1])
+    tokens, _, stats = greedy_decode(
+        make_apply_pair(model), params, ids, lens, jax.random.PRNGKey(0), 4,
+        257, 0.0, 40, row_mask=jnp.asarray([True, False]),
+        cache_stats=cache_stats)
+    assert tokens.shape == (2, 4)
+    assert int(stats["assignments"]) == (5 + 4) * 4 * 2
+
+
+def test_token_flops_count_the_parameters_a_token_touches(generator):
+    tree = generator.params
+    dense = sum(leaf.size for leaf in jax.tree_util.tree_leaves(tree))
+    active = active_params(tree, generator.mcfg)
+    experts = 4 * 8 * 3 * 32 * 16
+    embedding = 300 * 32
+    assert active == dense - embedding - experts * (1 - 2 / 8)
+    assert generator._token_flops() == 2.0 * active
+
+
+def test_the_cut_configuration_holds_what_the_issue_reckoned():
+    cfg = qwen3next_game_config()
+    m = cfg.models.qwen3_next
+    tree = jax.eval_shape(Qwen3NextLM(m).init, jax.random.PRNGKey(0),
+                          jax.ShapeDtypeStruct((1, 8), jnp.int32))
+    held = sum(leaf.size for leaf in jax.tree_util.tree_leaves(tree))
+    assert round(held / 1e9, 3) == 3.667
+    assert round(active_params(tree, m) / 1e9, 2) == 0.43
+    assert cfg.sampler.consistency and cfg.sampler.num_steps == 4
+    assert [m.is_full_attention(i) for i in range(8)] == [
+        False, False, False, True] * 2
+
+
+@pytest.mark.parametrize("change, match", [
+    (dict(models=dict(lm_int8=True)), "lm_int8"),
+    (dict(models=dict(lm_w8a8=True)), "lm_w8a8"),
+    (dict(spec_decode=SpecDecodeConfig(mode="ngram")), "speculative"),
+    (dict(weights_dir="/nonexistent/weights"), "converter"),
+], ids=["lm_int8", "lm_w8a8", "spec_decode", "weights_dir"])
+def test_what_the_family_does_not_serve_is_refused(change, match):
+    from cassmantle_tpu.serving.pipeline import PromptGenerator
+
+    cfg = configs.test_qwen3next_config()
+    weights_dir = change.pop("weights_dir", None)
+    if "models" in change:
+        change["models"] = dataclasses.replace(cfg.models,
+                                               **change["models"])
+    with pytest.raises(ValueError, match=match):
+        PromptGenerator(cfg.replace(**change), weights_dir)

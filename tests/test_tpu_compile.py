@@ -221,3 +221,41 @@ def test_flash_dispatch_under_a_batch_sharded_mesh(v5e, monkeypatch,
     assert "num_partitions=4" in text
     assert not any(op in text for op in (
         "all-gather", "all-reduce", "all-to-all", "collective-permute"))
+
+
+def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(v5e):
+    """``greedy_decode`` over Qwen3NextLM at the ``qwen3next_game`` cut
+    (published widths, 8 layers, 128 of 512 experts), batch 4 in the 64
+    bucket: it fits one chip beside the image stack, and the walk over
+    the routed assignments reads an expert's matrices where they lie
+    (a slice fused into the product; a copy of 4 MB a trip would double
+    the step's traffic)."""
+    from cassmantle_tpu.config import qwen3next_game_config
+    from cassmantle_tpu.models.qwen3_next import Qwen3NextLM, cache_stats
+    from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    model = Qwen3NextLM(qwen3next_game_config().models.qwen3_next)
+    tree = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, BF16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jax.ShapeDtypeStruct((1, 8), jnp.int32)))
+    compiled = greedy_decode.lower(
+        make_apply_pair(model), tree, on_chip((4, 64), jnp.int32),
+        on_chip((4,), jnp.int32), on_chip((2,), jnp.uint32), 96, 257, 0.0,
+        40, row_mask=on_chip((4,), jnp.bool_),
+        cache_stats=cache_stats).compile()
+    memory = compiled.memory_analysis()
+    assert 7.3e9 < memory.argument_size_in_bytes < 7.4e9
+    assert memory.temp_size_in_bytes < 1.5e9
+    walk = [line for line in compiled.as_text().splitlines()
+            if "moe._walk/while/body" in line]
+    assert walk, "the decode step walks no assignments"
+    copies = [line for line in walk
+              if " copy(" in line and ("[2048,1024]" in line
+                                       or "[512,2048]" in line)]
+    assert not copies, copies[:2]
