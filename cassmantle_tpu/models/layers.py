@@ -17,9 +17,12 @@ from typing import Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax.linen.dtypes import promote_dtype
 
 from cassmantle_tpu.ops import quant
 from cassmantle_tpu.ops.attention import multi_head_attention
+from cassmantle_tpu.ops.platform import on_tpu
+from cassmantle_tpu.utils.logging import metrics
 
 
 def nearest_upsample_2x(x: jax.Array) -> jax.Array:
@@ -120,8 +123,6 @@ class QDense(nn.Module):
             return w8a8_dense(x, kernel, bias,
                               out_dtype=self.dtype or x.dtype,
                               per_token=self.act_per_token)
-        from flax.linen.dtypes import promote_dtype
-
         x, kernel, bias = promote_dtype(x, kernel, bias,
                                         dtype=self.dtype)
         y = jax.lax.dot_general(
@@ -454,3 +455,70 @@ class Conv3x3Params(nn.Module):
             # the w8a8 tree transform looks up
             quant.note_act_stat("/".join(self.path), act_stat_of())
         return kernel, bias
+
+
+#: Batch rows from which the TPU compiler runs a 3x3 convolution on
+#: (B, H, W, C) as it stands, 8 rows to the sublanes. Under it, it
+#: rewrites the convolution space-to-batch: W cut into 8 chunks an
+#: image, each W/8 + 1 columns wide, the extra column computed and
+#: masked off (x1.5 the products at 16x16, x1.25 at 32x32, x1.125 at
+#: 64x64); at 8x8 it keeps B rows in the 8 sublanes. PERF.md section 7
+#: item 6 has the recipe to read the compiled program.
+DIRECT_FORM_BATCH = 8
+
+
+def conv3x3_form(tpu: bool, batch: int, height: int, width: int) -> str:
+    """Which operand shape a 3x3, stride-1, SAME convolution is handed
+    to the compiler in: a function of the platform and the call's static
+    shape, and of nothing else. ``"rows_folded"``: H in the batch
+    (:func:`conv3x3_rows_folded`), so the compiler sees B·H rows and
+    takes its direct form. ``"xla_2d"``: ``nn.Conv``'s convolution: off
+    the TPU; from ``DIRECT_FORM_BATCH`` rows up, where the 2-D form is
+    the direct one already; and from 64 columns up, where the padded
+    column is a ninth of the work or less and costs less than three
+    passes over that much activation (timed on the chip alone and
+    between its neighbours: PERF.md section 5, PR 30)."""
+    if not tpu or batch >= DIRECT_FORM_BATCH:
+        return "xla_2d"
+    folds = 8 <= width <= 32 and batch * height >= DIRECT_FORM_BATCH
+    return "rows_folded" if folds else "xla_2d"
+
+
+def conv3x3_rows_folded(x: jax.Array, kernel: jax.Array,
+                        axis: int = 1) -> jax.Array:
+    """3x3, stride-1, SAME convolution of ``x`` (B, H, W, C) with
+    ``kernel`` (3, 3, C, F) as 1-D convolutions along the other spatial
+    axis with ``axis`` (1: H, 2: W) folded into the batch: B·H (or B·W)
+    rows, so the TPU compiler takes its direct form and multiplies
+    exactly B·H·W output positions. One convolution a tap of the folded
+    axis over that tap's rows of the padded input, summed in float32:
+    the same products as the 2-D form in another order of summation,
+    and no stacked copy of the input."""
+    if axis == 2:
+        x, kernel = jnp.swapaxes(x, 1, 2), jnp.swapaxes(kernel, 0, 1)
+    b, h, w, c = x.shape
+    xp = jnp.pad(x, ((0, 0), (1, 1), (0, 0), (0, 0)))
+    y = sum(jax.lax.conv_general_dilated(
+        xp[:, dh:dh + h].reshape(b * h, w, c), kernel[dh], (1,), "SAME",
+        dimension_numbers=("NWC", "WIO", "NWC"),
+        preferred_element_type=jnp.float32) for dh in range(3))
+    y = y.astype(x.dtype).reshape(b, h, w, kernel.shape[-1])
+    return jnp.swapaxes(y, 1, 2) if axis == 2 else y
+
+
+def conv3x3_same(x: jax.Array, features: int, dtype, name: str):
+    """``nn.Conv(features, (3, 3), padding=1, dtype=dtype, name=name)``
+    at a site whose form :func:`conv3x3_form` chooses; counted under
+    ``conv.dispatch{form=...}`` once a site a trace, as
+    ``attention.dispatch`` is. Must be called inside the parent module's
+    ``@nn.compact`` ``__call__``; the param tree is ``nn.Conv``'s either
+    way (:class:`Conv3x3Params`)."""
+    form = conv3x3_form(on_tpu(), *x.shape[:3])
+    metrics.inc("conv.dispatch", labels={"form": form})
+    if form == "xla_2d":
+        return nn.Conv(features, (3, 3), padding=1, dtype=dtype,
+                       name=name)(x)
+    kernel, bias = Conv3x3Params(features, name=name)(x.shape[-1])
+    x, kernel, bias = promote_dtype(x, kernel, bias, dtype=dtype)
+    with jax.named_scope(name):
+        return conv3x3_rows_folded(x, kernel) + bias

@@ -34,6 +34,7 @@ from cassmantle_tpu.models.layers import (
     GroupNorm32,
     LayerNorm32,
     MultiHeadAttention,
+    conv3x3_same,
     fused_gn_silu_conv3x3,
     nearest_upsample_2x,
     timestep_embedding,
@@ -52,6 +53,9 @@ class ResBlock(nn.Module):
     checkpoints, the init cache, and the A/B share one tree;
     ``conv_pad_to`` additionally pads channel dims to MXU-friendly
     multiples inside the fused op (zero-fill, output sliced back).
+    Unfused, both convolutions go through ``conv3x3_same``: ``nn.Conv``,
+    or on the TPU under 8 batch rows the same products with H folded
+    into the batch (models/layers.py::conv3x3_form).
     """
 
     out_channels: int
@@ -71,8 +75,7 @@ class ResBlock(nn.Module):
         else:
             h = GroupNorm32(name="norm1")(x)
             h = nn.silu(h)
-            h = nn.Conv(self.out_channels, (3, 3), padding=1,
-                        dtype=self.dtype, name="conv1")(h)
+            h = conv3x3_same(h, self.out_channels, self.dtype, "conv1")
         t = nn.Dense(self.out_channels, dtype=self.dtype,
                      name="time_proj")(nn.silu(temb))
         h = h + t[:, None, None, :]
@@ -81,8 +84,7 @@ class ResBlock(nn.Module):
         else:
             h = GroupNorm32(name="norm2")(h)
             h = nn.silu(h)
-            h = nn.Conv(self.out_channels, (3, 3), padding=1,
-                        dtype=self.dtype, name="conv2")(h)
+            h = conv3x3_same(h, self.out_channels, self.dtype, "conv2")
         if x.shape[-1] != self.out_channels:
             x = nn.Conv(self.out_channels, (1, 1),
                         dtype=self.dtype, name="skip")(x)
@@ -308,8 +310,7 @@ class UNet(nn.Module):
                     )(x, context)
             if lvl != 0:
                 x = nearest_upsample_2x(x)
-                x = nn.Conv(ch, (3, 3), padding=1, dtype=dtype,
-                            name=f"up_{lvl}_upsample")(x)
+                x = conv3x3_same(x, ch, dtype, f"up_{lvl}_upsample")
 
         assert not skips, f"unconsumed skips: {len(skips)}"
 

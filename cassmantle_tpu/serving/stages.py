@@ -29,7 +29,14 @@ re-uses the monolithic schedule arrays and step arithmetic verbatim
 (ops/samplers.py::make_slot_sampler, ops/ddim.py::make_slot_denoiser),
 and every per-row computation in the UNet/CLIP/VAE is independent of
 its batch neighbors — so admission at a step boundary cannot perturb
-another slot (tests/test_stages.py pins both properties).
+another slot (tests/test_stages.py pins both properties). To the bit
+this holds between programs that hand every operation the same operand
+shapes: everywhere off the TPU, and on the TPU at equal width. There
+the UNet's 3x3 convolutions take one spatial axis into the batch under
+8 rows (models/layers.py::conv3x3_form), so a step at 4 live slots or
+more (CFG batch 8) sums a convolution's products in another order than
+the same rows at 1 or 2 slots or in the monolithic batch-1 program:
+equal to bfloat16 rounding, not to the bit.
 
 Control state (which slot is at which step, which are free) lives
 entirely on the HOST as plain numpy mirrors maintained by the single

@@ -259,3 +259,57 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(v5e):
               if " copy(" in line and ("[2048,1024]" in line
                                        or "[512,2048]" in line)]
     assert not copies, copies[:2]
+
+
+# (B, H = W, C, F): the ResBlock with the widest conv1 of each SD1.5
+# level at CFG batch 2, and the 16x16 one at the 4-image bucket
+RESBLOCK_SITES = {
+    "sd15_16x16_b2": (2, 16, 2560, 1280),
+    "sd15_32x32_b2": (2, 32, 1920, 640),
+    "sd15_64x64_b2": (2, 64, 960, 320),
+    "sd15_16x16_b8": (8, 16, 2560, 1280),
+    "sd15_8x8_b2": (2, 8, 2560, 1280),
+}
+
+
+@pytest.mark.parametrize("site", list(RESBLOCK_SITES))
+def test_resblock_convolutions_multiply_no_padding(v5e, monkeypatch, site):
+    """Under 8 batch rows the TPU compiler rewrites a 3x3 convolution
+    space-to-batch, W cut into 8 chunks of W/8 + 1 columns, one of them
+    padding: a ResBlock then counts x1.46 (16x16), x1.23 (32x32) and
+    x1.12 (64x64) the FLOPs its shapes need (ISSUE 30's reading of the
+    parent). Where ``conv3x3_form`` folds H into the batch the program
+    holds no 3x3 window and counts the needed FLOPs. From 8 rows up the
+    compiler's direct form is kept, which pads nothing; at 64x64 the
+    padded form is kept knowingly (PERF.md section 5: folding there lost
+    between its neighbours), and its ninth of padding is pinned here."""
+    from cassmantle_tpu.models import layers
+    from cassmantle_tpu.models.unet import ResBlock
+
+    monkeypatch.setattr(layers, "on_tpu", lambda: True)
+    b, hw, c, f = RESBLOCK_SITES[site]
+    chip = SingleDeviceSharding(v5e.devices[0])
+    block = ResBlock(f, BF16)
+    x = jax.ShapeDtypeStruct((b, hw, hw, c), BF16, sharding=chip)
+    temb = jax.ShapeDtypeStruct((b, 1280), BF16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=chip),
+        jax.eval_shape(block.init, jax.random.PRNGKey(0), x, temb))
+    compiled = jax.jit(block.apply).lower(params, x, temb).compile()
+    # conv1, conv2, the 1x1 skip, time_proj
+    needed = 2 * b * (hw * hw * (9 * c * f + 9 * f * f + c * f) + 1280 * f)
+    counted = compiled.cost_analysis()["flops"] / needed
+    windows_3x3 = [line for line in compiled.as_text().splitlines()
+                   if " convolution(" in line and "window={size=3x3" in line]
+    if layers.conv3x3_form(True, b, hw, hw) == "rows_folded":
+        assert b < 8 and hw <= 32
+        assert not windows_3x3, windows_3x3[:1]
+        assert counted <= 1.05
+    elif b >= 8:
+        assert len(windows_3x3) == 2
+        assert all("dim_labels=b01f_01io->b01f" in line
+                   for line in windows_3x3), windows_3x3
+        assert counted <= 1.05
+    else:
+        assert hw >= 64 and len(windows_3x3) == 2
+        assert 1.05 < counted < 1.15
