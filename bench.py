@@ -154,49 +154,6 @@ def bench_sd15_fast(weights_dir: str) -> dict:
         weights_dir)
 
 
-def bench_sd15_deepcache(weights_dir: str) -> dict:
-    """Deep-feature-reuse preset: full DDIM-50 trajectory, alternate
-    steps reusing the previous step's deepest-level activations (~60%
-    of the UNet compute; ops/ddim.py, models/unet.py)."""
-    from cassmantle_tpu.config import deepcache_serving_config
-
-    return _bench_txt2img(
-        deepcache_serving_config,
-        "sd15_512px_ddim50_deepcache_images_per_sec_per_chip",
-        weights_dir)
-
-
-def bench_sd15_turbo(weights_dir: str) -> dict:
-    """Composed preset: DPM-Solver++(2M) @ 24 steps WITH deep-feature
-    reuse (~3.3x fewer UNet-FLOPs/image than DDIM-50) — the workload-
-    level route to the 4 img/s/chip target (turbo_serving_config)."""
-    from cassmantle_tpu.config import turbo_serving_config
-
-    return _bench_txt2img(
-        turbo_serving_config,
-        "sd15_512px_dpmpp24_deepcache_images_per_sec_per_chip",
-        weights_dir)
-
-
-def bench_sdxl_turbo(weights_dir: str) -> dict:
-    """SDXL-1024 with the composed turbo path (DPM++(2M)@24 +
-    deepcache) — the samplers/deepcache machinery is shared with SD1.5
-    (serving/pipeline.py:run_cfg_denoise), so the workload-level
-    speedups apply to the reference's actual image model too."""
-    import dataclasses as _dc
-
-    from cassmantle_tpu.config import sdxl_config
-
-    def cfg():
-        base = sdxl_config()
-        return base.replace(sampler=_dc.replace(
-            base.sampler, kind="dpmpp_2m", num_steps=24, deepcache=True))
-
-    return _bench_sdxl_with(
-        cfg, "sdxl_1024px_dpmpp24_deepcache_images_per_sec_per_chip",
-        weights_dir)
-
-
 def bench_sd15_fusedconv(weights_dir: str) -> dict:
     """A/B arm for the fused GroupNorm+SiLU+conv3x3 Pallas path on the
     fixed DDIM-50 config (config.fusedconv_serving_config): identical
@@ -381,15 +338,10 @@ def bench_sd15_int8(weights_dir: str) -> dict:
         weights_dir)
 
 
-def _encprop_smoke_geometry() -> bool:
-    return os.environ.get("BENCH_ENCPROP_SMOKE_GEOMETRY", "").lower() in (
-        "1", "true", "yes", "on")
-
-
 def _smoke_clip_harness(weights_dir: str, smoke: bool):
     """The quality-report harness the A/B entries share: real CLIP
     weights off-smoke, the tiny fixed test geometry on the CPU smoke
-    (one definition so the encprop and lcm entries can never gate with
+    (one definition so the lcm and w8a8 entries can never gate with
     different harnesses)."""
     from cassmantle_tpu.eval.clip_parity import ClipSimilarityHarness
 
@@ -406,140 +358,6 @@ def _smoke_clip_harness(weights_dir: str, smoke: bool):
             intermediate_size=128, num_layers=2, num_heads=4,
             projection_dim=64),
         pad_len=16)
-
-
-def _bench_encprop_ab(metric: str, weights_dir: str, sdxl: bool) -> dict:
-    """Same-seed A/B for encoder propagation (the `sd15_encprop` /
-    `sdxl_encprop` entries): ONE harness builds the full-forward arm
-    and the encprop arm (full forwards only at key steps + batched
-    propagated-decoder forwards + fused VAE ResBlocks), runs both on
-    the SAME prompts and seeds, and reports img/s per arm plus the
-    eval/clip_parity.py quality report between the two arms' same-seed
-    outputs — throughput and the quality cost of the approximation in
-    one record. The runner attaches the `pipeline.encprop_*` diagnosis
-    counter deltas like every round-14+ entry.
-
-    Env: BENCH_ENCPROP_SMOKE_GEOMETRY=1 swaps in the 64px test
-    geometry at 12 steps (stride 4: 3 key + 9 propagated) so the CPU
-    harness smoke exercises the real scan structure — those numbers
-    exercise the scheduler and the batched decoder dispatch, not the
-    MXU, and are NOT hardware evidence (the BENCH_SUITE.json
-    annotation records this). BENCH_ENCPROP_REPS overrides the timed
-    rep count."""
-    import dataclasses as _dc
-
-    jax = _setup_jax()
-    from cassmantle_tpu.eval.clip_parity import encprop_quality_report
-    from cassmantle_tpu.ops.ddim import encprop_key_indices
-
-    smoke = _encprop_smoke_geometry()
-    if smoke:
-        from cassmantle_tpu.config import test_config, test_sdxl_config
-
-        base = test_sdxl_config() if sdxl else test_config()
-        base = base.replace(sampler=_dc.replace(base.sampler, num_steps=12))
-        enc_sampler = _dc.replace(base.sampler, encprop=True,
-                                  encprop_stride=4, encprop_dense_steps=0)
-        enc_cfg = base.replace(sampler=enc_sampler)
-    else:
-        from cassmantle_tpu.config import FrameworkConfig, sdxl_config
-
-        base = sdxl_config() if sdxl else FrameworkConfig()
-        enc_cfg = base.replace(
-            sampler=_dc.replace(base.sampler, encprop=True),
-            models=_dc.replace(base.models, vae=_dc.replace(
-                base.models.vae, fused_conv=True)))
-
-    if sdxl:
-        from cassmantle_tpu.serving.sdxl import SDXLPipeline
-
-        full_pipe = SDXLPipeline(base, weights_dir=weights_dir)
-        enc_pipe = SDXLPipeline(enc_cfg, weights_dir=weights_dir,
-                                share_params_with=full_pipe)
-    else:
-        from cassmantle_tpu.serving.pipeline import Text2ImagePipeline
-
-        full_pipe = Text2ImagePipeline(base, weights_dir=weights_dir)
-        enc_pipe = Text2ImagePipeline(enc_cfg, weights_dir=weights_dir,
-                                      share_params_with=full_pipe)
-
-    batch = 1 if (sdxl or smoke) else BATCH
-    reps = int(os.environ.get("BENCH_ENCPROP_REPS", "2" if sdxl else "3"))
-    prompts = (PROMPTS * ((batch + len(PROMPTS) - 1) // len(PROMPTS))
-               )[:batch]
-
-    def run_arm(pipe):
-        imgs = pipe.generate(prompts, seed=0)     # warmup compile
-        t0 = time.perf_counter()
-        for i in range(reps):
-            imgs = pipe.generate(prompts, seed=1)  # same seed both arms
-        elapsed = time.perf_counter() - t0
-        ips = reps * len(prompts) / elapsed / max(
-            1, jax.local_device_count())
-        return ips, imgs
-
-    full_ips, full_imgs = run_arm(full_pipe)
-    enc_ips, enc_imgs = run_arm(enc_pipe)
-
-    harness = _smoke_clip_harness(weights_dir, smoke)
-    quality = encprop_quality_report(harness, enc_imgs, full_imgs, prompts)
-
-    s = enc_cfg.sampler
-    keys = len(encprop_key_indices(s.num_steps, s.encprop_stride,
-                                   s.encprop_dense_steps))
-    return {
-        "metric": metric,
-        "value": round(enc_ips, 4),
-        "unit": "images/sec/chip",
-        "vs_baseline": None,
-        "ab_versus": "full-forward arm (same prompts/seed, shared params)",
-        "full_images_per_sec": round(full_ips, 4),
-        "speedup_vs_full": round(enc_ips / full_ips, 4) if full_ips else None,
-        "batch": batch,
-        "timed_rounds": reps,
-        "encprop": {
-            "num_steps": s.num_steps, "stride": s.encprop_stride,
-            "dense_steps": s.encprop_dense_steps, "key_steps": keys,
-            "propagated_steps": s.num_steps - keys,
-        },
-        "quality": quality,
-    }
-
-
-def bench_sd15_encprop(weights_dir: str) -> dict:
-    """A/B arm for encoder propagation on the fixed DDIM-50 SD1.5
-    config (config.encprop_serving_config): full UNet forwards at the
-    20 key steps, batched decoder-only forwards on the other 30 (the
-    analytic bound is 67.2 vs 82.8 TF/image — docs/PERF_NOTES.md
-    'Encoder propagation accounting'), fused VAE ResBlocks on the
-    decode tail. Quality rides the same record via the
-    eval/clip_parity.py encprop gate."""
-    res = _bench_encprop_ab(
-        "sd15_512px_ddim50_encprop_images_per_sec_per_chip",
-        weights_dir, sdxl=False)
-    # ceiling fractions only mean something at the real geometry — the
-    # 64px smoke would divide toy img/s by the 512px ceiling
-    return res if _encprop_smoke_geometry() else _sd15_ceiling_context(res)
-
-
-def bench_sdxl_encprop(weights_dir: str) -> dict:
-    """The profile-driven SDXL ceiling-gap attack (ROADMAP item 4):
-    encoder propagation at 1024² — the encoder (down+mid, 43% of UNet
-    FLOPs, dominated by the depth-10 transformer level) runs only at
-    the 20 key steps; propagated steps run the decoder alone, batched
-    per segment — plus fused VAE ResBlocks and wide-head flash VAE
-    attention on the 10.47 TF decode. Analytic bound 510.6 vs 686.6
-    TF/image (74%), i.e. an in-config ceiling of ~0.386 img/s/chip vs
-    the full config's 0.287; `fraction_of_fixed_config_ceiling` still
-    reports against the FIXED full-config ceiling so the entry reads as
-    progress toward the >80%-of-ceiling target."""
-    res = _bench_encprop_ab(
-        "sdxl_1024px_ddim50_encprop_images_per_sec_per_chip",
-        weights_dir, sdxl=True)
-    res["encprop_analytic_tf_per_image"] = SDXL_ENCPROP_ANALYTIC_TF_PER_IMAGE
-    res["encprop_ceiling_ips"] = SDXL_ENCPROP_CEILING_IPS
-    # see bench_sd15_encprop: no ceiling fraction from the 64px smoke
-    return res if _encprop_smoke_geometry() else _sdxl_ceiling_context(res)
 
 
 def _lcm_smoke_geometry() -> bool:
@@ -1096,19 +914,6 @@ def _bench_sdxl_with(config_factory, metric: str,
 # target, so the ceiling IS the baseline the fraction reports against).
 SDXL_ANALYTIC_TF_PER_IMAGE = 686.8
 SDXL_CEILING_IPS_DEFAULT = 0.287
-
-# Encoder propagation rewrites the SDXL per-image analytic cost
-# (tools/profile_unet.py --cost-table --sdxl now prints the
-# encoder/decoder split and this bound): full forwards at 20 key steps
-# + decoder-only (3.828 of 6.761 TF) forwards at the other 30, CFG-
-# doubled, + the 10.47 TF VAE decode = ~510.6 TF/image (74% of the
-# full 686.6) -> ~0.386 img/s/chip in-config ceiling on the same
-# ~197 TFLOP/s chip. The `sdxl_encprop` entry reports BOTH this and
-# the fraction of the FIXED full-config ceiling (progress toward the
-# ROADMAP >80%-of-ceiling target is measured against the latter).
-SDXL_ENCPROP_ANALYTIC_TF_PER_IMAGE = 510.6
-SDXL_ENCPROP_CEILING_IPS = 0.386
-
 
 def _sdxl_ceiling_context(res: dict) -> dict:
     """Attach the analytic ceiling context to an SDXL suite entry (the
@@ -2774,11 +2579,6 @@ _DELTA_COUNTERS = {
     "game.image_cache_hits", "game.image_cache_misses",
     "stage.denoise.admissions", "stage.denoise.preemptions",
     "stage.denoise.steps", "dispatch.thread_replacements",
-    # encoder propagation: full-encoder vs decoder-only UNet forwards
-    # the arm actually dispatched (zero in the full-forward arm and
-    # under CASSMANTLE_NO_ENCPROP, so the A/B deltas separate arms)
-    "pipeline.encprop_key_steps", "pipeline.encprop_shallow_steps",
-    "pipeline.encprop_prop_steps",
     # overload control plane (ISSUE 13): brownout churn + shed totals
     "overload.brownout_trips", "overload.brownout_recoveries",
     "overload.score_shed", "overload.loop_lag_sheds",
@@ -2843,20 +2643,15 @@ def _counter_deltas(before: dict, after: dict) -> dict:
 # CPU-light entries (scorer, gpt2) and the long e2e/soak runs come last.
 SUITE = {
     "sd15": bench_sd15,
-    "sd15_turbo": bench_sd15_turbo,
     "sd15_fast": bench_sd15_fast,
-    "sd15_deepcache": bench_sd15_deepcache,
     "sd15_fusedconv": bench_sd15_fusedconv,
     "sd15_int8": bench_sd15_int8,
     "sd15_w8a8": bench_sd15_w8a8,
     "sd15_staged": bench_sd15_staged,
-    "sd15_encprop": bench_sd15_encprop,
     "sd15_lcm": bench_sd15_lcm,
     "sd15_b8": bench_sd15_b8,
     "sdxl": bench_sdxl,
-    "sdxl_encprop": bench_sdxl_encprop,
     "sdxl_w8a8": bench_sdxl_w8a8,
-    "sdxl_turbo": bench_sdxl_turbo,
     "scorer": bench_scorer,
     "gpt2": bench_gpt2,
     "gpt2_spec": bench_gpt2_spec,
@@ -2877,7 +2672,7 @@ SUITE = {
 # unless the caller already pinned a rep count: the smallest run that
 # yields a hardware number for the target metric and its fastest
 # challenger.
-NORTH_STAR_ENTRIES = ("sd15", "sd15_turbo")
+NORTH_STAR_ENTRIES = ("sd15", "sd15_fast")
 
 
 def _run_entry_isolated(name: str, weights_dir: str,

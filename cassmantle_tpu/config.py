@@ -116,7 +116,7 @@ class VAEConfig:
     # per SDXL image and, like the UNet's, each of its norm→act→conv
     # sequences otherwise round-trips the level activation through HBM.
     # Param tree/checkpoint layout unchanged (parity-pinned,
-    # tests/test_encprop.py). VAE channels (128/256/512) are already
+    # tests/test_fused_conv.py). VAE channels (128/256/512) are already
     # 128-lane aligned, so no conv_pad_to analogue is needed.
     fused_conv: bool = False
 
@@ -324,33 +324,6 @@ class SamplerConfig:
     # unconditional arm). Tokenized host-side per batch, so changing it
     # never recompiles.
     negative_prompt: str = "blurry, distorted, fake, abstract, negative"
-    # Deep-feature reuse (DeepCache-style): steps run in full/shallow
-    # pairs, the shallow pass reusing the previous step's deepest-level
-    # activations (~60% of full compute; ddim only, even num_steps).
-    deepcache: bool = False
-    # Encoder propagation (Faster Diffusion, PAPERS.md): run the full
-    # UNet only at key steps; in between, reuse the key step's encoder
-    # features (skip stack + mid output) and run ONLY the decoder —
-    # batched across each segment's propagated steps in one forward,
-    # since the decoder never reads x_t (ops/ddim.py, models/unet.py
-    # ``return_skips``/``skips_cache``). Composes with ``deepcache``
-    # (deep-cache refreshes happen exactly at encoder key steps) and
-    # with every deterministic sampler kind; eta>0 is rejected and the
-    # staged denoise path falls back to monolithic.
-    # CASSMANTLE_NO_ENCPROP=1 is the runtime kill switch (docs/DEPLOY.md
-    # §6). Quality is gated by eval/clip_parity.py::encprop_quality_report
-    # (stride 1 is exact full-forward parity by construction).
-    encprop: bool = False
-    # Key-step cadence: one full forward every ``encprop_stride`` steps
-    # after the dense prefix. Stride 1 = full forward every step
-    # (bit-identical to the plain sampler).
-    encprop_stride: int = 3
-    # Leading steps that are ALL key steps — encoder features drift
-    # fastest early in sampling (Faster Diffusion's non-uniform key
-    # schedule), so keys are denser there. With the 50-step default and
-    # stride 3 this yields 20 encoder forwards per trajectory (the
-    # encoder is skipped on 60% of steps).
-    encprop_dense_steps: int = 5
     # Few-step consistency serving (ops/samplers.py::consistency_sample;
     # ISSUE 15): sample with a consistency/LCM-distilled student —
     # ``num_steps`` (1-8) direct x0 predictions through the boundary
@@ -358,9 +331,7 @@ class SamplerConfig:
     # student shares the teacher's UNetConfig arch and checkpoint
     # layout (parallel/train.py::ConsistencyDistillTrainer), so it
     # loads through the unchanged utils/checkpoint.py / share_compatible
-    # machinery. Does NOT compose with deepcache/encprop (the student
-    # is trained for direct few-step prediction — there is no long loop
-    # to cache into); composes with the staged continuous-batching path
+    # machinery. Composes with the staged continuous-batching path
     # (a consistency slot stepper) and the execution-level levers
     # (fused_conv, int8). CASSMANTLE_NO_CONSISTENCY=1 is the runtime
     # kill switch: it reverts serving bit-exactly to the TEACHER path —
@@ -519,7 +490,7 @@ class ServingConfig:
     # output is bit-identical to the monolithic path
     # (tests/test_stages.py); CASSMANTLE_NO_STAGED_SERVING=1 is the
     # runtime kill switch (docs/DEPLOY.md §6). Configs the slot stepper
-    # cannot replay exactly (deepcache pairing, eta>0, a dp/sp mesh)
+    # cannot replay exactly (eta>0, a dp/sp mesh)
     # fall back to the monolithic dispatch automatically.
     staged_serving: bool = False
     # Fixed denoise slot capacity. The slot tensor keeps this shape
@@ -705,18 +676,13 @@ class QualityGateConfig:
     by preset name; a preset absent here is reported but not gated.
 
     Ratios are preset clip_sim_mean / ddim50 anchor clip_sim_mean.
-    DPM-Solver++(2M)@25 and deepcache claim DDIM-50-class quality, so
-    they gate at 0.97; the composed turbo path trades a little more;
-    int8 is a weights-only quantization and must stay ~lossless."""
+    DPM-Solver++(2M)@25 claims DDIM-50-class quality, so it gates at
+    0.97; int8 is a weights-only quantization and must stay
+    ~lossless."""
 
     parity_vs_ddim50: Tuple[Tuple[str, float], ...] = (
         ("dpmpp25", 0.97),
-        ("deepcache", 0.97),
-        ("turbo", 0.95),
         ("int8", 0.98),
-        # encoder propagation reuses key-step encoder features on 60%
-        # of steps; like deepcache it claims near-anchor quality
-        ("encprop", 0.95),
         # the 4-step consistency student trades the most quality for
         # the biggest step-count win (LCM-class results, PAPERS.md
         # Efficient Diffusion Models survey)
@@ -786,20 +752,6 @@ def fast_serving_config() -> FrameworkConfig:
     )
 
 
-def turbo_serving_config() -> FrameworkConfig:
-    """The two workload-level speedups COMPOSED: DPM-Solver++(2M) at 24
-    steps (half of DDIM-50) with deep-feature reuse on alternate steps
-    (~60% UNet compute). Relative to the DDIM-50 north star this is
-    ~3.3x fewer UNet-FLOPs per image — the route past BASELINE.md's
-    ~2.5 img/s/chip bf16 ceiling toward the 4 img/s target. Quality is
-    gated by tools/clip_report.py's parity_vs_ddim50, like every other
-    preset. Even step count keeps the (full, shallow) pairing uniform."""
-
-    return FrameworkConfig(
-        sampler=SamplerConfig(kind="dpmpp_2m", num_steps=24, deepcache=True)
-    )
-
-
 def fusedconv_serving_config() -> FrameworkConfig:
     """The fixed DDIM-50 north-star config with the conv-side Pallas
     path on: fused GroupNorm+SiLU+conv3x3 in every UNet ResBlock plus
@@ -807,7 +759,7 @@ def fusedconv_serving_config() -> FrameworkConfig:
     (UNetConfig.fused_conv / conv_pad_to; ops/fused_conv.py). Same
     trajectory and param tree as the plain config — this is the ON arm
     of the `sd15_fusedconv` bench A/B, and it composes with the
-    workload-level presets (deepcache/dpmpp/int8) because it changes
+    workload-level presets (dpmpp/int8) because it changes
     how ResBlock convs execute, not what they compute."""
 
     base = FrameworkConfig()
@@ -822,7 +774,7 @@ def w8a8_serving_config() -> FrameworkConfig:
     fused-conv ResBlock site in the UNet, plus the prompt LM with
     per-token activation scales — the quantization lever the Efficient
     Diffusion survey (PAPERS.md) ranks beside step reduction, composing
-    multiplicatively with encprop/LCM/staged since it changes how
+    multiplicatively with LCM/staged since it changes how
     matmuls execute, not what the schedule computes. Rides the fused
     GN+SiLU+conv path (fused_conv=True + 128-lane padding), so this is
     fusedconv_serving_config plus quantized trees. Static activation
@@ -868,28 +820,6 @@ def staged_serving_config() -> FrameworkConfig:
     return FrameworkConfig(serving=ServingConfig(staged_serving=True))
 
 
-def encprop_serving_config() -> FrameworkConfig:
-    """DDIM-50 with encoder propagation AND the decode-side kernels on:
-    full UNet forwards only at the 20 key steps of the default schedule
-    (5 dense + every 3rd), decoder-only forwards — batched per segment
-    — on the other 30, plus fused GroupNorm+SiLU+conv3x3 VAE ResBlocks.
-    This is the ON arm of the `sd15_encprop` bench A/B; the SDXL arm
-    (`sdxl_encprop`) applies the same sampler/vae replaces to
-    sdxl_config(), where the encoder (down+mid, 43% of UNet FLOPs —
-    much of it the mid-block half of the depth-10 transformer level)
-    is the profile-driven lever for the >80%-of-ceiling ROADMAP
-    target. Quality gates via
-    eval/clip_parity.py (encprop row in QualityGateConfig);
-    CASSMANTLE_NO_ENCPROP=1 is the runtime kill switch."""
-
-    base = FrameworkConfig()
-    return base.replace(
-        sampler=dataclasses.replace(base.sampler, encprop=True),
-        models=dataclasses.replace(
-            base.models,
-            vae=dataclasses.replace(base.models.vae, fused_conv=True)))
-
-
 def lcm_serving_config() -> FrameworkConfig:
     """Few-step image serving (ROADMAP item 3a, ISSUE 15): a
     consistency/LCM-distilled student of the zoo UNet sampled at FOUR
@@ -926,16 +856,6 @@ def qwen3next_game_config() -> FrameworkConfig:
             num_hidden_layers=8, experts_held=128, first_expert=0,
             vocab_size=37984)),
         sampler=SamplerConfig(consistency=True, num_steps=4))
-
-
-def deepcache_serving_config() -> FrameworkConfig:
-    """DDIM-50 with deep-feature reuse (SamplerConfig.deepcache): the
-    full 50-step trajectory at ~60% of the UNet compute — alternate
-    steps reuse the previous step's deepest-level activations
-    (models/unet.py, ops/ddim.py). The second workload-level serving
-    speedup next to fast_serving_config's fewer-steps route."""
-
-    return FrameworkConfig(sampler=SamplerConfig(deepcache=True))
 
 
 def test_config() -> FrameworkConfig:

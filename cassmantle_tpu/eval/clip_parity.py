@@ -164,32 +164,21 @@ class ClipSimilarityHarness:
         of two image batches — the image↔image counterpart of
         :meth:`similarity`, jitted once like it (``_jit_pair_sim``).
         Identical batches score 1.0 exactly (both arms embed through
-        the same compiled tower), which is what makes the stride-1
-        exact-parity leg of the encprop gate a deterministic tier-1
-        assertion even on random init."""
+        the same compiled tower), which is what makes a bit-exact
+        revert leg of a quality gate a deterministic tier-1 assertion
+        even on random init."""
         return np.asarray(self._jit_pair_sim(
             self._params, jnp.asarray(images_a_u8),
             jnp.asarray(images_b_u8)))
 
 
-# Image-quality floor for encoder-propagation serving (the approximation
-# contract in PARITY.md): mean CLIP-vision similarity between the
-# encprop arm's images and the full-forward arm's SAME-SEED images must
-# stay above this. At stride 1 encprop IS the full forward (bit-exact,
-# similarity 1.0 — pinned in tier-1); the default key schedule is gated
-# against this floor whenever the harness runs with real weights
-# (random-init runs report advisory only, like every QualityGateConfig
-# gate).
-ENCPROP_IMAGE_SIM_FLOOR = 0.95
-
-
 # Image-quality floor for few-step consistency serving: mean
 # CLIP-vision similarity between the 4-step student's images and the
-# teacher's SAME-SEED full-schedule images must stay above this. Lower
-# than the encprop floor — the student is a learned approximation of
-# the whole trajectory, not a feature-reuse of it (LCM-class quality,
-# the `lcm` row of QualityGateConfig). Enforced only on real-weights
-# runs, advisory on random init, like every other gate.
+# teacher's SAME-SEED full-schedule images must stay above this — the
+# student is a learned approximation of the whole trajectory
+# (LCM-class quality, the `lcm` row of QualityGateConfig). Enforced
+# only on real-weights runs, advisory on random init, like every other
+# gate.
 CONSISTENCY_IMAGE_SIM_FLOOR = 0.90
 
 
@@ -202,8 +191,8 @@ def consistency_quality_report(
 ) -> dict:
     """The few-step quality gate (ISSUE 15): same-seed student (4-step
     consistency) vs teacher (full-schedule) outputs compared in
-    CLIP-vision space, plus both arms' prompt CLIP-sim for the record —
-    the encprop gate's structure applied to the distilled student.
+    CLIP-vision space (robust, image↔image — no text-prompt noise
+    term), plus both arms' prompt CLIP-sim for the record.
     ``passes_floor`` is the gate verdict; ``gate_enforced`` says
     whether it is a real-weights measurement or plumbing-only."""
     pair = harness.image_similarity(images_student, images_teacher)
@@ -242,10 +231,10 @@ def w8a8_quality_report(
     floor: float = W8A8_IMAGE_SIM_FLOOR,
 ) -> dict:
     """The W8A8 quality gate: same-seed quantized vs fp outputs
-    compared in CLIP-vision space (the encprop gate's structure applied
-    to the int8 kernel path). ``passes_floor`` is the gate verdict;
-    ``gate_enforced`` says whether it is a real-weights measurement or
-    plumbing-only."""
+    compared in CLIP-vision space (the consistency gate's structure
+    applied to the int8 kernel path). ``passes_floor`` is the gate
+    verdict; ``gate_enforced`` says whether it is a real-weights
+    measurement or plumbing-only."""
     pair = harness.image_similarity(images_w8a8, images_fp)
     return {
         "image_sim_mean": float(np.mean(pair)),
@@ -261,34 +250,3 @@ def w8a8_quality_report(
         "real_weights": harness.loaded_real_weights,
         "gate_enforced": harness.loaded_real_weights,
     }
-
-
-def encprop_quality_report(
-    harness: ClipSimilarityHarness,
-    images_encprop: np.ndarray,
-    images_full: np.ndarray,
-    prompts: Sequence[str],
-    floor: float = ENCPROP_IMAGE_SIM_FLOOR,
-) -> dict:
-    """The encprop image-quality gate: same-seed encprop vs full-forward
-    outputs compared in CLIP-vision space (robust, image↔image — no
-    text-prompt noise term), plus both arms' prompt CLIP-sim for the
-    record. ``passes_floor`` is the gate verdict; ``gate_enforced``
-    says whether it is a real-weights measurement or plumbing-only
-    (the enforcement convention of QualityGateConfig)."""
-    pair = harness.image_similarity(images_encprop, images_full)
-    report = {
-        "image_sim_mean": float(np.mean(pair)),
-        "image_sim_min": float(np.min(pair)),
-        "floor": float(floor),
-        "passes_floor": bool(np.mean(pair) >= floor),
-        "exact": bool(np.array_equal(images_encprop, images_full)),
-        "clip_sim_encprop": float(
-            np.mean(harness.similarity(images_encprop, prompts))),
-        "clip_sim_full": float(
-            np.mean(harness.similarity(images_full, prompts))),
-        "n": int(images_full.shape[0]),
-        "real_weights": harness.loaded_real_weights,
-        "gate_enforced": harness.loaded_real_weights,
-    }
-    return report

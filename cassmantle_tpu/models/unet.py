@@ -158,65 +158,16 @@ class UNet(nn.Module):
     @nn.compact
     def __call__(
         self,
-        latents: Optional[jax.Array],        # (B, H, W, 4) noisy latents
+        latents: jax.Array,                  # (B, H, W, 4) noisy latents
         timesteps: jax.Array,                # (B,) int/float
         context: jax.Array,                  # (B, S, context_dim) text states
         addition_embeds: Optional[jax.Array] = None,  # SDXL micro-conds
-        deep_cache: Optional[jax.Array] = None,
-        return_deep: bool = False,
-        skips_cache=None,
-        return_skips: bool = False,
     ) -> jax.Array:
-        """Denoise forward. Two pairs of extra modes implement feature
-        reuse across adjacent diffusion steps (PARITY.md documents both
-        approximation contracts):
-
-        Deep-feature reuse (DeepCache-style — ops/ddim.py::
-        ddim_sample_deepcache):
-
-        - ``return_deep=True``: also return the activation entering the
-          SHALLOWEST up level (captured after level 1's upsample conv).
-        - ``deep_cache=<that activation>``: run only conv_in + level-0
-          down blocks (fresh skips), substitute the cached deep
-          activation, and finish with level-0 up blocks + conv_out —
-          skipping every deeper level and the mid block entirely.
-
-        Encoder propagation (Faster Diffusion-style — ops/ddim.py::
-        ddim_sample_encprop; the symmetric counterpart that skips the
-        ENCODER instead of the deep levels):
-
-        - ``return_skips=True``: also return the encoder feature cache
-          ``(skip stack, up-path entry)`` — the full down-path skip
-          stack plus the activation entering the up path (the mid-block
-          output) as captured at a key step.
-        - ``skips_cache=<that cache>``: skip conv_in, every down level,
-          and the mid block; run ONLY the up path (+ conv_out) against
-          the cached skips. The time embedding stays fresh — it is the
-          only place the current timestep enters the decoder — so
-          ``latents`` may be None (nothing reads it). Because the
-          decoder never touches x_t, a run of consecutive propagated
-          steps can batch into ONE decoder forward (the cache rows
-          tile along batch; ops/ddim.py::make_cfg_denoiser_encprop).
-
-        Both return_* flags may be combined (the composed
-        deepcache+encprop serving loop captures both at key steps);
-        ``deep_cache`` and ``skips_cache`` are mutually exclusive.
-        """
+        """Denoise forward: predicted noise ``eps`` (B, H, W, 4), float32."""
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        decoder_only = skips_cache is not None
-        assert not (decoder_only and deep_cache is not None), (
-            "deep_cache and skips_cache are mutually exclusive modes"
-        )
-        if latents is not None:
-            latents = latents.astype(dtype)
-        else:
-            assert decoder_only, "latents may be None only with skips_cache"
+        latents = latents.astype(dtype)
         context = context.astype(dtype)
-        shallow_only = deep_cache is not None
-        assert not (return_skips and (shallow_only or decoder_only)), (
-            "return_skips needs the full encoder to have run"
-        )
 
         # -- time embedding ------------------------------------------------
         temb = timestep_embedding(timesteps, cfg.base_channels)
@@ -237,65 +188,43 @@ class UNet(nn.Module):
             return ResBlock(ch, dtype, fused_conv=cfg.fused_conv,
                             conv_pad_to=cfg.conv_pad_to, name=name)
 
-        if decoder_only:
-            # encoder propagation: the whole encoder (conv_in + down
-            # levels + mid block) is skipped — the cached skip stack and
-            # up-path entry stand in for it. Only temb above is fresh.
-            cached_skips, up_entry = skips_cache
-            skips = [s.astype(dtype) for s in cached_skips]
-            x = up_entry.astype(dtype)
-        else:
-            x = nn.Conv(cfg.base_channels, (3, 3), padding=1,
-                        dtype=dtype, name="conv_in")(latents)
+        x = nn.Conv(cfg.base_channels, (3, 3), padding=1,
+                    dtype=dtype, name="conv_in")(latents)
 
-            # -- down ------------------------------------------------------
-            skips = [x]
-            down_levels = 1 if shallow_only else levels
-            for lvl in range(down_levels):
-                ch = cfg.base_channels * cfg.channel_mults[lvl]
-                for blk in range(cfg.blocks_per_level):
-                    x = res_block(ch, f"down_{lvl}_res_{blk}")(x, temb)
-                    if cfg.attention_levels[lvl] \
-                            and cfg.transformer_depth[lvl]:
-                        x = SpatialTransformer(
-                            num_heads=self._heads(ch),
-                            depth=cfg.transformer_depth[lvl],
-                            context_dim=cfg.context_dim, dtype=dtype,
-                            name=f"down_{lvl}_attn_{blk}",
-                        )(x, context)
-                    skips.append(x)
-                if lvl != levels - 1 and not shallow_only:
-                    x = nn.Conv(ch, (3, 3), strides=(2, 2), padding=1,
-                                dtype=dtype,
-                                name=f"down_{lvl}_downsample")(x)
-                    skips.append(x)
+        # -- down ----------------------------------------------------------
+        skips = [x]
+        for lvl in range(levels):
+            ch = cfg.base_channels * cfg.channel_mults[lvl]
+            for blk in range(cfg.blocks_per_level):
+                x = res_block(ch, f"down_{lvl}_res_{blk}")(x, temb)
+                if cfg.attention_levels[lvl] and cfg.transformer_depth[lvl]:
+                    x = SpatialTransformer(
+                        num_heads=self._heads(ch),
+                        depth=cfg.transformer_depth[lvl],
+                        context_dim=cfg.context_dim, dtype=dtype,
+                        name=f"down_{lvl}_attn_{blk}",
+                    )(x, context)
+                skips.append(x)
+            if lvl != levels - 1:
+                x = nn.Conv(ch, (3, 3), strides=(2, 2), padding=1,
+                            dtype=dtype, name=f"down_{lvl}_downsample")(x)
+                skips.append(x)
 
-        skips_out = tuple(skips) if return_skips else None
-
-        if not shallow_only and not decoder_only:
-            # -- mid -------------------------------------------------------
-            mid_ch = cfg.base_channels * cfg.channel_mults[-1]
-            mid_depth = max(
-                [d for lvl, d in enumerate(cfg.transformer_depth)
-                 if cfg.attention_levels[lvl]] or [1]
-            )
-            x = res_block(mid_ch, "mid_res_0")(x, temb)
-            x = SpatialTransformer(
-                num_heads=self._heads(mid_ch), depth=mid_depth,
-                context_dim=cfg.context_dim, dtype=dtype, name="mid_attn",
-            )(x, context)
-            x = res_block(mid_ch, "mid_res_1")(x, temb)
-
-        up_entry_out = x if return_skips else None
+        # -- mid -----------------------------------------------------------
+        mid_ch = cfg.base_channels * cfg.channel_mults[-1]
+        mid_depth = max(
+            [d for lvl, d in enumerate(cfg.transformer_depth)
+             if cfg.attention_levels[lvl]] or [1]
+        )
+        x = res_block(mid_ch, "mid_res_0")(x, temb)
+        x = SpatialTransformer(
+            num_heads=self._heads(mid_ch), depth=mid_depth,
+            context_dim=cfg.context_dim, dtype=dtype, name="mid_attn",
+        )(x, context)
+        x = res_block(mid_ch, "mid_res_1")(x, temb)
 
         # -- up ------------------------------------------------------------
-        deep_out = None
-        up_levels = [0] if shallow_only else list(reversed(range(levels)))
-        if shallow_only:
-            x = deep_cache.astype(dtype)
-        for lvl in up_levels:
-            if lvl == 0 and return_deep:
-                deep_out = x
+        for lvl in reversed(range(levels)):
             ch = cfg.base_channels * cfg.channel_mults[lvl]
             for blk in range(cfg.blocks_per_level + 1):
                 skip = skips.pop()
@@ -319,11 +248,4 @@ class UNet(nn.Module):
         x = nn.silu(x)
         x = nn.Conv(cfg.sample_channels, (3, 3), padding=1,
                     dtype=jnp.float32, name="conv_out")(x)
-        eps = x.astype(jnp.float32)
-        if return_deep and return_skips:
-            return eps, deep_out, (skips_out, up_entry_out)
-        if return_deep:
-            return eps, deep_out
-        if return_skips:
-            return eps, (skips_out, up_entry_out)
-        return eps
+        return x.astype(jnp.float32)

@@ -17,8 +17,8 @@ This module makes the analytic model a *runtime* object (ISSUE 14):
 - :func:`flops_per_item` is what the serving pipelines call per
   dispatch variant: committed entry when the runtime signature matches
   the artifact (production configs — no tracing at startup), else a
-  trace-once of the pipeline's OWN jitted impl (exact for any config:
-  tiers, encprop, deepcache — the jaxpr is the truth), cached
+  trace-once of the pipeline's OWN jitted impl (exact for any config,
+  brownout tiers included — the jaxpr is the truth), cached
   process-wide. The result feeds ``block_timer(flops_est=...)``
   (utils/profiling.py): stage spans gain ``flops_est`` attrs and
   ``pipeline.mxu_utilization`` / ``request.device_flops`` report
@@ -223,8 +223,7 @@ def t2i_signature(cfg, sampler_cfg=None) -> str:
     s = sampler_cfg if sampler_cfg is not None else cfg.sampler
     m = cfg.models
     return _digest("t2i", m.unet.arch(), m.vae.arch(), m.clip_text,
-                   s.image_size, s.num_steps, s.kind, s.deepcache,
-                   s.encprop, s.encprop_stride, s.encprop_dense_steps,
+                   s.image_size, s.num_steps, s.kind,
                    s.consistency, _w8a8_effective(m.unet_w8a8))
 
 
@@ -233,9 +232,7 @@ def sdxl_signature(cfg, sampler_cfg=None) -> str:
     m = cfg.models
     return _digest("sdxl", m.unet.arch(), m.vae.arch(), m.clip_text,
                    m.clip_text_2, s.image_size, s.num_steps, s.kind,
-                   s.deepcache, s.encprop, s.encprop_stride,
-                   s.encprop_dense_steps, s.consistency,
-                   _w8a8_effective(m.unet_w8a8))
+                   s.consistency, _w8a8_effective(m.unet_w8a8))
 
 
 def lm_signature(mcfg, w8a8: bool = False) -> str:
@@ -306,7 +303,7 @@ def flops_per_item(kind: str, signature: str,
     1. the committed ``data/cost_model.json`` entry when the runtime
        signature matches (production configs — zero tracing cost);
     2. else ``tracer()`` — the caller traces its OWN jitted impl
-       (exact for tiers/encprop/deepcache), cached process-wide by
+       (exact for brownout tiers), cached process-wide by
        ``(kind, signature)``;
     3. else None — the dispatch simply carries no cost attribution
        (attribution must never break serving).

@@ -53,8 +53,7 @@ def consistency_disabled() -> bool:
     CASSMANTLE_NO_CONSISTENCY reverts consistency-configured serving to
     the TEACHER path — the plain configured sampler kind at
     ``SamplerConfig.consistency_teacher_steps`` — bit-exactly (read at
-    pipeline build/trace time, like CASSMANTLE_NO_ENCPROP: set it
-    before serving starts)."""
+    pipeline build/trace time: set it before serving starts)."""
     import os
 
     return os.environ.get("CASSMANTLE_NO_CONSISTENCY", "").lower() \
@@ -325,178 +324,6 @@ def dpmpp_2m_sample(
     return final
 
 
-def dpmpp_2m_sample_deepcache(
-    denoise_full: Callable,     # (x, t) -> (eps, deep_features)
-    denoise_shallow: Callable,  # (x, t, deep_features) -> eps
-    latents: jax.Array,
-    schedule: DPMppSchedule,
-) -> jax.Array:
-    """DPM-Solver++(2M) with deep-feature reuse — the two serving
-    speedups COMPOSED: half the steps of DDIM-50 (2M multistep) and
-    ~60% UNet compute on alternate steps (DeepCache pairing from
-    ops/ddim.py:ddim_sample_deepcache, same full/shallow contract).
-
-    Steps run in (full, shallow) pairs; an odd step count runs its
-    final step as an unpaired FULL pass — the t→0 step where accuracy
-    matters most never consumes a stale cache. The multistep history
-    m1 (previous step's predicted x0) threads through pairs unchanged,
-    so the integrator is exactly dpmpp_2m_sample wherever the eps
-    values agree.
-    """
-    n = schedule.timesteps.shape[0]
-    pairs = n // 2
-
-    def sl(a):
-        return a[: 2 * pairs].reshape(pairs, 2)
-
-    def one_update(x, m1, eps, alpha, sigma, c_skip, c_d0, c_d1):
-        m0 = (x - sigma * eps) / alpha
-        x = c_skip * x + c_d0 * m0 + c_d1 * m1
-        return x, m0
-
-    @jax.named_scope("denoise_step")
-    def pair_step(carry, per):
-        x, m1 = carry
-        t, alpha, sigma, c_skip, c_d0, c_d1 = per
-        eps, deep = denoise_full(x, t[0])
-        x, m1 = one_update(x, m1, eps, alpha[0], sigma[0],
-                           c_skip[0], c_d0[0], c_d1[0])
-        eps = denoise_shallow(x, t[1], deep)
-        x, m1 = one_update(x, m1, eps, alpha[1], sigma[1],
-                           c_skip[1], c_d0[1], c_d1[1])
-        return (x, m1), None
-
-    (x, m1), _ = jax.lax.scan(
-        pair_step, (latents, jnp.zeros_like(latents)),
-        (sl(schedule.timesteps), sl(schedule.alphas), sl(schedule.sigmas),
-         sl(schedule.c_skip), sl(schedule.c_d0), sl(schedule.c_d1)),
-    )
-    if n % 2:
-        eps, _ = denoise_full(x, schedule.timesteps[-1])
-        x, _ = one_update(x, m1, eps, schedule.alphas[-1],
-                          schedule.sigmas[-1], schedule.c_skip[-1],
-                          schedule.c_d0[-1], schedule.c_d1[-1])
-    return x
-
-
-def euler_spec(schedule: EulerSchedule) -> dict:
-    """Euler solver spec for :func:`cassmantle_tpu.ops.ddim.encprop_sample`
-    — per-step arithmetic verbatim from :func:`euler_sample` (x carried
-    in k-space; the denoiser sees the VP-space projection)."""
-    return {
-        "timesteps": schedule.timesteps,
-        "coefs": (schedule.sigmas[:-1], schedule.sigmas[1:]),
-        "init": lambda latents: (latents * schedule.sigmas[0],),
-        "x_for": lambda carry, c: carry[0] / jnp.sqrt(1.0 + c[0] * c[0]),
-        "update": lambda carry, eps, c: (carry[0] + (c[1] - c[0]) * eps,),
-        "final": lambda carry: carry[0],
-    }
-
-
-def dpmpp_2m_spec(schedule: DPMppSchedule) -> dict:
-    """DPM-Solver++(2M) spec for encprop sampling — the scan-body
-    expressions of :func:`dpmpp_2m_sample` verbatim; carry is (x, m1)
-    with the multistep history threading through key and propagated
-    steps unchanged."""
-    def update(carry, eps, c):
-        x, m1 = carry
-        alpha, sigma, c_skip, c_d0, c_d1 = c
-        m0 = (x - sigma * eps) / alpha
-        return (c_skip * x + c_d0 * m0 + c_d1 * m1, m0)
-
-    return {
-        "timesteps": schedule.timesteps,
-        "coefs": (schedule.alphas, schedule.sigmas, schedule.c_skip,
-                  schedule.c_d0, schedule.c_d1),
-        "init": lambda latents: (latents, jnp.zeros_like(latents)),
-        "x_for": lambda carry, c: carry[0],
-        "update": update,
-        "final": lambda carry: carry[0],
-    }
-
-
-def euler_sample_encprop(denoise_key, denoise_prop, latents,
-                         schedule: EulerSchedule, stride: int,
-                         dense_steps: int = 0,
-                         batch_props: bool = True) -> jax.Array:
-    """Euler with encoder propagation (see ops/ddim.py::encprop_sample;
-    no deepcache composition — euler has no deepcache loop to compose
-    with)."""
-    from cassmantle_tpu.ops.ddim import encprop_sample
-
-    return encprop_sample(
-        euler_spec(schedule), denoise_key, denoise_prop, latents,
-        stride, dense_steps, batch_props=batch_props)
-
-
-def dpmpp_2m_sample_encprop(denoise_key, denoise_prop, latents,
-                            schedule: DPMppSchedule, stride: int,
-                            dense_steps: int = 0,
-                            denoise_shallow=None,
-                            batch_props: bool = True) -> jax.Array:
-    """DPM-Solver++(2M) with encoder propagation; ``denoise_shallow``
-    composes DeepCache exactly as in ops/ddim.py::encprop_sample."""
-    from cassmantle_tpu.ops.ddim import encprop_sample
-
-    return encprop_sample(
-        dpmpp_2m_spec(schedule), denoise_key, denoise_prop, latents,
-        stride, dense_steps, denoise_shallow=denoise_shallow,
-        batch_props=batch_props)
-
-
-def make_encprop_sampler(kind: str, num_steps: int, stride: int,
-                         dense_steps: int = 0, deepcache: bool = False):
-    """(kind, steps, key schedule) ->
-    ``sample(denoise_key, denoise_prop, latents, denoise_shallow=None)``
-    — the encoder-propagation counterpart of :func:`make_sampler`,
-    covering every deterministic sampler kind. ``deepcache`` marks the
-    composed loop (the caller must then pass ``denoise_shallow`` and a
-    ``denoise_key`` that also returns the deep cache); euler+deepcache
-    is rejected here exactly as the plain deepcache path rejects it."""
-    from cassmantle_tpu.ops.ddim import (
-        DDIMSchedule,
-        ddim_sample_encprop,
-    )
-
-    if deepcache and kind not in ("ddim", "dpmpp_2m"):
-        raise AssertionError(
-            f"deepcache composes with ddim or dpmpp_2m, not {kind!r}")
-
-    if kind == "ddim":
-        schedule = DDIMSchedule.create(num_steps)
-
-        def sample(dk, dp, latents, denoise_shallow=None,
-                   batch_props=True):
-            return ddim_sample_encprop(
-                dk, dp, latents, schedule, stride, dense_steps,
-                denoise_shallow=denoise_shallow, batch_props=batch_props)
-
-        return sample
-    if kind == "euler":
-        eschedule = EulerSchedule.create(num_steps)
-
-        def sample(dk, dp, latents, denoise_shallow=None,
-                   batch_props=True):
-            assert denoise_shallow is None, "euler has no deepcache loop"
-            return euler_sample_encprop(
-                dk, dp, latents, eschedule, stride, dense_steps,
-                batch_props=batch_props)
-
-        return sample
-    if kind == "dpmpp_2m":
-        dschedule = DPMppSchedule.create(num_steps)
-
-        def sample(dk, dp, latents, denoise_shallow=None,
-                   batch_props=True):
-            return dpmpp_2m_sample_encprop(
-                dk, dp, latents, dschedule, stride, dense_steps,
-                denoise_shallow=denoise_shallow, batch_props=batch_props)
-
-        return sample
-    raise ValueError(f"unknown sampler kind {kind!r}; "
-                     f"choose from {SAMPLER_KINDS}")
-
-
 def make_slot_sampler(kind: str, num_steps: int, eta: float = 0.0,
                       teacher_steps: int = 50):
     """Step-granular counterpart of :func:`make_sampler` for the staged
@@ -523,7 +350,7 @@ def make_slot_sampler(kind: str, num_steps: int, eta: float = 0.0,
     staged-vs-monolithic parity bar (tests/test_stages.py). Only
     deterministic samplers qualify: ``eta > 0`` draws per-step noise
     from a carried key chain that step-boundary admission cannot
-    replay, so it (and deepcache's paired steps) stays monolithic.
+    replay, so it stays monolithic.
     """
     if eta != 0.0:
         raise ValueError(

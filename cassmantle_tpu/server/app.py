@@ -1498,15 +1498,11 @@ def main() -> None:
                              "cross-worker redirects; foreign rooms "
                              "serve locally)")
     parser.add_argument("--preset", default="sd15",
-                        choices=("sd15", "sdxl", "fast", "deepcache",
-                                 "turbo"),
+                        choices=("sd15", "sdxl", "fast"),
                         help="model/sampler preset: sd15 = SD1.5-512 "
                              "DDIM-50; sdxl = SDXL-base 1024 (the "
                              "reference's image model); fast = SD1.5 "
-                             "with DPM++(2M) @ 25 steps; deepcache = "
-                             "DDIM-50 with deep-feature reuse (~60% "
-                             "UNet compute); turbo = the two composed "
-                             "(DPM++(2M)@24 + deepcache)")
+                             "with DPM++(2M) @ 25 steps")
     parser.add_argument("--platform", default="auto",
                         choices=("auto", "cpu"),
                         help="'cpu' pins jax to host devices — e.g. "
@@ -1544,14 +1540,6 @@ def main() -> None:
         from cassmantle_tpu.config import fast_serving_config
 
         cfg = fast_serving_config()
-    elif args.preset == "deepcache":
-        from cassmantle_tpu.config import deepcache_serving_config
-
-        cfg = deepcache_serving_config()
-    elif args.preset == "turbo":
-        from cassmantle_tpu.config import turbo_serving_config
-
-        cfg = turbo_serving_config()
     else:
         cfg = FrameworkConfig()
     import dataclasses

@@ -24,9 +24,9 @@ mechanisms fix that:
   BEFORE queues back up (the loop is upstream of every queue).
 - :class:`BrownoutLadder` — a consumer of the SLO burn-rate engine
   (obs/slo.py): on sustained fast-window burn it steps through ordered
-  quality tiers (diffusion step-count reduction → encprop stride
-  increase → the few-step consistency student → resolution downshift →
-  blur-ladder coarsening), each tier
+  quality tiers (diffusion step-count reduction → the few-step
+  consistency student → resolution downshift → blur-ladder
+  coarsening), each tier
   a config *delta* the pipelines compile once and reuse (bucketed like
   every other serving variant — a tier change never recompiles in
   steady state). The active tier is counted
@@ -286,9 +286,6 @@ class BrownoutTier:
     # diffusion step-count multiplier (the dominant latency knob —
     # Efficient Diffusion Models survey, PAPERS.md)
     num_steps_scale: float = 1.0
-    # added to SamplerConfig.encprop_stride when encprop is on (more
-    # propagated decoder-only steps per full encoder forward)
-    encprop_stride_add: int = 0
     # step INTO the few-step consistency student
     # (SamplerConfig.consistency, ops/samplers.py::consistency_sample)
     # at CONSISTENCY_BROWNOUT_STEPS — the biggest step-count lever in
@@ -314,27 +311,25 @@ CONSISTENCY_BROWNOUT_STEPS = 4
 
 # Ordered mild → severe; tier 0 is full quality. Every tier includes
 # the previous tiers' deltas so stepping up only ever removes compute.
+# "stride" serves what "fewer-steps" serves: the tier gauge, the status
+# payload and the step-up timing count rungs by index.
 DEFAULT_TIERS: Tuple[BrownoutTier, ...] = (
     BrownoutTier("full"),
     BrownoutTier("fewer-steps", num_steps_scale=0.6),
-    BrownoutTier("stride", num_steps_scale=0.6, encprop_stride_add=2),
-    BrownoutTier("few-step", num_steps_scale=0.6, encprop_stride_add=2,
-                 consistency=True),
-    BrownoutTier("low-res", num_steps_scale=0.6, encprop_stride_add=2,
-                 consistency=True, image_size_scale=0.5),
-    BrownoutTier("coarse-blur", num_steps_scale=0.6,
-                 encprop_stride_add=2, consistency=True,
+    BrownoutTier("stride", num_steps_scale=0.6),
+    BrownoutTier("few-step", num_steps_scale=0.6, consistency=True),
+    BrownoutTier("low-res", num_steps_scale=0.6, consistency=True,
+                 image_size_scale=0.5),
+    BrownoutTier("coarse-blur", num_steps_scale=0.6, consistency=True,
                  image_size_scale=0.5, blur_bucket_px=2.0),
 )
 
 
 def degraded_sampler_cfg(sampler_cfg, tier: BrownoutTier):
     """Apply a tier's deltas to a SamplerConfig, respecting the
-    config's structural invariants (deepcache pairing needs even ddim
-    step counts, encprop's dense prefix must fit the step count, the
-    latent grid needs image_size on a /16 boundary, consistency does
-    not compose with deepcache/encprop). Returns a config EQUAL to the
-    input at tier 0 (callers skip the degraded path)."""
+    config's structural invariants (the latent grid needs image_size
+    on a /16 boundary, consistency is deterministic). Returns a config
+    EQUAL to the input at tier 0 (callers skip the degraded path)."""
     from cassmantle_tpu.ops.samplers import consistency_disabled
     from cassmantle_tpu.serving.pipeline import effective_sampler_cfg
 
@@ -345,11 +340,6 @@ def degraded_sampler_cfg(sampler_cfg, tier: BrownoutTier):
     # teacher revert the pipeline/staged paths take)
     s = effective_sampler_cfg(sampler_cfg)
     steps = max(2, int(round(s.num_steps * tier.num_steps_scale)))
-    if s.deepcache and s.kind == "ddim":
-        steps += steps % 2
-    stride = s.encprop_stride
-    if s.encprop and tier.encprop_stride_add:
-        stride = s.encprop_stride + int(tier.encprop_stride_add)
     size = s.image_size
     if tier.image_size_scale != 1.0:
         size = max(32, (int(s.image_size * tier.image_size_scale)
@@ -357,9 +347,9 @@ def degraded_sampler_cfg(sampler_cfg, tier: BrownoutTier):
     if (tier.consistency and not consistency_disabled()
             and (s.consistency or s.consistency_available)):
         # the few-step tier swaps the whole sampling loop for the
-        # consistency student; deepcache/encprop don't compose with it
-        # and eta is meaningless for the deterministic re-noise ladder,
-        # so the delta clears all three — and touches NOTHING else, so
+        # consistency student; eta is meaningless for the deterministic
+        # re-noise ladder, so the delta clears it — and touches
+        # NOTHING else, so
         # at the default geometry the delta's cost-model signature is
         # exactly the committed `t2i_lcm` entry's (no runtime jaxpr
         # trace while the system is shedding). A config ALREADY serving
@@ -368,12 +358,9 @@ def degraded_sampler_cfg(sampler_cfg, tier: BrownoutTier):
         few = (min(CONSISTENCY_BROWNOUT_STEPS, s.num_steps)
                if s.consistency else CONSISTENCY_BROWNOUT_STEPS)
         return dataclasses.replace(
-            s, consistency=True, num_steps=few, deepcache=False,
-            encprop=False, eta=0.0, image_size=size)
-    dense = min(s.encprop_dense_steps, steps)
-    return dataclasses.replace(
-        s, num_steps=steps, encprop_stride=stride, image_size=size,
-        encprop_dense_steps=dense)
+            s, consistency=True, num_steps=few, eta=0.0,
+            image_size=size)
+    return dataclasses.replace(s, num_steps=steps, image_size=size)
 
 
 class BrownoutLadder:

@@ -151,7 +151,7 @@ def share_compatible(models_a, models_b) -> bool:
 def int8_unet_tools(models_cfg):
     """(loader transform, apply wrapper) for the weights-only int8 UNet
     option — the one place the int8 serving contract lives (shared by
-    the SD1.5 and SDXL pipelines, like deepcache_schedule): quantize
+    the SD1.5 and SDXL pipelines, like dp_sharded_sampler): quantize
     host-side before device placement, dequantize inside the jit."""
     if not models_cfg.unet_int8:
         return None, lambda apply: apply
@@ -214,68 +214,13 @@ def w8a8_unet_tools(models_cfg):
         params, act_scales=scales, predicate=pred)
 
 
-def deepcache_schedule(sampler_cfg):
-    """Validate a deepcache sampler config and build the matching
-    schedule (shared by the SD1.5 and SDXL pipelines, like
-    dp_sharded_sampler). Composes with ddim (even steps only) and
-    dpmpp_2m (any step count; an odd final step runs unpaired-full)."""
-    assert sampler_cfg.eta == 0.0, \
-        "deepcache needs eta=0 (the paired loop is deterministic)"
-    if sampler_cfg.kind == "ddim":
-        from cassmantle_tpu.ops.ddim import DDIMSchedule
-
-        assert sampler_cfg.num_steps % 2 == 0, \
-            "ddim deepcache pairing needs an even step count"
-        return DDIMSchedule.create(sampler_cfg.num_steps)
-    if sampler_cfg.kind == "dpmpp_2m":
-        from cassmantle_tpu.ops.samplers import DPMppSchedule
-
-        return DPMppSchedule.create(sampler_cfg.num_steps)
-    raise AssertionError(
-        f"deepcache composes with ddim or dpmpp_2m, not "
-        f"{sampler_cfg.kind!r}")
-
-
-def encprop_plan(sampler_cfg):
-    """Validate an encoder-propagation sampler config and return its
-    ``(stride, dense_steps, key_count)`` key schedule (shared by the
-    SD1.5 and SDXL pipelines, like deepcache_schedule). Composes with
-    every deterministic sampler kind; eta>0 is rejected (propagated
-    steps replay the decoder deterministically — there is no per-step
-    noise chain to reuse), and the deepcache composition inherits
-    deepcache's own sampler-kind constraint."""
-    from cassmantle_tpu.ops.ddim import encprop_key_indices
-    from cassmantle_tpu.ops.samplers import SAMPLER_KINDS
-
-    assert sampler_cfg.eta == 0.0, \
-        "encprop needs eta=0 (the propagated decoder loop is deterministic)"
-    assert sampler_cfg.kind in SAMPLER_KINDS, \
-        f"encprop composes with {SAMPLER_KINDS}, not {sampler_cfg.kind!r}"
-    assert sampler_cfg.encprop_stride >= 1, \
-        f"encprop stride must be >= 1, got {sampler_cfg.encprop_stride}"
-    assert 0 <= sampler_cfg.encprop_dense_steps <= sampler_cfg.num_steps, \
-        "encprop dense prefix outside the step count"
-    if sampler_cfg.deepcache:
-        assert sampler_cfg.kind in ("ddim", "dpmpp_2m"), \
-            "deepcache composes with ddim or dpmpp_2m, not " \
-            f"{sampler_cfg.kind!r}"
-    keys = encprop_key_indices(
-        sampler_cfg.num_steps, sampler_cfg.encprop_stride,
-        sampler_cfg.encprop_dense_steps)
-    return (sampler_cfg.encprop_stride, sampler_cfg.encprop_dense_steps,
-            len(keys))
-
-
 def consistency_plan(sampler_cfg) -> int:
     """Validate a few-step consistency sampler config and return its
     step count (shared by the SD1.5 and SDXL pipelines, like
-    deepcache_schedule/encprop_plan). Consistency serving IS the
-    few-step path — 1-8 direct x0 predictions — and does not compose
-    with deepcache or encprop: the student is trained for direct
-    few-step prediction, so there is no long solver loop to cache
-    into. eta>0 is rejected (the re-noise ladder is deterministic by
-    construction — what lets few-step requests ride the staged
-    slot stepper)."""
+    dp_sharded_sampler). Consistency serving IS the few-step path —
+    1-8 direct x0 predictions. eta>0 is rejected (the re-noise ladder
+    is deterministic by construction — what lets few-step requests
+    ride the staged slot stepper)."""
     s = sampler_cfg
     assert s.eta == 0.0, \
         "consistency sampling is deterministic (eta=0)"
@@ -283,10 +228,6 @@ def consistency_plan(sampler_cfg) -> int:
         f"consistency serving is the few-step path (1-8 steps), got "
         f"{s.num_steps}; the teacher schedule lives in "
         f"consistency_teacher_steps")
-    assert not s.deepcache, \
-        "consistency does not compose with deepcache (no paired loop)"
-    assert not s.encprop, \
-        "consistency does not compose with encprop (no key schedule)"
     assert s.consistency_teacher_steps > s.num_steps, (
         f"consistency_teacher_steps ({s.consistency_teacher_steps}) must "
         f"exceed num_steps ({s.num_steps}): the student only ever trains "
@@ -325,7 +266,8 @@ def effective_sampler_steps(sampler_cfg) -> int:
 
 def note_consistency_counter(sampler_cfg, n_images: int) -> None:
     """Diagnosis counter for few-step serving (host-side, derived from
-    the static schedule like note_encprop_counters): how many
+    the static schedule — the step loop itself is one XLA computation,
+    so per-step device counters would cost a host sync): how many
     consistency UNet forwards the dispatch performed —
     ``pipeline.consistency_steps`` / images = UNet forwards per image,
     the number the `sd15_lcm` bench A/B attaches. Silent when the knob
@@ -374,91 +316,26 @@ def note_w8a8_counter(models_cfg, sampler_cfg, n_images: int) -> None:
                     effective_sampler_steps(sampler_cfg) * n_images)
 
 
-def run_cfg_denoise(sampler_cfg, sample_latents, dc_schedule, unet_apply,
+def run_cfg_denoise(sampler_cfg, sample_latents, unet_apply,
                     params, ctx, uncond_ctx, lat,
                     addition_embeds=None, uncond_addition_embeds=None):
     """The denoise stage both image pipelines share: few-step
-    consistency sampling (the distilled-student path), plain CFG
-    sampling, the deepcache full/shallow pairing, or encoder
-    propagation (full forwards at key steps, batched decoder-only
-    forwards in between — possibly composed with deepcache) when
-    configured."""
-    from cassmantle_tpu.ops.ddim import encprop_disabled
+    consistency sampling (the distilled-student path) when configured,
+    else plain CFG sampling."""
     from cassmantle_tpu.ops.samplers import consistency_disabled
 
-    if sampler_cfg.consistency and not consistency_disabled():
-        from cassmantle_tpu.ops.samplers import make_consistency_sampler
-
-        denoise = make_cfg_denoiser(
-            unet_apply, params, ctx, uncond_ctx,
-            sampler_cfg.guidance_scale,
-            addition_embeds=addition_embeds,
-            uncond_addition_embeds=uncond_addition_embeds,
-        )
-        return make_consistency_sampler(
-            sampler_cfg.num_steps,
-            sampler_cfg.consistency_teacher_steps)(denoise, lat)
-    if sampler_cfg.encprop and not encprop_disabled():
-        from cassmantle_tpu.ops.ddim import make_cfg_denoiser_encprop
-        from cassmantle_tpu.ops.samplers import make_encprop_sampler
-
-        stride, dense, _ = encprop_plan(sampler_cfg)
-        sample = make_encprop_sampler(
-            sampler_cfg.kind, sampler_cfg.num_steps, stride, dense,
-            deepcache=sampler_cfg.deepcache)
-        dn_key, dn_prop, dn_shallow = make_cfg_denoiser_encprop(
-            unet_apply, params, ctx, uncond_ctx,
-            sampler_cfg.guidance_scale,
-            addition_embeds=addition_embeds,
-            uncond_addition_embeds=uncond_addition_embeds,
-            deepcache=sampler_cfg.deepcache,
-        )
-        return sample(dn_key, dn_prop, lat, denoise_shallow=dn_shallow)
-    if sampler_cfg.deepcache:
-        from cassmantle_tpu.ops.ddim import (
-            ddim_sample_deepcache,
-            make_cfg_denoiser_pair,
-        )
-
-        dn_full, dn_shallow = make_cfg_denoiser_pair(
-            unet_apply, params, ctx, uncond_ctx,
-            sampler_cfg.guidance_scale,
-            addition_embeds=addition_embeds,
-            uncond_addition_embeds=uncond_addition_embeds,
-        )
-        if sampler_cfg.kind == "dpmpp_2m":
-            from cassmantle_tpu.ops.samplers import (
-                dpmpp_2m_sample_deepcache,
-            )
-
-            return dpmpp_2m_sample_deepcache(
-                dn_full, dn_shallow, lat, dc_schedule)
-        return ddim_sample_deepcache(dn_full, dn_shallow, lat, dc_schedule)
     denoise = make_cfg_denoiser(
         unet_apply, params, ctx, uncond_ctx, sampler_cfg.guidance_scale,
         addition_embeds=addition_embeds,
         uncond_addition_embeds=uncond_addition_embeds,
     )
+    if sampler_cfg.consistency and not consistency_disabled():
+        from cassmantle_tpu.ops.samplers import make_consistency_sampler
+
+        return make_consistency_sampler(
+            sampler_cfg.num_steps,
+            sampler_cfg.consistency_teacher_steps)(denoise, lat)
     return sample_latents(denoise, lat)
-
-
-def note_encprop_counters(counts, n_images: int) -> None:
-    """Diagnosis counters for encoder propagation (host-side, derived
-    from the static key schedule — the step loop itself is one XLA
-    computation, so per-step device counters would cost a host sync):
-    how many full-encoder, deepcache-shallow (composed loop only), and
-    decoder-only UNet forwards the serving path dispatched. Shared by
-    both image pipelines; silent when the config or the kill switch has
-    encprop off, so bench A/B counter deltas separate the arms."""
-    from cassmantle_tpu.ops.ddim import encprop_disabled
-
-    if counts and not encprop_disabled():
-        keys, shallow, props = counts
-        metrics.inc("pipeline.encprop_key_steps", keys * n_images)
-        if shallow:
-            metrics.inc("pipeline.encprop_shallow_steps",
-                        shallow * n_images)
-        metrics.inc("pipeline.encprop_prop_steps", props * n_images)
 
 
 def degraded_dispatch_variant(cache: dict, sampler_cfg, mesh,
@@ -466,13 +343,12 @@ def degraded_dispatch_variant(cache: dict, sampler_cfg, mesh,
     """Shared brownout-variant machinery for BOTH image pipelines
     (serving/overload.py, ISSUE 13): resolve the active tier into a
     degraded SamplerConfig, build that delta's sampler + schedules +
-    jitted dispatch ONCE (cached by the (steps, stride, size) key — a
-    tier change never recompiles in steady state), and fall back to
-    full quality on any build failure. ``build_impl(scfg, sampler,
-    dc_schedule)`` returns the pipeline-specific sample impl, jitted
-    under the pipeline's program ``name``; returns
-    ``(sample_fn, scfg, encprop_counts)`` or None (tier 0 / no-op
-    delta / unusable delta)."""
+    jitted dispatch ONCE (cached by the (steps, size, consistency) key
+    — a tier change never recompiles in steady state), and fall back
+    to full quality on any build failure. ``build_impl(scfg, sampler)``
+    returns the pipeline-specific sample impl, jitted under the
+    pipeline's program ``name``; returns ``(sample_fn, scfg)`` or None
+    (tier 0 / no-op delta / unusable delta)."""
     from cassmantle_tpu.serving import overload
 
     tier = overload.quality_overrides()
@@ -482,29 +358,19 @@ def degraded_dispatch_variant(cache: dict, sampler_cfg, mesh,
         scfg = overload.degraded_sampler_cfg(sampler_cfg, tier)
         if scfg == sampler_cfg:
             return None
-        key = (scfg.num_steps, scfg.encprop_stride, scfg.image_size,
-               scfg.consistency)
+        key = (scfg.num_steps, scfg.image_size, scfg.consistency)
         entry = cache.get(key)
         if entry is None:
             if scfg.consistency:
                 consistency_plan(scfg)
-            dc = deepcache_schedule(scfg) if scfg.deepcache else None
-            counts = None
-            if scfg.encprop:
-                from cassmantle_tpu.ops.ddim import encprop_step_counts
-
-                encprop_plan(scfg)
-                counts = encprop_step_counts(
-                    scfg.num_steps, scfg.encprop_stride,
-                    scfg.encprop_dense_steps, scfg.deepcache)
             # consistency tiers dispatch their own sampler inside
             # run_cfg_denoise; a plain schedule here would be dead code
             sampler = (None if scfg.consistency
                        else make_sampler(scfg.kind, scfg.num_steps,
                                          eta=scfg.eta))
-            fn, _ = dp_sharded_sampler(build_impl(scfg, sampler, dc),
+            fn, _ = dp_sharded_sampler(build_impl(scfg, sampler),
                                        mesh, name)
-            entry = (fn, scfg, counts)
+            entry = (fn, scfg)
             cache[key] = entry
         return entry
     except Exception:
@@ -560,7 +426,7 @@ class Text2ImagePipeline:
         """``share_params_with``: reuse another pipeline's already-loaded
         param trees (device buffers are shared, nothing is copied) when
         the model architectures match — presets that differ only in
-        sampler (ddim50 vs dpmpp25 vs deepcache) then skip re-reading
+        sampler (ddim50 vs dpmpp25) then skip re-reading
         and re-converting the multi-GB checkpoints per variant. A donor
         that differs ONLY in ``unet_int8`` still shares CLIP/VAE, and an
         int8 pipeline derives its quantized UNet from the donor's
@@ -723,18 +589,6 @@ class Text2ImagePipeline:
             log.info("%s", w8a8_describe(
                 w8a8_calibrated(self.unet_params),
                 w8a8_site_count(self.unet_params)))
-        self._dc_schedule = (deepcache_schedule(cfg.sampler)
-                             if cfg.sampler.deepcache else None)
-        # fail fast on invalid encprop configs and precompute the
-        # key/shallow/propagated accounting the diagnosis counters report
-        self._encprop_counts = None
-        if cfg.sampler.encprop:
-            from cassmantle_tpu.ops.ddim import encprop_step_counts
-
-            encprop_plan(cfg.sampler)
-            self._encprop_counts = encprop_step_counts(
-                cfg.sampler.num_steps, cfg.sampler.encprop_stride,
-                cfg.sampler.encprop_dense_steps, cfg.sampler.deepcache)
         # fail fast on invalid few-step consistency configs; with the
         # kill switch set the plain schedule below IS the teacher path
         # (run_cfg_denoise falls through to it), so the revert is
@@ -756,7 +610,7 @@ class Text2ImagePipeline:
         self._sample, self.dp = dp_sharded_sampler(
             self._sample_impl, mesh, "t2i_sample")
         # brownout actuation (serving/overload.py, ISSUE 13): degraded
-        # sampler variants keyed by their (steps, stride, size) delta —
+        # sampler variants keyed by their (steps, size, consistency) delta —
         # each TIER compiles once on first engagement and is reused
         # (bucketed like every other serving variant), so steady-state
         # tier changes never recompile. Tier 0 uses self._sample
@@ -837,11 +691,9 @@ class Text2ImagePipeline:
     def _staged_enabled(self) -> bool:
         """Per-call routing decision: the ServingConfig knob, minus the
         runtime kill switch, minus configs the slot stepper cannot
-        replay exactly — deepcache's paired steps, encprop's per-segment
-        key/propagated structure (slots sit at arbitrary schedule
-        positions; a slot admitted mid-segment has no cache), eta>0's
-        per-step noise chain, non-stageable sampler kinds, and meshed
-        (dp/sp) serving all keep the proven monolithic dispatch."""
+        replay exactly — eta>0's per-step noise chain, non-stageable
+        sampler kinds, and meshed (dp/sp) serving all keep the proven
+        monolithic dispatch."""
         from cassmantle_tpu.serving.stages import (
             STAGEABLE_KINDS,
             staged_serving_disabled,
@@ -851,8 +703,6 @@ class Text2ImagePipeline:
         return (self.cfg.serving.staged_serving
                 and not staged_serving_disabled()
                 and self.mesh is None
-                and not s.deepcache
-                and not s.encprop
                 and s.eta == 0.0
                 and s.kind in STAGEABLE_KINDS)
 
@@ -894,7 +744,7 @@ class Text2ImagePipeline:
 
     def _sample_impl(self, params, ids, uncond_ids, rng):
         return self._build_tier_impl(
-            self.cfg.sampler, self.sample_latents, self._dc_schedule)(
+            self.cfg.sampler, self.sample_latents)(
                 params, ids, uncond_ids, rng)
 
     def _tokenize(self, prompts: Sequence[str]) -> np.ndarray:
@@ -905,10 +755,10 @@ class Text2ImagePipeline:
 
     # -- brownout actuation (serving/overload.py, ISSUE 13) ----------------
 
-    def _build_tier_impl(self, scfg, sampler, dc):
+    def _build_tier_impl(self, scfg, sampler):
         """The SD1.5 sample impl bound to a sampler config: the
         pipeline's own (``_sample_impl``) or a degraded tier's, with
-        (steps, stride, size) swapped. The stage scopes are op
+        (steps, size) swapped. The stage scopes are op
         metadata: a device trace puts every operation under
         ``clip_encode``, ``denoise_scan/denoise_step`` or
         ``vae_decode``, then the Flax module path."""
@@ -923,7 +773,7 @@ class Text2ImagePipeline:
             lat = spatially_shard_latents(lat, self.mesh)
             with jax.named_scope("denoise_scan"):
                 final = run_cfg_denoise(
-                    scfg, sampler, dc, self.unet_apply,
+                    scfg, sampler, self.unet_apply,
                     params["unet"], ctx, uncond, lat,
                 )
             with jax.named_scope("vae_decode"):
@@ -933,7 +783,7 @@ class Text2ImagePipeline:
         return impl
 
     def _degraded_sampler(self):
-        """(sample_fn, sampler_cfg, encprop_counts) for the active
+        """(sample_fn, sampler_cfg) for the active
         brownout tier, or None at full quality (see
         :func:`degraded_dispatch_variant`)."""
         return degraded_dispatch_variant(
@@ -946,8 +796,8 @@ class Text2ImagePipeline:
         no attribution yet): the committed data/cost_model.json entry
         when the runtime signature matches the artifact, else a
         trace-once of the actual jitted ``sample_fn`` — exact for any
-        variant (tiers, deepcache, encprop) because the jaxpr is the
-        truth. Shared by the SDXL pipeline (same dispatch shape).
+        variant (brownout tiers) because the jaxpr is the truth. Shared
+        by the SDXL pipeline (same dispatch shape).
 
         Resolution is locked (racing executor threads pay one trace,
         not one each) and tiered by urgency: the pipeline's OWN config
@@ -961,8 +811,7 @@ class Text2ImagePipeline:
         # attribution follows what is DISPATCHED: under the consistency
         # kill switch the effective config is the teacher schedule
         eff = effective_sampler_cfg(scfg)
-        key = (eff.num_steps, eff.image_size, eff.encprop,
-               eff.encprop_stride, eff.deepcache, eff.consistency)
+        key = (eff.num_steps, eff.image_size, eff.consistency)
         if signature is None:
             signature = costmodel.t2i_signature(self.cfg, eff)
 
@@ -1024,9 +873,9 @@ class Text2ImagePipeline:
             note_w8a8_counter(self.cfg.models, self.cfg.sampler,
                               len(prompts))
             return images
-        sample_fn, scfg, ep_counts = (
+        sample_fn, scfg = (
             degraded if degraded is not None
-            else (self._sample, self.cfg.sampler, self._encprop_counts))
+            else (self._sample, self.cfg.sampler))
         padded, n = pad_prompts_to_dp(prompts, self.dp)
         ids = jnp.asarray(self._tokenize(padded))
         uncond = jnp.asarray(self._tokenize(
@@ -1065,7 +914,6 @@ class Text2ImagePipeline:
             metrics.inc("pipeline.images", n)
             if degraded is not None:
                 metrics.inc("pipeline.brownout_images", n)
-            note_encprop_counters(ep_counts, n)
             note_consistency_counter(scfg, n)
             note_w8a8_counter(self.cfg.models, scfg, n)
         return out
@@ -1146,19 +994,6 @@ class Text2ImagePipeline:
         in (0, 1]: fraction of the schedule re-run; higher = less of the
         input survives. Single-chip path (no dp sharding)."""
         assert 0.0 < strength <= 1.0
-        if self.cfg.sampler.deepcache:
-            raise NotImplementedError(
-                "img2img does not support deepcache (schedule tails have "
-                "arbitrary parity); use a non-deepcache config for "
-                "image-conditioned generation"
-            )
-        if self.cfg.sampler.encprop:
-            raise NotImplementedError(
-                "img2img does not support encoder propagation (strength "
-                "tails start mid-schedule, where the dense-prefix key "
-                "accounting no longer holds); use a non-encprop config "
-                "for image-conditioned generation"
-            )
         if self.cfg.sampler.consistency:
             raise NotImplementedError(
                 "img2img does not support the few-step consistency "

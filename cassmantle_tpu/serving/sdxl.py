@@ -77,9 +77,9 @@ class SDXLPipeline:
     ) -> None:
         """``share_params_with``: reuse another SDXL pipeline's loaded
         param trees (device buffers shared, nothing copied) when the
-        architectures match — the `sdxl_encprop` bench A/B arms then
-        hold ONE set of the multi-GB SDXL weights in HBM instead of
-        two. Stricter than the SD1.5 donor contract: both text towers
+        architectures match — pipelines that differ only in sampler
+        then hold ONE set of the multi-GB SDXL weights in HBM instead
+        of two. Stricter than the SD1.5 donor contract: both text towers
         and the int8 flag must match exactly (SDXL has no
         int8-asymmetry re-load path)."""
         enable_compile_cache()
@@ -222,23 +222,6 @@ class SDXLPipeline:
 
         self._param_loader = load_all_params
         load_all_params()
-        from cassmantle_tpu.serving.pipeline import (
-            deepcache_schedule,
-            encprop_plan,
-        )
-
-        self._dc_schedule = (deepcache_schedule(cfg.sampler)
-                             if cfg.sampler.deepcache else None)
-        # fail fast on invalid encprop configs + accounting for the
-        # diagnosis counters (see Text2ImagePipeline)
-        self._encprop_counts = None
-        if cfg.sampler.encprop:
-            from cassmantle_tpu.ops.ddim import encprop_step_counts
-
-            encprop_plan(cfg.sampler)
-            self._encprop_counts = encprop_step_counts(
-                cfg.sampler.num_steps, cfg.sampler.encprop_stride,
-                cfg.sampler.encprop_dense_steps, cfg.sampler.deepcache)
         self.unet_apply = wrap_unet_apply(self.unet.apply)
         from cassmantle_tpu.ops.fused_conv import describe as fc_describe
 
@@ -366,7 +349,7 @@ class SDXLPipeline:
 
     def _sample_impl(self, params, ids, uncond_ids, rng):
         return self._build_tier_impl(
-            self.cfg.sampler, self.sample_latents, self._dc_schedule)(
+            self.cfg.sampler, self.sample_latents)(
                 params, ids, uncond_ids, rng)
 
     def _tokenize(self, prompts: Sequence[str]) -> np.ndarray:
@@ -424,10 +407,10 @@ class SDXLPipeline:
                     )
         return self._staged
 
-    def _build_tier_impl(self, scfg, sampler, dc):
+    def _build_tier_impl(self, scfg, sampler):
         """The SDXL sample impl bound to a sampler config: the
         pipeline's own (``_sample_impl``) or a degraded tier's, with
-        (steps, stride, size) swapped and the micro-conditioning
+        (steps, size) swapped and the micro-conditioning
         time_ids tracking the size. Stage scopes as in the SD1.5
         pipeline, under the same names."""
         from cassmantle_tpu.serving.pipeline import (
@@ -448,7 +431,7 @@ class SDXLPipeline:
             lat = spatially_shard_latents(lat, self.mesh)
             with jax.named_scope("denoise_scan"):
                 final = run_cfg_denoise(
-                    scfg, sampler, dc, self.unet_apply,
+                    scfg, sampler, self.unet_apply,
                     params["unet"], ctx, uctx, lat,
                     addition_embeds=add,
                     uncond_addition_embeds=uadd,
@@ -499,7 +482,6 @@ class SDXLPipeline:
         from cassmantle_tpu.serving.pipeline import (
             IMAGE_BATCH_BUCKETS,
             note_consistency_counter,
-            note_encprop_counters,
             note_w8a8_counter,
         )
 
@@ -512,9 +494,9 @@ class SDXLPipeline:
             note_w8a8_counter(self.cfg.models, self.cfg.sampler,
                               len(prompts))
             return images
-        sample_fn, scfg, ep_counts = (
+        sample_fn, scfg = (
             degraded if degraded is not None
-            else (self._sample, self.cfg.sampler, self._encprop_counts))
+            else (self._sample, self.cfg.sampler))
         from cassmantle_tpu.serving.pipeline import pad_prompts_to_dp
 
         padded, n = pad_prompts_to_dp(prompts, self.dp)
@@ -547,7 +529,6 @@ class SDXLPipeline:
             metrics.inc("pipeline.sdxl_images", n)
             if degraded is not None:
                 metrics.inc("pipeline.brownout_images", n)
-            note_encprop_counters(ep_counts, n)
             note_consistency_counter(scfg, n)
             note_w8a8_counter(self.cfg.models, scfg, n)
         return out

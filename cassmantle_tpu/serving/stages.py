@@ -167,11 +167,10 @@ class StagedImageServer:
         self._negative = cfg.sampler.negative_prompt
         self._supervisor = supervisor
         s = cfg.sampler
-        assert s.kind in STAGEABLE_KINDS and not s.deepcache \
-            and s.eta == 0.0, (
-                "staged serving supports deterministic ddim/euler/dpmpp_2m "
-                "without deepcache; the pipeline should have fallen back "
-                f"to monolithic for {s.kind!r}")
+        assert s.kind in STAGEABLE_KINDS and s.eta == 0.0, (
+            "staged serving supports deterministic ddim/euler/dpmpp_2m; "
+            "the pipeline should have fallen back "
+            f"to monolithic for {s.kind!r}")
         self.capacity = int(cfg.serving.denoise_slots)
         assert self.capacity >= 1
         # step-width bucket ladder: powers of two up to capacity (plus

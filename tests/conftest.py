@@ -124,20 +124,16 @@ FAST_MODULES = frozenset({
     # actually runs in the default sweep. test_spec_decode stays for
     # the same reason: greedy/spec bit-parity + the jit-sentinel
     # steady-state assertions are tier-1 acceptance bars (PR 5/7).
-    # test_encprop follows the same pattern (round 16): it compiles
-    # two tiny pipelines, but stride-1 bit-parity, the quality gate,
-    # and the warmed-encprop-loop jit sentinel are acceptance bars
-    # that MUST run in the default sweep; its secondary pipeline
-    # smokes (kill switch, counters, batched-decoder equivalence,
-    # composed/preset pipelines) live in test_encprop_serving (slow).
+    # test_sdxl, test_img2img, test_mistral and test_torch_parity are
+    # in the default tier as well: the only tests that execute
+    # serving/sdxl.py, the img2img sampler path, the model the
+    # benchmark's own test fixture uses, and the zoo against an
+    # outside implementation.
 })
 
 SLOW_MODULES = frozenset({
     "test_parallel",   # 8-device mesh collectives: ~6 min of compiles
-    "test_sdxl",       # dual-tower pipeline compiles: ~3 min
     "test_cli",        # subprocess-per-test CLI runs: ~2.5 min
-    "test_deepcache",  # paired full/shallow pipeline compiles: ~2 min
-    "test_img2img",    # encoder + per-strength-bucket compiles: ~1.5 min
     "test_manifests",  # full converter grammars over manifests: ~1 min
     # multi-process fabric cluster runs (worker subprocesses + sustained
     # HTTP/WS load + the store-leader failover drill): ~15 s of pure
@@ -150,29 +146,11 @@ SLOW_MODULES = frozenset({
     # behavior live in test_chaos / test_fault_injection /
     # test_chaos_recovery
     "test_chaos_drill",
-    # moved to slow at round 14: the default tier outgrew its tier-1
-    # window on a 2-core host (the fabric + cluster-obs suites grew it
-    # past ~900s vs the 870s budget) and was alphabetically truncating
-    # its own tail — exactly what this split exists to prevent. Their
-    # tier-1 coverage is duplicated: test_weights pins every torch
-    # converter; test_spec_decode pins mistral decode_chunk/greedy
-    # parity. Both still run in the full tier (~92s together).
-    "test_torch_parity",  # torch cross-checks of the jax zoo
-    "test_mistral",       # RoPE/GQA/sliding-window reference parity
     # ~75s of compile-bound distributed LM TRAINING steps — serving-
     # independent; the multi-device path keeps tier-1 smoke coverage
     # via test_multihost (fast) and full coverage via test_parallel
-    # (slow). Moved with the round-14 pair above for timing margin:
-    # the default tier was landing within run-to-run variance of the
-    # 870s window (777s pass / ~880s miss on the same tree).
+    # (slow).
     "test_lm_train",
-    # secondary encprop serving smokes (each compiles another whole
-    # tiny pipeline or unet scan, ~80s together on a small host); the
-    # tier-1 acceptance bars — stride-1 bit-parity on both geometries,
-    # the quality-gate mechanism, key-schedule accounting, the warmed-
-    # loop jit sentinel, decode-kernel parity — stay in the default
-    # tier via test_encprop (round 16)
-    "test_encprop_serving",
 })
 
 

@@ -128,10 +128,10 @@ def test_north_star_only_runs_fast_path(tmp_path, monkeypatch):
 
 
 def test_suite_order_is_north_star_first():
-    """Suites get cut short: sd15 and sd15_turbo must be the first two
+    """Suites get cut short: sd15 and sd15_fast must be the first two
     entries so a partial run still lands the perf-case numbers."""
     bench = _import_bench()
-    assert list(bench.SUITE)[:2] == ["sd15", "sd15_turbo"]
+    assert list(bench.SUITE)[:2] == ["sd15", "sd15_fast"]
 
 
 def test_kept_prior_is_annotated_with_fresh_error(tmp_path, monkeypatch):

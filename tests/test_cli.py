@@ -87,19 +87,19 @@ def test_quality_gate_thresholds():
     def report(anchor_sim, parity):
         return {"presets": {
             "ddim50": {"clip_sim_mean": anchor_sim},
-            "turbo": {"clip_sim_mean": anchor_sim * parity,
-                      "parity_vs_ddim50": parity},
+            "dpmpp25": {"clip_sim_mean": anchor_sim * parity,
+                        "parity_vs_ddim50": parity},
         }}
 
     clean = report(0.30, 0.99)
     assert mod.apply_quality_gate(clean) == []
-    assert clean["presets"]["turbo"]["gate"]["passed"]
+    assert clean["presets"]["dpmpp25"]["gate"]["passed"]
     assert clean["presets"]["ddim50"]["gate"]["passed"]
 
-    low_parity = report(0.30, 0.90)  # turbo gates at 0.95
+    low_parity = report(0.30, 0.90)  # dpmpp25 gates at 0.97
     fails = mod.apply_quality_gate(low_parity)
-    assert len(fails) == 1 and "turbo" in fails[0]
-    assert not low_parity["presets"]["turbo"]["gate"]["passed"]
+    assert len(fails) == 1 and "dpmpp25" in fails[0]
+    assert not low_parity["presets"]["dpmpp25"]["gate"]["passed"]
 
     dead_anchor = report(0.05, 0.99)  # uniform degradation
     fails = mod.apply_quality_gate(dead_anchor)

@@ -239,3 +239,30 @@ def test_dispatch_per_batch_shard_matches_unsharded(seq_k):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(xla_attention(q, k, v)),
         atol=2e-5, rtol=2e-5)
+
+
+def test_flash_vae_attention_parity_and_gate():
+    """The VAE mid block's single-head, full-channel-width attention
+    takes the flash kernel as a self-attention site of one wide head;
+    numeric parity vs the XLA path, and the gate keeps ragged
+    sequences off the kernel."""
+    from cassmantle_tpu.ops.attention import multi_head_attention
+
+    q = jax.random.normal(jax.random.PRNGKey(1), (1, 512, 1, 320),
+                          jnp.float32)
+    assert flash_plan(q, q).kind == "flash_self"
+    ref = multi_head_attention(q, q, q, use_flash=False)
+    out = multi_head_attention(q, q, q, use_flash=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+    # production width and narrow heads are the same plan kind; a
+    # ragged S stays XLA, and so does a head wider than the kernel's tile
+    q_wide = jnp.zeros((1, 4096, 1, 512))
+    q_narrow = jnp.zeros((1, 1024, 1, 64))
+    assert (flash_plan(q_wide, q_wide).kind
+            == flash_plan(q_narrow, q_narrow).kind == "flash_self")
+    q_ragged = jnp.zeros((1, 500, 1, 320))
+    assert flash_plan(q_ragged, q_ragged) is None
+    q_fat = jnp.zeros((1, 1024, 1, 2048))
+    assert flash_plan(q_fat, q_fat) is None

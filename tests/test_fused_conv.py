@@ -163,6 +163,57 @@ def test_unet_flag_parity(cfg):
                                atol=2e-4, rtol=1e-3)
 
 
+def test_fused_vae_resblocks_numeric_parity(cfg):
+    """VAEConfig.fused_conv routes every GN→SiLU→conv3x3 pair through
+    the fused Pallas kernel (interpret mode on CPU — the real kernel)
+    with an IDENTICAL param tree; decoder and encoder outputs must
+    match the naive path."""
+    import dataclasses
+
+    from cassmantle_tpu.models.vae import VAEDecoder, VAEEncoder
+    from cassmantle_tpu.models.weights import init_params
+
+    vcfg = cfg.models.vae
+    fused_cfg = dataclasses.replace(vcfg, fused_conv=True)
+    assert fused_cfg.arch() == vcfg.arch()
+
+    lat = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 4))
+    dec = VAEDecoder(vcfg)
+    params = init_params(dec, 3, lat)
+    a = dec.apply(params, lat)
+    b = VAEDecoder(fused_cfg).apply(params, lat)      # same tree
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               atol=2e-5, rtol=2e-5)
+
+    img = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 16, 3))
+    enc = VAEEncoder(vcfg)
+    eparams = init_params(enc, 4, img, jax.random.PRNGKey(2))
+    ea = enc.apply(eparams, img, jax.random.PRNGKey(3))
+    eb = VAEEncoder(fused_cfg).apply(eparams, img, jax.random.PRNGKey(3))
+    np.testing.assert_allclose(np.asarray(ea), np.asarray(eb),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_fused_vae_kill_switch(cfg, monkeypatch):
+    """CASSMANTLE_NO_FUSED_CONV covers the VAE sites too (one switch for
+    every fused-conv site, UNet and VAE alike)."""
+    import dataclasses
+
+    from cassmantle_tpu.models.vae import VAEDecoder
+    from cassmantle_tpu.models.weights import init_params
+
+    vcfg = dataclasses.replace(cfg.models.vae, fused_conv=True)
+    lat = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 8, 4))
+    dec = VAEDecoder(vcfg)
+    params = init_params(dec, 3, lat)
+    monkeypatch.setenv("CASSMANTLE_NO_FUSED_CONV", "1")
+    a = dec.apply(params, lat)
+    monkeypatch.delenv("CASSMANTLE_NO_FUSED_CONV")
+    b = dec.apply(params, lat)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.slow
 def test_pipeline_flag_parity(cfg):
     """End-to-end tiny SD1.5 pipeline: flag on vs off produce the same

@@ -409,19 +409,11 @@ def test_chaos_brownout_forces_tier_flap(monkeypatch):
 
 def test_degraded_sampler_cfg_respects_invariants():
     cfg = _tiny_config()
-    s = dataclasses.replace(cfg.sampler, num_steps=50, deepcache=True,
-                            image_size=512)
+    s = dataclasses.replace(cfg.sampler, num_steps=50, image_size=512)
     tier = BrownoutTier("t", num_steps_scale=0.6, image_size_scale=0.5)
     d = degraded_sampler_cfg(s, tier)
-    assert d.num_steps == 30 and d.num_steps % 2 == 0
+    assert d.num_steps == 30
     assert d.image_size == 256 and d.image_size % 16 == 0
-    # encprop stride only moves when encprop is on
-    tier2 = BrownoutTier("t2", encprop_stride_add=2)
-    assert degraded_sampler_cfg(s, tier2).encprop_stride == \
-        s.encprop_stride
-    s_ep = dataclasses.replace(s, deepcache=False, encprop=True,
-                               encprop_stride=3)
-    assert degraded_sampler_cfg(s_ep, tier2).encprop_stride == 5
     # the identity tier is a no-op config (callers skip the degraded
     # path => tier 0 is bit-for-bit the old behavior)
     assert degraded_sampler_cfg(s, BrownoutTier("full")) == s
@@ -429,8 +421,8 @@ def test_degraded_sampler_cfg_respects_invariants():
 
 def test_degraded_sampler_cfg_few_step_tier(monkeypatch):
     """The few-step tier swaps the sampling loop for the consistency
-    student at 4 steps, clears the non-composing deepcache/encprop
-    flags, carries the resolution delta of later rungs, ONLY engages
+    student at 4 steps, carries the resolution delta of later rungs,
+    ONLY engages
     when the deployment declares a distilled student checkpoint
     (consistency_available — an undistilled eps-net sampled 4-step is
     near-noise), and defers to the CASSMANTLE_NO_CONSISTENCY kill
@@ -448,13 +440,11 @@ def test_degraded_sampler_cfg_few_step_tier(monkeypatch):
     d_stock = degraded_sampler_cfg(
         stock, BrownoutTier("t", num_steps_scale=0.6, consistency=True))
     assert not d_stock.consistency and d_stock.num_steps == 30
-    s = dataclasses.replace(cfg.sampler, num_steps=50, encprop=True,
-                            encprop_stride=3, image_size=512,
+    s = dataclasses.replace(cfg.sampler, num_steps=50, image_size=512,
                             consistency_available=True)
     tier = BrownoutTier("t", num_steps_scale=0.6, consistency=True)
     d = degraded_sampler_cfg(s, tier)
     assert d.consistency and d.num_steps == CONSISTENCY_BROWNOUT_STEPS
-    assert not d.deepcache and not d.encprop
     assert d.image_size == 512                    # few-step BEFORE low-res
     low = BrownoutTier("t2", consistency=True, image_size_scale=0.5)
     assert degraded_sampler_cfg(s, low).image_size == 256

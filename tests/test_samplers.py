@@ -338,10 +338,6 @@ def test_consistency_config_rejections():
 
     with pytest.raises(AssertionError, match="few-step"):
         Text2ImagePipeline(cfg(num_steps=12))
-    with pytest.raises(AssertionError, match="deepcache"):
-        Text2ImagePipeline(cfg(num_steps=4, deepcache=True))
-    with pytest.raises(AssertionError, match="encprop"):
-        Text2ImagePipeline(cfg(num_steps=4, encprop=True))
     with pytest.raises(AssertionError, match="eta"):
         Text2ImagePipeline(cfg(num_steps=4, eta=0.5))
     with pytest.raises(AssertionError, match="consistency_teacher_steps"):

@@ -126,7 +126,6 @@ def test_staged_enabled_gating(monkeypatch):
     assert not enabled(ns(_tiny_config()))          # knob off
     assert not enabled(ns(cfg, mesh=object()))     # meshed serving
     for sampler in (
-        dataclasses.replace(cfg.sampler, deepcache=True),
         dataclasses.replace(cfg.sampler, eta=0.5),
         dataclasses.replace(cfg.sampler, kind="nonexistent"),
     ):

@@ -1,7 +1,7 @@
 """CLIP-similarity report across serving presets (the quality gate).
 
 BASELINE.md's gate is "CLIP-similarity parity": the fast presets
-(DPM-Solver++(2M) @ 25 steps, deepcache) only count as wins if their
+(DPM-Solver++(2M) @ 25 steps, int8) only count as wins if their
 images score on par with the fixed DDIM-50 config under CLIP. This tool
 generates the same prompts with each preset, scores every image against
 its prompt with the local CLIP harness (eval/clip_parity.py — both
@@ -16,7 +16,7 @@ must not be quoted as a quality number.
 
 Usage:
     python tools/clip_report.py [--weights weights] [--out CLIP_REPORT.json]
-        [--platform cpu] [--presets ddim50,dpmpp25,deepcache,turbo,int8,encprop]
+        [--platform cpu] [--presets ddim50,dpmpp25,int8]
         [--tiny]
 """
 
@@ -65,31 +65,16 @@ def preset_factories(tiny: bool):
         return {
             "ddim50": tiny_kind("ddim", num_steps=4),
             "dpmpp25": tiny_kind("dpmpp_2m", num_steps=2),
-            "deepcache": tiny_kind("ddim", num_steps=4, deepcache=True),
-            "turbo": tiny_kind("dpmpp_2m", num_steps=4, deepcache=True),
             "int8": lambda: _with_unet_int8(test_config()),
-            "encprop": tiny_kind("ddim", num_steps=4, encprop=True,
-                                 encprop_stride=2, encprop_dense_steps=0),
         }
-    from cassmantle_tpu.config import (
-        FrameworkConfig,
-        deepcache_serving_config,
-        encprop_serving_config,
-        fast_serving_config,
-        turbo_serving_config,
-    )
+    from cassmantle_tpu.config import FrameworkConfig, fast_serving_config
 
     return {
         "ddim50": FrameworkConfig,
         "dpmpp25": fast_serving_config,
-        "deepcache": deepcache_serving_config,
-        "turbo": turbo_serving_config,
         # quality arm of the sd15_int8 bench A/B: same DDIM-50
         # trajectory, int8 UNet weights
         "int8": lambda: _with_unet_int8(FrameworkConfig()),
-        # quality arm of the sd15_encprop bench A/B: DDIM-50 with
-        # encoder propagation (20 key steps) + fused VAE decode
-        "encprop": encprop_serving_config,
     }
 
 
@@ -140,7 +125,7 @@ def main() -> None:
                          "suite file)")
     ap.add_argument("--platform", default="auto", choices=["auto", "cpu"])
     ap.add_argument("--presets",
-                    default="ddim50,dpmpp25,deepcache,turbo,int8,encprop")
+                    default="ddim50,dpmpp25,int8")
     ap.add_argument("--seeds", type=int, default=2,
                     help="image batches per preset (n = seeds * 8 prompts)")
     ap.add_argument("--tiny", action="store_true",

@@ -102,85 +102,9 @@ def cost_table(fn, *args, top: int = 10):
     return out_rows, total
 
 
-def encoder_decoder_split(model, params, lat, ts, ctx, add=None):
-    """(encoder TF, decoder TF, total TF) per UNet forward: the decoder
-    figure comes from costing the decoder-only apply (``skips_cache``
-    mode, models/unet.py — exactly what an encprop propagated step
-    runs) against an eval_shape'd encoder cache; encoder = total −
-    decoder. Shape-derived, so valid on any backend."""
-    args = (lat, ts, ctx) + ((add,) if add is not None else ())
-    _, cache = jax.eval_shape(
-        lambda p, *a: model.apply(p, *a, return_skips=True), params, *args)
-
-    def decoder_only(p, cache_, t, c, *a):
-        return model.apply(p, None, t, c, *a, skips_cache=cache_)
-
-    dec_args = (params, cache, ts, ctx) + ((add,) if add is not None
-                                           else ())
-    _, dec_total = cost_table(decoder_only, *dec_args)
-    _, total = cost_table(
-        lambda p, *a: model.apply(p, *a), params, *args)
-    return total - dec_total, dec_total, total
-
-
-def vae_decode_cost(vae_cfg, image_size: int, batch: int):
-    """(VAE decode TF/image, mid-attention TF/image, token count) at the
-    given output resolution — the decode-side rows of the cost table.
-    The attention figure is the analytic dot cost of VAEAttnBlock at
-    the mid-block geometry (4 S×C² projections + the 2 S²×C attention
-    einsums over S = latent H·W tokens), i.e. what the naive path pays
-    and what the flash-VAE-attn route keeps out of HBM."""
-    from cassmantle_tpu.models.vae import VAEDecoder
-
-    vae = VAEDecoder(vae_cfg)
-    scale = 2 ** (len(vae_cfg.channel_mults) - 1)
-    lat_hw = image_size // scale
-    lat = jax.ShapeDtypeStruct((batch, lat_hw, lat_hw, 4), jnp.float32)
-    params = jax.eval_shape(
-        vae.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, lat_hw, lat_hw, 4), jnp.float32))
-    _, total = cost_table(lambda p, z: vae.apply(p, z), params, lat)
-    s_tokens = lat_hw * lat_hw
-    c = vae_cfg.base_channels * vae_cfg.channel_mults[-1]
-    attn = batch * (4 * 2.0 * s_tokens * c * c
-                    + 2 * 2.0 * s_tokens * s_tokens * c)
-    return total / batch, attn / batch, s_tokens
-
-
 # The analytic ceilings this tool prints, and the committed cost model's
 # ``chip_tflops``, are for one v5e chip: its row of the peak table.
 ANALYTIC_DEVICE_KIND = "TPU v5 lite"
-
-
-def print_encprop_accounting(encoder, decoder, total, vae_tf, vae_attn,
-                             s_tokens, sampler_cfg):
-    """The encprop analytic bound, from the same numbers the per-image
-    TF figure came from: full forwards at the key steps of the
-    configured schedule, decoder-only forwards elsewhere (CFG doubles
-    both), plus the VAE decode — the PERF_NOTES 'Encoder propagation
-    accounting' model."""
-    from cassmantle_tpu.obs.costmodel import peak_flops_for_kind
-    from cassmantle_tpu.ops.ddim import encprop_key_indices
-
-    chip_tflops = peak_flops_for_kind(ANALYTIC_DEVICE_KIND)
-    n = sampler_cfg.num_steps
-    keys = len(encprop_key_indices(n, sampler_cfg.encprop_stride,
-                                   sampler_cfg.encprop_dense_steps))
-    full_img = 2 * n * total
-    enc_img = 2 * (keys * total + (n - keys) * decoder) + vae_tf
-    print(f"UNet split/forward: encoder(conv_in+down+mid) "
-          f"{encoder / 1e12:.3f} TF ({100 * encoder / total:.0f}%)  "
-          f"decoder(up+out) {decoder / 1e12:.3f} TF "
-          f"({100 * decoder / total:.0f}%)")
-    print(f"VAE decode: {vae_tf / 1e12:.2f} TF/image  "
-          f"(mid attention {vae_attn / 1e12:.2f} TF at S={s_tokens})")
-    print(f"encprop bound @ stride {sampler_cfg.encprop_stride} "
-          f"+{sampler_cfg.encprop_dense_steps} dense ({keys} keys / {n} "
-          f"steps): {enc_img / 1e12:.1f} TF/image vs "
-          f"{(full_img + vae_tf) / 1e12:.1f} full "
-          f"({100 * enc_img / (full_img + vae_tf):.0f}%) -> ceiling "
-          f"{chip_tflops / enc_img:.3f} img/s/chip vs "
-          f"{chip_tflops / (full_img + vae_tf):.3f}")
 
 
 def _image_cost_entry(kind: str, cfg) -> dict:
@@ -544,13 +468,6 @@ def main():
         for r in rows:
             print(f"{r['op']:22s} {r['shapes']:46s} "
                   f"{r['count']:5d} {r['gflops']:9.1f} {r['pct']:5.1f}")
-        enc, dec, tot = encoder_decoder_split(
-            model, params, lat, ts, ctx, add)
-        vae_tf, vae_attn, s_tokens = vae_decode_cost(
-            xcfg.models.vae, xcfg.sampler.image_size, batch)
-        print_encprop_accounting(
-            enc / batch, dec / batch, tot / batch, vae_tf, vae_attn,
-            s_tokens, xcfg.sampler)
         return
     cfg = FrameworkConfig()
     ucfg = cfg.models.unet
@@ -600,14 +517,6 @@ def main():
         for r in rows:
             print(f"{r['op']:22s} {r['shapes']:46s} "
                   f"{r['count']:5d} {r['gflops']:9.1f} {r['pct']:5.1f}")
-        if not opts.full_pipeline:
-            enc, dec, tot = encoder_decoder_split(model, params, lat, ts,
-                                                  ctx)
-            vae_tf, vae_attn, s_tokens = vae_decode_cost(
-                cfg.models.vae, cfg.sampler.image_size, batch)
-            print_encprop_accounting(
-                enc / batch, dec / batch, tot / batch, vae_tf, vae_attn,
-                s_tokens, cfg.sampler)
         return
 
     lowered = step.lower(params, lat, ts, ctx)
