@@ -33,6 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from cassmantle_tpu.ops.moe_walk import moe_walk, moe_walk_fits
+from cassmantle_tpu.ops.platform import on_tpu
+from cassmantle_tpu.utils.logging import metrics
+
 
 class MoEMLP(nn.Module):
     """Top-1 (Switch) routed MLP: x (B, S, D) -> (B, S, D)."""
@@ -116,7 +120,21 @@ class HeldExperts(nn.Module):
     of the held experts, and the step is bound by the weights it reads).
     ``real`` (T,) marks tokens that count: padding is neither computed in
     the walk nor counted in ``stats`` (``assignments``,
-    ``assignments_held``, ``experts_touched``, ``load`` (experts_held,))."""
+    ``assignments_held``, ``experts_touched``, ``load`` (experts_held,)).
+
+    Which form the walk takes is a rule on what the code can see, and
+    nothing sets it: on the TPU, at widths the kernel tiles
+    (``moe_walk_fits``), one Pallas call a layer that copies the landed
+    assignments' matrices back to back while the one before multiplies
+    (ops/moe_walk.py, ``walk_kernel``); anywhere else ``_walk``'s
+    ``fori_loop`` of dependent products (``walk_xla``), which is also the
+    form the kernel is tested against. Counted under
+    ``moe.dispatch{path}`` once a site a trace, with ``dense``. On one
+    v5e a layer call at 1 / 2 / 4 rows, net of the scan around it, took
+    36.6 / 81.6 / 163.3 us as the loop and 23.4 / 43.0 / 88.2 as the
+    kernel against a read of 19.1 / 39.0 / 78.7, and a whole batch-1
+    dispatch 146.5 ms and 138.5 (PR 32; the forms, piece sizes and cost
+    statements tried are in the kernel's module)."""
 
     num_experts: int
     experts_held: int
@@ -163,6 +181,7 @@ class HeldExperts(nn.Module):
             down = self.param("down", init, (held_n, f, d),
                               jnp.float32).astype(self.dtype)
             if dense:
+                metrics.inc("moe.dispatch", labels={"path": "dense"})
                 combine = jnp.zeros((t, held_n), jnp.float32).at[
                     jnp.arange(t)[:, None], local].add(
                         jnp.where(here, top_p, 0.0))
@@ -172,8 +191,15 @@ class HeldExperts(nn.Module):
                 out = jnp.einsum("tef,efd->td", h.astype(self.dtype), down,
                                  preferred_element_type=jnp.float32)
             else:
-                out = self._walk(xb, gate_up, down, local.reshape(-1),
-                                 top_p.reshape(-1), here.reshape(-1))
+                # the assignments that landed here first, each row's in
+                # its own order: a row's sum does not depend on its company
+                order = jnp.argsort(~here.reshape(-1), stable=True)
+                kernel = on_tpu() and moe_walk_fits(d, f)
+                metrics.inc("moe.dispatch", labels={
+                    "path": "walk_kernel" if kernel else "walk_xla"})
+                out = (moe_walk if kernel else self._walk)(
+                    xb, gate_up, down, local.reshape(-1), top_p.reshape(-1),
+                    order, jnp.sum(here))
 
         if self.shared_intermediate:
             with jax.named_scope("moe_shared"):
@@ -195,13 +221,12 @@ class HeldExperts(nn.Module):
                     x32, s_gate.astype(jnp.float32), precision=hi))
         return out, stats
 
-    def _walk(self, xb, gate_up, down, expert, weight, here):
-        """One assignment a step, those that landed here first and in
-        their row's own order, so that a row's sum does not depend on its
-        company; the trip count is the number that landed."""
+    def _walk(self, xb, gate_up, down, expert, weight, order, count):
+        """The walk as XLA runs it, and what ops/moe_walk.py is tested
+        against: one assignment a loop trip in ``order``, ``count``
+        trips; ``expert`` and ``weight`` by slot, slot // top_k the row."""
         f = self.intermediate
         top_k = expert.shape[0] // xb.shape[0]
-        order = jnp.argsort(~here, stable=True)
 
         def body(i, acc):
             slot = order[i]
@@ -221,7 +246,7 @@ class HeldExperts(nn.Module):
                 + weight[slot] * y, row, axis=0)
 
         return jax.lax.fori_loop(
-            0, jnp.sum(here), body,
+            0, count, body,
             jnp.zeros((xb.shape[0], down.shape[-1]), jnp.float32))
 
 

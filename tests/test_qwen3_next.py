@@ -20,6 +20,7 @@ from cassmantle_tpu.config import (
     SpecDecodeConfig,
     qwen3next_game_config,
 )
+from cassmantle_tpu.models import moe
 from cassmantle_tpu.models.moe import HeldExperts
 from cassmantle_tpu.models.qwen3_next import (
     Qwen3NextLM,
@@ -27,6 +28,7 @@ from cassmantle_tpu.models.qwen3_next import (
     cache_stats,
 )
 from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
+from cassmantle_tpu.utils.logging import metrics
 
 TINY = Qwen3NextConfig.tiny()
 #: logits are of order 4. float32 differs by summation order alone: the
@@ -274,6 +276,180 @@ def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, dense):
     assert np.abs(without_shared).max() > 0.01
 
 
+# -- the walk as one kernel (ops/moe_walk.py), interpreted -------------------
+
+def routed_layer(dtype=jnp.float32, **kw):
+    """Lane-aligned widths, top-10 of 32 experts of which 8 are held
+    (ids 8..15): a row's ten assignments land here 2.5 times on average,
+    as in the served cut."""
+    args = dict(num_experts=32, experts_held=8, first_expert=8, top_k=10,
+                intermediate=256, dtype=dtype)
+    return HeldExperts(**dict(args, **kw))
+
+
+def dispatch_counts():
+    return {labels[0][1]: value for name, labels, value
+            in metrics.dump_state()["counters"] if name == "moe.dispatch"}
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def routed(request):
+    """(layer, params in the stored type, x (4, 512))."""
+    layer = routed_layer(jnp.dtype(request.param))
+    x = jax.random.normal(jax.random.PRNGKey(13), (4, 512))
+    params = layer.init(jax.random.PRNGKey(12), x, jnp.ones((4,), bool), True)
+    return layer, jax.tree_util.tree_map(
+        lambda a: a.astype(request.param), params), x
+
+
+def three_forms(layer, params, x, real, monkeypatch):
+    """{form: (out, stats)}: the dense form, the walk as XLA runs it and
+    the walk as the kernel (the rule sees a TPU; the kernel itself still
+    sees none and interprets)."""
+    before = dispatch_counts()
+    forms = {"dense": layer.apply(params, x, real, True),
+             "walk_xla": layer.apply(params, x, real, False)}
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "on_tpu", lambda: True)
+        forms["walk_kernel"] = layer.apply(params, x, real, False)
+    after = dispatch_counts()
+    assert {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)} == {
+                "dense": 1, "walk_xla": 1, "walk_kernel": 1}
+    return forms
+
+
+def biased_router(params, x, experts):
+    """Every row's first ``len(experts)`` choices are ``experts``, in that
+    order (input 0 raised, the router's row 0 set)."""
+    router = np.zeros_like(np.asarray(params["params"]["router"],
+                                      np.float32))
+    router[1:] = np.asarray(params["params"]["router"], np.float32)[1:]
+    router[0, list(experts)] = 50.0 - np.arange(len(experts))
+    x = np.asarray(x).copy()
+    x[:, 0] = 3.0
+    dtype = params["params"]["router"].dtype
+    return ({"params": dict(params["params"],
+                            router=jnp.asarray(router, dtype))},
+            jnp.asarray(x))
+
+
+#: what the two walks may differ by: float32 differs by the order of a
+#: sum; in bfloat16 the loop rounds ``h`` to the stored type before
+#: ``down`` where its compiler keeps the rounding (the kernel carries
+#: ``h`` whole, as two operands). The dense form rounds ``h`` with the
+#: routing weight already on it
+WALK_TOLERANCE = {"float32": 2e-5, "bfloat16": 4e-3}
+DENSE_TOLERANCE = {"float32": 2e-5, "bfloat16": 2e-2}
+
+
+def tolerance(layer, form="walk_kernel"):
+    table = DENSE_TOLERANCE if form == "dense" else WALK_TOLERANCE
+    return table[jnp.dtype(layer.dtype).name]
+
+
+def assert_forms_agree(layer, forms, rows=slice(None)):
+    """Every form's ``rows`` against the loop's, and its ``stats`` equal
+    to the loop's."""
+    want, stats = forms["walk_xla"]
+    for form, (out, form_stats) in forms.items():
+        np.testing.assert_allclose(
+            np.asarray(out)[rows], np.asarray(want)[rows],
+            atol=tolerance(layer, form), err_msg=form)
+        for name in stats:
+            np.testing.assert_array_equal(np.asarray(form_stats[name]),
+                                          np.asarray(stats[name]), form)
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4],
+                         ids=["10_slots", "20_slots", "40_slots"])
+def test_the_walk_kernel_agrees_with_the_loop_and_the_dense_form(
+        routed, rows, monkeypatch):
+    layer, params, x = routed
+    forms = three_forms(layer, params, x[:rows], jnp.ones((rows,), bool),
+                        monkeypatch)
+    want, stats = forms["walk_xla"]
+    assert 0 < int(stats["assignments_held"]) < rows * 10
+    assert float(jnp.abs(want).max()) > 0.05
+    assert_forms_agree(layer, forms)
+
+
+@pytest.mark.parametrize("case", ["nothing_lands", "every_row_on_one_expert",
+                                  "every_assignment_lands"])
+def test_the_walk_kernel_at_the_ends_of_the_routing(routed, case,
+                                                    monkeypatch):
+    """No assignment lands (zeros, nothing counted, no loop trip); every
+    row's first choice is held expert 11 (the same matrices copied trip
+    after trip into alternating slots); all eight of every row land (as
+    many trips as slots)."""
+    layer, params, x = routed
+    experts = {"nothing_lands": range(16, 26),
+               "every_row_on_one_expert": [11] + list(range(16, 25)),
+               "every_assignment_lands": [8, 9, 10, 11, 12, 13, 14, 15]}[case]
+    if case == "every_assignment_lands":
+        layer = routed_layer(layer.dtype, top_k=8)
+    params, x = biased_router(params, x, experts)
+    forms = three_forms(layer, params, x, jnp.ones((4,), bool), monkeypatch)
+    stats = forms["walk_xla"][1]
+    held = {"nothing_lands": 0, "every_row_on_one_expert": 4,
+            "every_assignment_lands": 32}[case]
+    assert int(stats["assignments_held"]) == held
+    if case == "every_row_on_one_expert":
+        assert int(stats["load"][11 - 8]) == 4
+    assert_forms_agree(layer, forms)
+    if not held:
+        assert not any(np.asarray(out).any() for out, _ in forms.values())
+
+
+@pytest.mark.parametrize("real", [[True, False, True, False],
+                                  [False, False, False, True],
+                                  [False, False, False, False]],
+                         ids=["two_real", "last_real", "none_real"])
+def test_the_walk_kernel_neither_computes_nor_counts_padding_rows(
+        routed, real, monkeypatch):
+    layer, params, x = routed
+    real = jnp.asarray(real)
+    forms = three_forms(layer, params, x, real, monkeypatch)
+    assert int(forms["walk_xla"][1]["assignments"]) == 10 * int(real.sum())
+    for form in ("walk_xla", "walk_kernel"):
+        assert not np.asarray(forms[form][0])[~np.asarray(real)].any(), form
+    # the dense form computes padding rows too: the real ones count
+    assert_forms_agree(layer, forms, np.asarray(real))
+
+
+@pytest.mark.parametrize("row", [0, 1, 2, 3])
+def test_a_row_alone_in_the_walk_kernel_equals_the_row_in_company(
+        routed, row, monkeypatch):
+    """A row's sum is taken in its own order over its own assignments,
+    whatever rows share the call (row 2 is one none of whose ten
+    assignments land: zeros, alone and in company). The interpreter's
+    matrix product over one row and over four blocks its sums otherwise,
+    hence not to the bit here."""
+    layer, params, x = routed
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    together, _ = layer.apply(params, x, jnp.ones((4,), bool), False)
+    alone, stats = layer.apply(params, x[row:row + 1], jnp.ones((1,), bool),
+                               False)
+    assert bool(jnp.any(alone != 0)) == (int(stats["assignments_held"]) > 0)
+    assert (int(stats["assignments_held"]) == 0) == (row == 2)
+    np.testing.assert_allclose(
+        np.asarray(alone[0]), np.asarray(together[row]),
+        atol={"float32": 1e-6, "bfloat16": 4e-3}[jnp.dtype(layer.dtype).name])
+
+
+def test_widths_the_kernel_does_not_tile_keep_the_loop(whole_layer,
+                                                       monkeypatch):
+    """The rule reads the platform, ``dense`` and the widths: a layer 32
+    wide with experts 16 wide walks as XLA runs it on a TPU too."""
+    layer, params, x = whole_layer
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    before = dispatch_counts()
+    layer.apply(params, x, jnp.ones((24,), bool), False)
+    after = dispatch_counts()
+    assert after["walk_xla"] - before.get("walk_xla", 0) == 1
+    assert after.get("walk_kernel", 0) == before.get("walk_kernel", 0)
+
+
 # -- the serving path ---------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -327,6 +503,24 @@ def test_greedy_decode_hands_back_the_cache_counters(generator):
         cache_stats=cache_stats)
     assert tokens.shape == (2, 4)
     assert int(stats["assignments"]) == (5 + 4) * 4 * 2
+
+
+def test_a_decode_program_counts_its_expert_layers_by_path(generator):
+    """``moe.dispatch{path}`` once a site a trace: off the TPU a decode
+    program's prefill takes the dense form and its step the loop, once
+    for each of the tiny model's four layers (on the chip the served
+    program counts 8 ``walk_kernel`` and 8 ``dense``)."""
+    before = dispatch_counts()
+    greedy_decode.lower(
+        make_apply_pair(generator.model), generator.params,
+        jax.ShapeDtypeStruct((3, 24), jnp.int32),
+        jax.ShapeDtypeStruct((3,), jnp.int32), jax.random.PRNGKey(0), 5,
+        257, 0.0, 40, row_mask=jax.ShapeDtypeStruct((3,), jnp.bool_),
+        cache_stats=cache_stats)
+    after = dispatch_counts()
+    assert {k: after[k] - before.get(k, 0) for k in after} == {
+        "dense": 4, "walk_xla": 4, **{k: 0 for k in after
+                                      if k not in ("dense", "walk_xla")}}
 
 
 def test_token_flops_count_the_parameters_a_token_touches(generator):
