@@ -235,6 +235,71 @@ class Qwen3NextConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """LFM2-MoE-class causal LM (models/lfm2_moe.py): gated short
+    convolutions among grouped-query attention layers (``layer_types``),
+    a dense SwiGLU MLP in the first ``num_dense_layers`` layers and
+    sigmoid-scored sparse experts after, tied embeddings. Field names are
+    the published ``config.json``'s; defaults are LFM2-24B-A2B's widths
+    (``rope_theta`` is its ``rope_parameters.rope_theta``).
+
+    ``experts_held`` / ``first_expert`` as in ``Qwen3NextConfig``.
+    ``tiny()`` is the CPU-test variant.
+    """
+
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40
+    layer_types: Tuple[str, ...] = (
+        "conv", "conv", "full_attention", "conv") * 10
+    num_dense_layers: int = 2
+    intermediate_size: int = 11776
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    rope_theta: float = 1000000.0
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    moe_intermediate_size: int = 1536
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-5
+    max_position_embeddings: int = 128000
+    experts_held: int = 64
+    first_expert: int = 0
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        assert len(self.layer_types) == self.num_hidden_layers, (
+            self.layer_types, self.num_hidden_layers)
+        assert not self.conv_bias, "a convolution bias is not served"
+
+    @property
+    def max_positions(self) -> int:
+        """The name the serving layer knows the position limit by."""
+        return self.max_position_embeddings
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @staticmethod
+    def tiny() -> "Lfm2MoeConfig":
+        """A dense leading layer and one period, 8 experts top-2, all
+        held."""
+        return Lfm2MoeConfig(
+            vocab_size=300, hidden_size=32, num_hidden_layers=5,
+            layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+            num_dense_layers=1, intermediate_size=48,
+            num_attention_heads=4, num_key_value_heads=2, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=16,
+            max_position_embeddings=128, experts_held=8, dtype="float32",
+        )
+
+
+@dataclasses.dataclass(frozen=True)
 class MiniLMConfig:
     """all-MiniLM-L6-v2-class sentence encoder for guess scoring."""
 
@@ -263,6 +328,10 @@ class ModelZooConfig:
     # sparse experts); when set it is the prompt LM. Weights-only int8,
     # W8A8 and speculative decode are refused for it (serving/pipeline.py).
     qwen3_next: Optional[Qwen3NextConfig] = None
+    # Optional LFM2-MoE-class prompt LM (short-convolution + grouped-query
+    # attention layers, a dense leading layer, sigmoid-routed experts);
+    # when set it is the prompt LM, with the same refusals as qwen3_next.
+    lfm2_moe: Optional[Lfm2MoeConfig] = None
     minilm: MiniLMConfig = dataclasses.field(default_factory=MiniLMConfig)
     # Directory holding safetensors checkpoints; None -> deterministic
     # random-init (fixed PRNG) so the full pipeline runs without artifacts.
@@ -858,6 +927,23 @@ def qwen3next_game_config() -> FrameworkConfig:
         sampler=SamplerConfig(consistency=True, num_steps=4))
 
 
+def lfm2_game_config() -> FrameworkConfig:
+    """The game with an LFM2-24B-A2B-class story model and the few-step
+    image model of ``qwen3next_game_config``: the first pipeline stage's
+    chip of a deployment that divides no layer. Held here: published
+    layers 0 and 2-9 at the published widths (one leading dense layer,
+    counted once, and two whole periods of full, conv, conv, conv), all
+    64 experts of each of the 8 expert layers, the whole tied embedding:
+    5.18 B parameters."""
+
+    return FrameworkConfig(
+        models=ModelZooConfig(lfm2_moe=Lfm2MoeConfig(
+            num_hidden_layers=9, num_dense_layers=1,
+            layer_types=("conv",) + (
+                "full_attention", "conv", "conv", "conv") * 2)),
+        sampler=SamplerConfig(consistency=True, num_steps=4))
+
+
 def test_config() -> FrameworkConfig:
     """A tiny config for CPU tests: small models, fast rounds, 64px images."""
 
@@ -902,6 +988,16 @@ def test_qwen3next_config() -> FrameworkConfig:
     return base.replace(
         models=dataclasses.replace(base.models,
                                    qwen3_next=Qwen3NextConfig.tiny()),
+        sampler=dataclasses.replace(base.sampler, consistency=True))
+
+
+def test_lfm2_config() -> FrameworkConfig:
+    """``lfm2_game_config`` at the CPU-test size."""
+
+    base = test_config()
+    return base.replace(
+        models=dataclasses.replace(base.models,
+                                   lfm2_moe=Lfm2MoeConfig.tiny()),
         sampler=dataclasses.replace(base.sampler, consistency=True))
 
 

@@ -18,10 +18,11 @@ static shapes, so XLA can lay the expert dim out across the mesh:
 ``MoEMLP`` drops in anywhere a TransformerMLP fits; ``expert_specs`` gives
 the ``P("ep", ...)`` param specs for mesh placement.
 
-``HeldExperts`` is the serving-side expert layer (models/qwen3_next.py):
-top-k routing over the published number of experts, told which contiguous
-range of them this chip holds, no capacity and no drop; it computes its
-own experts' part of the result and says what it routed.
+``HeldExperts`` is the serving-side expert layer (models/qwen3_next.py,
+models/lfm2_moe.py): top-k routing over the published number of experts
+under the family's routing rule, told which contiguous range of them
+this chip holds (all of them, or a share), no capacity and no drop; it
+computes its own experts' part of the result and says what it routed.
 """
 
 from __future__ import annotations
@@ -112,7 +113,13 @@ class HeldExperts(nn.Module):
     The router scores all ``num_experts`` in float32 and keeps the
     ``top_k`` best, weights renormalised over the k when
     ``norm_topk_prob``; an assignment to an absent expert adds nothing
-    (another chip's part). No assignment is dropped: ``dense=True``
+    (another chip's part). The routing rule is data of the module:
+    ``scoring`` ("softmax" over the experts, or "sigmoid" of each),
+    ``selection_bias`` (a buffer ``expert_bias`` (num_experts,) added to
+    the scores for the choice alone: the weights stay the unbiased scores
+    of the chosen), ``norm_eps`` (added to the sum the weights are
+    divided by) and ``scaling`` (what the weights are multiplied by
+    last). No assignment is dropped: ``dense=True``
     runs every held expert over every token and combines by the routing
     weights (prefill: with hundreds of tokens every expert is touched
     anyway); ``dense=False`` walks the assignments that landed here, one
@@ -133,8 +140,11 @@ class HeldExperts(nn.Module):
     v5e a layer call at 1 / 2 / 4 rows, net of the scan around it, took
     36.6 / 81.6 / 163.3 us as the loop and 23.4 / 43.0 / 88.2 as the
     kernel against a read of 19.1 / 39.0 / 78.7, and a whole batch-1
-    dispatch 146.5 ms and 138.5 (PR 32; the forms, piece sizes and cost
-    statements tried are in the kernel's module)."""
+    dispatch 146.5 ms and 138.5 (PR 32, experts of 6.3 MB); with experts
+    of 18.9 MB, all held, 124.2 / 263.0 / 520.4 and 103.0 / 205.1 / 400.5
+    against 92.2 / 184.4 / 368.7, and a dispatch 192.1 and 191.8 (PR 34;
+    the forms, piece sizes and cost statements tried are in the kernel's
+    module)."""
 
     num_experts: int
     experts_held: int
@@ -143,6 +153,10 @@ class HeldExperts(nn.Module):
     intermediate: int
     shared_intermediate: int = 0
     norm_topk_prob: bool = True
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    norm_eps: float = 0.0
+    scaling: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -158,11 +172,25 @@ class HeldExperts(nn.Module):
         with jax.named_scope("moe_router"):
             router = self.param("router", nn.initializers.lecun_normal(),
                                 (d, self.num_experts), jnp.float32)
-            probs = jax.nn.softmax(jnp.dot(
-                x32, router.astype(jnp.float32), precision=hi), axis=-1)
-            top_p, top_i = jax.lax.top_k(probs, k)              # (T, k)
+            logits = jnp.dot(x32, router.astype(jnp.float32), precision=hi)
+            scores = {"softmax": lambda z: jax.nn.softmax(z, axis=-1),
+                      "sigmoid": jax.nn.sigmoid}[self.scoring](logits)
+            if self.selection_bias:
+                bias = self.param(
+                    "expert_bias", lambda key, shape: jax.random.uniform(
+                        key, shape, jnp.float32, -0.1, 0.1),
+                    (self.num_experts,)).astype(jnp.float32)
+                _, top_i = jax.lax.top_k(scores + bias, k)      # (T, k)
+                top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+            else:
+                top_p, top_i = jax.lax.top_k(scores, k)         # (T, k)
             if self.norm_topk_prob:
-                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+                total = jnp.sum(top_p, axis=-1, keepdims=True)
+                if self.norm_eps:
+                    total = total + self.norm_eps
+                top_p = top_p / total
+            if self.scaling != 1.0:
+                top_p = top_p * self.scaling
             local = top_i - self.first_expert
             here = (local >= 0) & (local < held_n) & real[:, None]
             local = jnp.clip(local, 0, held_n - 1)
@@ -194,7 +222,8 @@ class HeldExperts(nn.Module):
                 # the assignments that landed here first, each row's in
                 # its own order: a row's sum does not depend on its company
                 order = jnp.argsort(~here.reshape(-1), stable=True)
-                kernel = on_tpu() and moe_walk_fits(d, f)
+                kernel = on_tpu() and moe_walk_fits(
+                    d, f, jnp.dtype(self.dtype).itemsize)
                 metrics.inc("moe.dispatch", labels={
                     "path": "walk_kernel" if kernel else "walk_xla"})
                 out = (moe_walk if kernel else self._walk)(

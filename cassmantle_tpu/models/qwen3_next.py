@@ -97,6 +97,20 @@ def rotary(x, positions, rotary_dim: int, theta: float):
         axis=-1)
 
 
+def window_at(inputs, prompt_len, width: int):
+    """(padded, window): ``inputs`` (B, S, C) of a causal convolution with
+    ``width`` zeros before the sequence's start, and each row's last
+    ``width`` inputs before its own ``prompt_len`` (B, width, C), which is
+    what a decode step's window starts from: inputs prompt_len - width ..
+    prompt_len - 1 sit at padded prompt_len .. prompt_len + width - 1, so
+    whatever the pad positions hold changes nothing."""
+    padded = jnp.pad(inputs, ((0, 0), (width, 0), (0, 0)))
+    window = jnp.take_along_axis(
+        padded, (prompt_len[:, None] + jnp.arange(width)[None, :])[..., None],
+        axis=1)
+    return padded, window
+
+
 def delta_rule(q, k, v, g, beta, state):
     """The gated delta rule, a token a step. q, k, v (B, S, H, d), g, beta
     (B, S, H), state (B, H, d_k, d_v) float32 -> (o (B, S, H, d_v), state).
@@ -149,12 +163,7 @@ class GatedDeltaNet(nn.Module):
 
         if state is None:
             recurrent = jnp.zeros((b, hv, dk, dv), F32)
-            padded = jnp.pad(mixed, ((0, 0), (kern - 1, 0), (0, 0)))
-            # inputs prompt_len-3 .. prompt_len-1 sit at padded
-            # prompt_len .. prompt_len+2
-            window = jnp.take_along_axis(
-                padded, (prompt_len[:, None]
-                         + jnp.arange(kern - 1)[None, :])[..., None], axis=1)
+            padded, window = window_at(mixed, prompt_len, kern - 1)
         else:
             recurrent, window = state
             padded = jnp.concatenate([window, mixed], axis=1)

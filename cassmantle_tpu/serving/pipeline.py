@@ -1053,7 +1053,7 @@ def _lm_families() -> Tuple[LMFamily, ...]:
     """The families in the order they are looked for: the first whose
     config attribute is set on ``cfg.models`` is the prompt LM; GPT-2,
     always set, comes last."""
-    from cassmantle_tpu.models import qwen3_next
+    from cassmantle_tpu.models import lfm2_moe, qwen3_next
     from cassmantle_tpu.models.mistral import MistralLM
     from cassmantle_tpu.models.weights import convert_mistral
 
@@ -1062,6 +1062,10 @@ def _lm_families() -> Tuple[LMFamily, ...]:
                  quantized=False, speculative=False,
                  active_params=qwen3_next.active_params,
                  cache_stats=qwen3_next.cache_stats),
+        LMFamily("lfm2_moe", lfm2_moe.Lfm2MoeLM,
+                 quantized=False, speculative=False,
+                 active_params=lfm2_moe.active_params,
+                 cache_stats=lfm2_moe.cache_stats),
         LMFamily("mistral", MistralLM,
                  lambda m: lambda t: convert_mistral(t, m.num_layers)),
         LMFamily("gpt2", GPT2LM,
@@ -1077,8 +1081,9 @@ class PromptGenerator:
     a Mistral-7B-class model (the reference's actual prompt model,
     backend.py:25) when ``cfg.models.mistral`` is set, a Qwen3-Next-class
     sparse model with linear-attention layers when ``cfg.models.qwen3_next``
-    is. All expose the same prefill/decode_step contract, so the scan in
-    ops/decode.py drives each."""
+    is, an LFM2-MoE-class one (short convolutions, sigmoid-routed experts)
+    when ``cfg.models.lfm2_moe`` is. All expose the same prefill/decode_step
+    contract, so the scan in ops/decode.py drives each."""
 
     PROMPT_BUCKETS = (32, 64, 128, 256)
 
@@ -1106,7 +1111,8 @@ class PromptGenerator:
         if not family.speculative and cfg.spec_decode.mode != "off":
             raise ValueError(
                 f"speculative decode is not served for {family.name}: a "
-                f"rejected draft would need its recurrent state rolled back")
+                f"rejected draft would need its recurrent state or "
+                f"convolution window rolled back")
         if family.converter is None and weights_dir:
             raise ValueError(
                 f"no checkpoint converter for {family.name}: it runs on "

@@ -45,6 +45,25 @@ async def test_respects_max_batch():
 
 
 @pytest.mark.asyncio
+async def test_a_queue_that_does_not_wait_hands_over_one_item_a_dispatch():
+    """``max_delay_ms`` 0 closes the coalescing window before it opens:
+    ten requests pending at once are ten dispatches of one, in order
+    (what ``lfm2_game``'s file sets for the prompt queue, so that every
+    window of its cell does the same work)."""
+    batches = []
+
+    def handler(items):
+        batches.append(list(items))
+        return items
+
+    q = BatchingQueue(handler, max_batch=4, max_delay_ms=0)
+    results = await asyncio.gather(*(q.submit(i) for i in range(10)))
+    assert results == list(range(10))
+    assert batches == [[i] for i in range(10)]
+    await q.stop()
+
+
+@pytest.mark.asyncio
 async def test_handler_exception_propagates():
     def handler(items):
         raise ValueError("boom")

@@ -7,6 +7,7 @@ the family and refuses what it does not serve.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from cassmantle_tpu.models.qwen3_next import (
     active_params,
     cache_stats,
 )
+from cassmantle_tpu.ops import moe_walk
 from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
 from cassmantle_tpu.utils.logging import metrics
 
@@ -246,34 +248,66 @@ def test_the_walk_and_the_dense_form_agree_and_padding_is_not_counted(
         (np.asarray(stats_d["load"]) > 0).sum())
 
 
+SIGMOID_RULE = dict(scoring="sigmoid", selection_bias=True, norm_eps=1e-6,
+                    shared_intermediate=0)
+
+
+def sigmoid_reference_block(params, x, **kw):
+    """The plain reference of the other routing rule
+    (benchmarks/references/lfm2_moe.py): sigmoid scores, the choice on
+    score + bias, the unbiased scores over their sum + 1e-6."""
+    from benchmarks.references import lfm2_moe as other
+
+    fields = dict(num_experts=8, num_experts_per_tok=2,
+                  moe_intermediate_size=16, norm_topk_prob=True,
+                  use_expert_bias=True, routed_scaling_factor=1.0,
+                  experts_held=8, first_expert=0)
+    d = other.Dims(**{k: dict(fields, **kw).get(k) for k in
+                      other.Dims._fields})
+    p = params["params"]
+    weight = other.routing(p, x, d)[
+        :, d.first_expert:d.first_expert + d.experts_held]
+    return np.asarray(other.experts(p["gate_up"], p["down"], x, weight))
+
+
+@pytest.mark.parametrize("rule", ["softmax_and_a_shared_expert",
+                                  "sigmoid_and_a_selection_bias"])
 @pytest.mark.parametrize("dense", [True, False], ids=["dense", "walk"])
-def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, dense):
-    """The share test: four chips hold two experts each; their parts, with
-    the shared expert (which every chip computes alike) counted once, add
-    up to what the plain reference gives for the whole layer."""
+def test_the_four_shares_add_up_to_the_uncut_layer(whole_layer, dense, rule):
+    """The share test, under each routing rule: four chips hold two
+    experts each; their parts, with the shared expert (which every chip
+    computes alike) counted once, add up to what the plain reference
+    gives for the whole layer."""
     _, params, x = whole_layer
+    rule_args, reference = {}, reference_block
+    if rule == "sigmoid_and_a_selection_bias":
+        rule_args, reference = SIGMOID_RULE, sigmoid_reference_block
+        params = expert_layer(**rule_args).init(
+            jax.random.PRNGKey(6), x, jnp.ones((24,), bool), True)
     p = params["params"]
     real = jnp.ones((24,), bool)
-    want = reference_block(params, x)
-    without_shared = reference_block(
+    want = reference(params, x)
+    without_shared = reference(
         {"params": dict(p, gate_up=p["gate_up"][:0], down=p["down"][:0])},
         x, experts_held=0)
     total, held = np.zeros_like(want), 0
     for first in (0, 2, 4, 6):
         share = {"params": dict(p, gate_up=p["gate_up"][first:first + 2],
                                 down=p["down"][first:first + 2])}
-        part, stats = expert_layer(experts_held=2, first_expert=first).apply(
-            share, x, real, dense)
+        part, stats = expert_layer(
+            experts_held=2, first_expert=first, **rule_args).apply(
+                share, x, real, dense)
         # the reference, given the same share, gives the same part
         np.testing.assert_allclose(
-            np.asarray(part), reference_block(
+            np.asarray(part), reference(
                 share, x, experts_held=2, first_expert=first), atol=2e-5)
         total += np.asarray(part) - without_shared
         held += int(stats["assignments_held"])
         assert int(stats["assignments"]) == 48
     np.testing.assert_allclose(total + without_shared, want, atol=5e-5)
     assert held == 48
-    assert np.abs(without_shared).max() > 0.01
+    assert (np.abs(without_shared).max() > 0.01) == (
+        rule == "softmax_and_a_shared_expert")
 
 
 # -- the walk as one kernel (ops/moe_walk.py), interpreted -------------------
@@ -287,30 +321,59 @@ def routed_layer(dtype=jnp.float32, **kw):
     return HeldExperts(**dict(args, **kw))
 
 
+#: the two served width sets (D 2048 with F 512 and F 1536) a quarter as
+#: wide, each under a plan whose rings are shorter than an expert's
+#: pieces (``gate_up`` 4 pieces through 3 slots, ``down`` 2 or 6 through
+#: 2: more than one turn of a ring an expert, and slots that change hands
+#: between assignments), and the narrower under the plan its widths give
+#: (one piece a matrix, a ring that holds several experts)
+KERNEL_CASES = {
+    "f256_ring_of_pieces": (256, moe_walk.WalkPlan(128, 3, 128, 2)),
+    "f768_ring_of_pieces": (768, moe_walk.WalkPlan(128, 3, 128, 2)),
+    "f256_plan_of_the_widths": (256, None),
+}
+
+
 def dispatch_counts():
     return {labels[0][1]: value for name, labels, value
             in metrics.dump_state()["counters"] if name == "moe.dispatch"}
 
 
-@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+@pytest.fixture(scope="module", params=[
+    (case, dtype) for case in KERNEL_CASES
+    for dtype in ("float32", "bfloat16")], ids="-".join)
 def routed(request):
-    """(layer, params in the stored type, x (4, 512))."""
-    layer = routed_layer(jnp.dtype(request.param))
+    """(layer, params in the stored type, x (4, 512)); the case's plan
+    is what ``as_on_the_chip`` hands the kernel."""
+    case, dtype = request.param
+    width, plan = KERNEL_CASES[case]
+    layer = routed_layer(jnp.dtype(dtype), intermediate=width)
     x = jax.random.normal(jax.random.PRNGKey(13), (4, 512))
     params = layer.init(jax.random.PRNGKey(12), x, jnp.ones((4,), bool), True)
+    PLANS[width] = plan
     return layer, jax.tree_util.tree_map(
-        lambda a: a.astype(request.param), params), x
+        lambda a: a.astype(dtype), params), x
+
+
+PLANS: dict = {}  # expert width -> the plan of the fixture's case
+
+
+def as_on_the_chip(patch, layer):
+    """The rule sees a TPU (the kernel itself still sees none and
+    interprets), and the kernel takes the layer's test plan."""
+    patch.setattr(moe, "on_tpu", lambda: True)
+    patch.setattr(moe, "moe_walk", functools.partial(
+        moe_walk.moe_walk, plan=PLANS[layer.intermediate]))
 
 
 def three_forms(layer, params, x, real, monkeypatch):
     """{form: (out, stats)}: the dense form, the walk as XLA runs it and
-    the walk as the kernel (the rule sees a TPU; the kernel itself still
-    sees none and interprets)."""
+    the walk as the kernel."""
     before = dispatch_counts()
     forms = {"dense": layer.apply(params, x, real, True),
              "walk_xla": layer.apply(params, x, real, False)}
     with monkeypatch.context() as patch:
-        patch.setattr(moe, "on_tpu", lambda: True)
+        as_on_the_chip(patch, layer)
         forms["walk_kernel"] = layer.apply(params, x, real, False)
     after = dispatch_counts()
     assert {k: after[k] - before.get(k, 0) for k in after
@@ -387,7 +450,8 @@ def test_the_walk_kernel_at_the_ends_of_the_routing(routed, case,
                "every_row_on_one_expert": [11] + list(range(16, 25)),
                "every_assignment_lands": [8, 9, 10, 11, 12, 13, 14, 15]}[case]
     if case == "every_assignment_lands":
-        layer = routed_layer(layer.dtype, top_k=8)
+        layer = routed_layer(layer.dtype, top_k=8,
+                             intermediate=layer.intermediate)
     params, x = biased_router(params, x, experts)
     forms = three_forms(layer, params, x, jnp.ones((4,), bool), monkeypatch)
     stats = forms["walk_xla"][1]
@@ -426,7 +490,7 @@ def test_a_row_alone_in_the_walk_kernel_equals_the_row_in_company(
     matrix product over one row and over four blocks its sums otherwise,
     hence not to the bit here."""
     layer, params, x = routed
-    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    as_on_the_chip(monkeypatch, layer)
     together, _ = layer.apply(params, x, jnp.ones((4,), bool), False)
     alone, stats = layer.apply(params, x[row:row + 1], jnp.ones((1,), bool),
                                False)

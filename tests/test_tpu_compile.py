@@ -240,26 +240,48 @@ def _prefetches_in_the_decode_step(text: str) -> int:
     return max(counts.values())[1]
 
 
+def _sparse_cut(name: str):
+    """(model, bytes of its weights in bfloat16 (low, high), the shapes of
+    an expert's two matrices in the compiled text, ``cache_stats``, the
+    least prefetches its decode step keeps: 172 and 92 were read, the
+    most its temporaries may take) of a served cut of a sparse prompt LM.
+    ``lfm2_game``'s largest program read 245 MB of temporaries; 537 MB
+    more when the embedding's look-up widened the whole tied table to
+    float32 in every decode step (PR 34: a step of 3.15 ms for 1.58)."""
+    from cassmantle_tpu import config as configs
+    from cassmantle_tpu.models import lfm2_moe, qwen3_next
+
+    if name == "qwen3next":
+        cfg = configs.qwen3next_game_config().models.qwen3_next
+        return (qwen3_next.Qwen3NextLM(cfg), (7.3e9, 7.4e9),
+                ("[2048,1024]", "[512,2048]"), qwen3_next.cache_stats, 100,
+                1.5e9)
+    cfg = configs.lfm2_game_config().models.lfm2_moe
+    return (lfm2_moe.Lfm2MoeLM(cfg), (10.3e9, 10.4e9),
+            ("[2048,3072]", "[1536,2048]"), lfm2_moe.cache_stats, 80, 0.5e9)
+
+
+@pytest.mark.parametrize("cut", ["qwen3next", "lfm2"])
 @pytest.mark.parametrize("rows, bucket", [(4, 64), (1, 32)],
                          ids=["batch4_bucket64", "batch1_bucket32"])
 def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
-        v5e, monkeypatch, rows, bucket):
-    """``greedy_decode`` over Qwen3NextLM at the ``qwen3next_game`` cut
-    (published widths, 8 layers, 128 of 512 experts), the largest and the
-    smallest served program: it fits one chip beside the image stack, and
-    each expert layer of the decode step walks its routed assignments in
-    one ``moe_walk`` kernel (ops/moe_walk.py) that reads an expert's
-    matrices where they lie: no loop of dependent products under
-    ``moe_experts``, no copy of a matrix beside the kernel, and the
-    kernel's two slots of one expert within what the compiler gives a
-    kernel. The compiler still prefetches the step's other weights into
-    on-chip memory (132 pieces a step around the loop; none at all
-    around a kernel that states no cost, which made a dispatch 12 ms
-    slower on the chip, PR 32). (The rule asks ``on_tpu``; a described
-    chip is not attached, so the test answers for it.)"""
-    from cassmantle_tpu.config import qwen3next_game_config
+        v5e, monkeypatch, rows, bucket, cut):
+    """``greedy_decode`` over a sparse prompt LM at its served cut
+    (``qwen3next_game``: published widths, 8 layers, 128 of 512 experts of
+    6.3 MB; ``lfm2_game``: 1 + 8 layers, all 64 experts of 18.9 MB, more
+    than on-chip memory holds of one), the largest and the smallest served
+    program: it fits one chip beside the image stack, and each expert
+    layer of the decode step walks its routed assignments in one
+    ``moe_walk`` kernel (ops/moe_walk.py) that reads an expert's matrices
+    where they lie: no loop of dependent products under ``moe_experts``,
+    no copy of a matrix beside the kernel, and the kernel's rings of
+    pieces within what the compiler gives a kernel. The compiler still
+    prefetches the step's other weights into on-chip memory (132 pieces
+    a step around the loop; none at all around a kernel that states no
+    cost, which made a dispatch 12 ms slower on the chip, PR 32). (The
+    rule asks ``on_tpu``; a described chip is not attached, so the test
+    answers for it.)"""
     from cassmantle_tpu.models import moe
-    from cassmantle_tpu.models.qwen3_next import Qwen3NextLM, cache_stats
     from cassmantle_tpu.ops import moe_walk
     from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
 
@@ -270,7 +292,8 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    model = Qwen3NextLM(qwen3next_game_config().models.qwen3_next)
+    (model, weight_bytes, matrices, cache_stats, prefetches,
+     temporaries) = _sparse_cut(cut)
     tree = jax.tree_util.tree_map(
         lambda a: on_chip(a.shape, BF16),
         jax.eval_shape(model.init, jax.random.PRNGKey(0),
@@ -281,10 +304,11 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
         40, row_mask=on_chip((rows,), jnp.bool_),
         cache_stats=cache_stats).compile()
     memory = compiled.memory_analysis()
-    assert 7.3e9 < memory.argument_size_in_bytes < 7.4e9
-    assert memory.temp_size_in_bytes < 1.5e9
+    low, high = weight_bytes
+    assert low < memory.argument_size_in_bytes < high
+    assert memory.temp_size_in_bytes < temporaries
     text = compiled.as_text()
-    assert _prefetches_in_the_decode_step(text) >= 100
+    assert _prefetches_in_the_decode_step(text) >= prefetches
     step_experts = [line for line in text.splitlines()
                     if "lm_decode_step" in line and "/moe_experts/" in line]
     kernels = [line for line in step_experts
@@ -296,9 +320,29 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
     assert scoped == {moe_walk.VMEM_LIMIT_BYTES} and max(scoped) < 16 << 20
     assert not [line for line in step_experts if " while(" in line]
     copies = [line for line in step_experts
-              if " copy(" in line and ("[2048,1024]" in line
-                                       or "[512,2048]" in line)]
+              if " copy(" in line and any(m in line for m in matrices)]
     assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("d, f", [(2048, 512), (2048, 1536)],
+                         ids=["qwen3next_experts", "lfm2_experts"])
+def test_the_walk_kernels_rings_are_sized_by_piece_not_by_expert(d, f):
+    """At both served width sets the plan's rings stay under what the
+    kernel asks of on-chip memory, with room for a piece beside them,
+    whatever an expert weighs (6.3 and 18.9 MB); widths without whole
+    lanes have no plan, and ``moe_walk_fits`` says so."""
+    from cassmantle_tpu.ops import moe_walk
+
+    plan = moe_walk.walk_plan(d, f, 2)
+    scratch = plan.scratch_bytes(d, f, 2)
+    assert scratch + moe_walk.PIECE_BYTES <= moe_walk.VMEM_LIMIT_BYTES
+    assert moe_walk.moe_walk_fits(d, f)
+    assert d % plan.gate_up_rows == 0 and f % plan.down_rows == 0
+    assert min(plan.gate_up_slots, plan.down_slots) >= 2
+    if f == 1536:  # an expert does not fit: it streams through
+        assert 3 * d * f * 2 > moe_walk.VMEM_LIMIT_BYTES > scratch
+    assert not moe_walk.moe_walk_fits(d, f + 64)
+    assert moe_walk.walk_plan(d, f, 2, piece_bytes=1024) is None
 
 
 # (B, H = W, C, F): the ResBlock with the widest conv1 of each SD1.5

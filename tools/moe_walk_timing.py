@@ -1,16 +1,19 @@
 #!/usr/bin/env python3
 """Time the sparse LM's decode walk alone on the chip, in each form.
 
-One expert layer of the served cut (``qwen3next_game_config``: 128 held
-experts of 512, D 2048, F 512, top-10, bfloat16) at 1, 2 and 4 rows: the
+One expert layer of a served cut, its widths taken from the configuration
+(``--config qwen3next``: ``qwen3next_game_config``, 128 held experts of
+512, D 2048, F 512, top-10; ``--config lfm2``: ``lfm2_game_config``, all
+64 held, D 2048, F 1536, top-4; bfloat16) at 1, 2 and 4 rows: the
 XLA form (``models/moe.py::HeldExperts._walk``, a ``fori_loop`` of
 dependent products, ``xla``) against the Pallas kernel
 (``ops/moe_walk.py``, ``kernel``), what the scan and the routing around
 them cost with no walk at all (``scan``), and every slot's expert in
 float32 at full precision (``reference``: put first, it is what the
-others' outputs are held against). Every step routes each row to ten
-distinct experts of the 512, as the router does, so about a quarter of
-the assignments land and the count differs from step to step.
+others' outputs are held against). Every step routes each row to
+``top_k`` distinct experts of all, as the router does: under
+``qwen3next`` about a quarter of the assignments land and the count
+differs from step to step, under ``lfm2`` every one lands.
 
     python tools/moe_walk_timing.py --rows 1,4 --forms reference,xla,kernel
 
@@ -24,7 +27,7 @@ line, also appended to ``chiprun_out/moe_walk_timing.jsonl``. Needs a TPU unless
 interpreted: no time it prints is a measurement).
 
 ``--dispatch`` times one whole ``greedy_decode`` dispatch of the served
-LM instead (8 layers, 96 new tokens, seeded weights, ``--rows`` rows in
+LM instead (its layers, 96 new tokens, seeded weights, ``--rows`` rows in
 the ``--bucket`` prompt bucket), the expert layers' rule answered here, in
 the tool: what the walk costs between its neighbours, which the compiler
 schedules around it otherwise than around a layer alone (PR 32: the
@@ -49,27 +52,47 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from cassmantle_tpu.config import qwen3next_game_config  # noqa: E402
+from cassmantle_tpu import config as configs  # noqa: E402
 from cassmantle_tpu.models.moe import HeldExperts  # noqa: E402
-from cassmantle_tpu.ops.moe_walk import moe_walk  # noqa: E402
+from cassmantle_tpu.ops.moe_walk import moe_walk, walk_plan  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9  # one v5e chip (benchmarks/harness/peaks.py)
 
 
-def layer_of(rehearse: bool) -> HeldExperts:
-    if rehearse:
+def family_of(args):
+    """(FrameworkConfig, its prompt LM's ``LMFamily``) of ``--config``,
+    at the tiny size under ``--rehearse``: the family is found as
+    ``PromptGenerator`` finds it."""
+    from cassmantle_tpu.serving.pipeline import _lm_families
+
+    game, tiny = {
+        "qwen3next": (configs.qwen3next_game_config,
+                      configs.test_qwen3next_config),
+        "lfm2": (configs.lfm2_game_config, configs.test_lfm2_config),
+    }[args.config]
+    cfg = (tiny if args.rehearse else game)()
+    return cfg, next(f for f in _lm_families()
+                     if getattr(cfg.models, f.name) is not None)
+
+
+def layer_of(args) -> tuple:
+    """(the expert layer, D)."""
+    if args.rehearse:
         return HeldExperts(num_experts=32, experts_held=8, first_expert=0,
-                           top_k=4, intermediate=256, dtype=jnp.bfloat16)
-    m = qwen3next_game_config().models.qwen3_next
+                           top_k=4, intermediate=256,
+                           dtype=jnp.bfloat16), 512
+    cfg, family = family_of(args)
+    m = getattr(cfg.models, family.name)
     return HeldExperts(
         num_experts=m.num_experts, experts_held=m.experts_held,
         first_expert=m.first_expert, top_k=m.num_experts_per_tok,
-        intermediate=m.moe_intermediate_size, dtype=jnp.dtype(m.dtype))
+        intermediate=m.moe_intermediate_size,
+        dtype=jnp.dtype(m.dtype)), m.hidden_size
 
 
 def routing(layer: HeldExperts, rows: int, steps: int, seed: int):
-    """(expert, weight, landed), each (steps, rows · top_k): ten distinct
-    experts of all a row, weights normalised over the ten."""
+    """(expert, weight, landed), each (steps, rows · top_k): ``top_k``
+    distinct experts of all a row, weights normalised over them."""
     rs = np.random.RandomState(seed)
     top_i = np.stack([
         np.stack([rs.permutation(layer.num_experts)[:layer.top_k]
@@ -115,15 +138,13 @@ def walk_of(layer: HeldExperts, form: str, rehearse: bool):
 
 def dispatches(args, out_path: str) -> int:
     """One whole LM dispatch under each form of the walk."""
-    from cassmantle_tpu import config as configs
     from cassmantle_tpu.models import moe
-    from cassmantle_tpu.models.qwen3_next import Qwen3NextLM, cache_stats
     from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
 
-    cfg = (configs.test_qwen3next_config() if args.rehearse
-           else qwen3next_game_config())
-    model = Qwen3NextLM(cfg.models.qwen3_next)
-    dtype = jnp.dtype(cfg.models.qwen3_next.dtype)
+    cfg, family = family_of(args)
+    mcfg = getattr(cfg.models, family.name)
+    model, cache_stats = family.model(mcfg), family.cache_stats
+    dtype = jnp.dtype(mcfg.dtype)
     params = jax.jit(lambda key: jax.tree_util.tree_map(
         lambda a: a.astype(dtype), model.init(
             key, jnp.zeros((1, 8), jnp.int32))))(
@@ -156,7 +177,8 @@ def dispatches(args, out_path: str) -> int:
                 dispatch()
                 seconds.append(time.perf_counter() - t0)
             line = {
-                "form": form, "rows": rows, "bucket": args.bucket,
+                "config": args.config, "form": form, "rows": rows,
+                "bucket": args.bucket,
                 "new_tokens": new_tokens,
                 "ms_a_dispatch": 1e3 * statistics.median(seconds),
                 "ms_min_max": [1e3 * min(seconds), 1e3 * max(seconds)],
@@ -174,6 +196,8 @@ def dispatches(args, out_path: str) -> int:
 
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--config", choices=["qwen3next", "lfm2"],
+                        default="qwen3next")
     parser.add_argument("--dispatch", action="store_true")
     parser.add_argument("--bucket", type=int, default=64)
     parser.add_argument("--rows", default="1,2,4")
@@ -190,10 +214,9 @@ def main() -> int:
     os.makedirs(os.path.dirname(out_path), exist_ok=True)
     if args.dispatch:
         return dispatches(args, out_path)
-    layer = layer_of(args.rehearse)
-    d = 512 if args.rehearse else qwen3next_game_config(
-    ).models.qwen3_next.hidden_size
+    layer, d = layer_of(args)
     held, f = layer.experts_held, layer.intermediate
+    plan = walk_plan(d, f, jnp.dtype(layer.dtype).itemsize)
     k_gu, k_dn, k_x = jax.random.split(jax.random.PRNGKey(args.seed), 3)
     gate_up = (jax.random.normal(k_gu, (held, d, 2 * f), jnp.float32)
                * d ** -0.5).astype(layer.dtype)
@@ -233,7 +256,8 @@ def main() -> int:
             mean_landed = float(jnp.mean(jnp.sum(landed, axis=1)))
             call_us = 1e6 * statistics.median(seconds) / args.steps
             line = {
-                "form": form, "rows": rows, "steps": args.steps,
+                "config": args.config, "form": form, "rows": rows,
+                "steps": args.steps, "plan": plan and list(plan),
                 "landed_a_call": mean_landed,
                 "us_a_call": call_us,
                 "us_an_assignment": call_us / mean_landed,
