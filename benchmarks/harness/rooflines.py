@@ -4,8 +4,8 @@ and bytes, from the plain reference's shapes, over the published peaks.
 A call in the trace is given its site by the table of device instructions
 (trace.reduce_trace): the event's whole HLO text holds the call's result
 and operand shapes, and the reference says which products of those shapes
-the models have. A call that fits no site of the reference, or more than
-one, is an error: a share of a roofline is never worked out from a guess.
+the models have. A call that fits no site of the reference (at a whole
+multiple of the site's batch), or more than one, is an error: a share of a roofline is never worked out from a guess.
 """
 
 from __future__ import annotations
@@ -44,12 +44,16 @@ def attention_sites(trees: dict, sizes: dict, names: dict) -> set:
 
 
 def attention_call(hlo: str, sites: set) -> tuple:
-    """(site, bytes an element) of one attention kernel call: q, k and v
-    as (B, S, H*D) operands, the output of q's shape. The program may pad
-    the keys (a ragged S_k up to whole blocks): the site is the one with
-    the call's B, S_q and H*D whose S_k is the call's, or else the only
-    one whose S_k is shorter. The work counted is the site's, with the
-    published S_k and head size, not the padded call's."""
+    """(site at the call's batch, bytes an element) of one attention kernel
+    call: q, k and v as (B, S, H*D) operands, the output of q's shape. The
+    program may pad the keys (a ragged S_k up to whole blocks) and may
+    gather several images into one call: the site is the one with the
+    call's S_q and H*D, a B that divides the call's, and the call's S_k,
+    or else the only one whose S_k is shorter. The work counted is the
+    site's, with the published S_k and head size, not the padded call's,
+    once for each of the site's batches the call holds: the site comes
+    back with the call's B in place of its own, so its floor is that
+    multiple of one image's."""
     results, operands = call_shapes(hlo)
     if len(results) != 1 or len(operands) != 3 \
             or any(len(dims) != 3 for _, dims in results + operands):
@@ -57,7 +61,8 @@ def attention_call(hlo: str, sites: set) -> tuple:
     (kind, (b, sq, width)), (_, (_, sk_call, _)) = results[0], operands[1]
     if kind not in ELEMENT_BYTES:
         raise ValueError(f"no size on record for element type {kind!r}")
-    fits = [s for s in sites if (s[0], s[1], s[3]) == (b, sq, width)]
+    fits = [s for s in sites
+            if (s[1], s[3]) == (sq, width) and b % s[0] == 0]
     exact = [s for s in fits if s[2] == sk_call]
     match = exact or [s for s in fits if s[2] < sk_call]
     if len(match) != 1:
@@ -65,7 +70,7 @@ def attention_call(hlo: str, sites: set) -> tuple:
             f"an attention call of B {b}, S_q {sq}, S_k {sk_call}, H*D "
             f"{width} fits {len(match)} sites of the reference "
             f"{sorted(sites)}")
-    return match[0], ELEMENT_BYTES[kind]
+    return (b,) + match[0][1:], ELEMENT_BYTES[kind]
 
 
 def attention_floor_s(site: tuple, element_bytes: int,

@@ -4,6 +4,14 @@ The window is driven through the two injection points the game engine is
 wired to and nothing below them: ``service.content_backend.generate`` and
 ``service.similarity``. One process, one event loop that stays free (every
 heavy call runs in a thread), few threads.
+
+The window holds whole orbits. In a closed loop every room completes once
+an orbit of the program (one LM dispatch and one image a room today; one
+LM dispatch for all rooms and then their images, once a program gathers
+them), so the window opens at a round completion and closes on a
+completion of the SAME room (``window_close``): rounds over window is then
+rooms over the orbit at whatever phase the window opened, whether the
+completions come evenly spaced or in bursts.
 """
 
 from __future__ import annotations
@@ -93,6 +101,29 @@ def mark(what: str) -> None:
 
 
 _T0 = time.perf_counter()
+
+
+def window_close(completions: list, n_open: int, seconds: float,
+                 slack_s: float):
+    """Which completion closes the window that ``completions[n_open]``
+    opened: ``(index, "opener" | "any")``, or None while none of the
+    completions so far does. ``completions`` holds ``(time, room, valid)``
+    in order of time.
+
+    The window closes at the first valid completion of the opener's own
+    room at or after ``seconds``: a whole number of orbits. A room that
+    only fails never closes it, so ``slack_s`` later the first completion
+    of any room does, and the result line says so."""
+    t_open, opener, _ = completions[n_open]
+    for index in range(n_open + 1, len(completions)):
+        t_done, room, valid = completions[index]
+        if t_done - t_open < seconds:
+            continue
+        if room == opener and valid:
+            return index, "opener"
+        if t_done - t_open >= seconds + slack_s:
+            return index, "any"
+    return None
 
 
 def valid_round(content, image_size: int) -> bool:
@@ -243,7 +274,8 @@ class Run:
         mark("warming the LM and scorer shapes")
         await asyncio.to_thread(self.warm_shapes)
         mark("warm-up rounds")
-        horizon = self.seconds + mix.get("close_slack_s", 30.0)
+        slack = mix.get("close_slack_s", 30.0)
+        horizon = self.seconds + slack
         answers, calls = tr.guess_schedule(mix, self.seed, horizon)
         warm_answers, warm_calls = tr.guess_schedule(
             mix, self.seed + 1, mix["warm"].get("guess_s", 0.0))
@@ -261,7 +293,7 @@ class Run:
         await asyncio.gather(*warm_tasks)
         self.book.scores.clear()
         self.lateness.clear()
-        # the window opens at a round completion...
+        # the window opens at a round completion (its room: the opener)...
         n_open = len(self.completions)
         t_open = await self.next_completion(n_open)
         before = Snapshot()
@@ -279,13 +311,12 @@ class Run:
         guess_tasks: list = []
         guessing = asyncio.ensure_future(self.guesses(
             t_open, answers, calls, guess_tasks))
-        # ...and closes at the first one at or after --seconds later
-        n = n_open + 1
-        while True:
-            t_close = await self.next_completion(n)
-            if t_close - t_open >= self.seconds:
-                break
-            n += 1
+        # ...and closes at the first one of the same room at or after
+        # --seconds later
+        while (closed := window_close(self.completions, n_open,
+                                      self.seconds, slack)) is None:
+            await self.next_completion(len(self.completions))
+        t_close = self.completions[closed[0]][0]
         self.book.closed = True
         after = Snapshot()
         mark(f"window closed after {t_close - t_open:.2f}s")
@@ -303,7 +334,8 @@ class Run:
         await asyncio.gather(*rooms, return_exceptions=True)
         await self.service.stop()
         return {"t_open": t_open, "t_close": t_close, "before": before,
-                "after": after, "score_depth_at_close": depth_at_close}
+                "after": after, "score_depth_at_close": depth_at_close,
+                "window_closed_on": closed[1]}
 
     async def stop_trace_after(self, seconds: float):
         import jax
@@ -335,6 +367,7 @@ class Run:
             "guess_calls": len(calls), "guess_failed": len(failed_calls),
             "compiles_in_window": int(window.counter("jit.compiles")),
             "score_depth_at_close": m["score_depth_at_close"],
+            "window_closed_on": m["window_closed_on"],
             "generator_late_p95_ms": (
                 1e3 * tr.percentile(self.lateness, 95)
                 if self.lateness else None),
@@ -457,7 +490,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     line["notes"] = {k: res[k] for k in (
         "window_s", "rounds", "guess_calls", "guess_failed",
         "failure_counters", "round_errors", "text_fallbacks",
-        "score_depth_at_close",
+        "score_depth_at_close", "window_closed_on",
         "generator_late_p95_ms", "score_p50_ms") if res.get(k) is not None}
     if not trace and not rehearsal:
         line["notes"]["spans"] = {n: m["value"]

@@ -43,8 +43,27 @@ def test_a_call_is_given_the_site_of_its_shapes(hlo, site):
     assert rooflines.attention_call(hlo, SITES) == (site, 2)
 
 
+@pytest.mark.parametrize("b, site", [
+    (8, (2, 4096, 4096, 320)),      # four images' CFG pairs in one call
+    (4, (2, 4096, 77, 320)),        # two, keys padded to 128
+    (2, (1, 4096, 4096, 512)),      # the VAE on two images
+], ids=["self_x4", "cross_x2", "vae_x2"])
+def test_a_gathered_call_reads_that_multiple_of_its_sites_floor(b, site):
+    """An image batch over 1 (ROADMAP A1): the call's B is a whole
+    multiple of the site's, and the floor that multiple of one image's."""
+    sk_call = 128 if site[2] == 77 else site[2]
+    got, element_bytes = rooflines.attention_call(
+        call(b, site[1], sk_call, site[3]), SITES)
+    assert got == (b,) + site[1:] and element_bytes == 2
+    assert rooflines.attention_floor_s(got, 2, KIND) == pytest.approx(
+        (b // site[0]) * rooflines.attention_floor_s(site, 2, KIND))
+
+
 @pytest.mark.parametrize("hlo, sites", [
     (call(2, 2048, 2048, 320), SITES),                 # no such site
+    (call(3, 4096, 4096, 320), SITES),                 # no whole multiple
+    (call(4, 4096, 4096, 320),
+     SITES | {(4, 4096, 4096, 320)}),                  # two sites divide it
     (call(2, 4096, 64, 320), SITES),                   # fewer keys than any
     (call(2, 4096, 8192, 320), SITES),                 # two sites below it
     ("%flash_attention.1 = bf16[16,4096,40]{2,1,0} custom-call(bf16[16,4096,"
@@ -53,7 +72,8 @@ def test_a_call_is_given_the_site_of_its_shapes(hlo, site):
     ("%flash_attention.1 = (bf16[2,4096,320]{2,1,0}, f32[2,4096]{1,0}) "
      "custom-call(bf16[2,4096,320]{2,1,0} %a)", SITES),  # not (q, k, v) -> o
     (call(2, 4096, 4096, 320, kind="c64"), SITES),     # no size on record
-], ids=["unknown", "too_short", "ambiguous", "folded", "not_qkv", "dtype"])
+], ids=["unknown", "no_multiple", "two_batches", "too_short", "ambiguous",
+        "folded", "not_qkv", "dtype"])
 def test_a_call_whose_site_cannot_be_told_is_an_error(hlo, sites):
     with pytest.raises(ValueError):
         rooflines.attention_call(hlo, sites)

@@ -22,7 +22,7 @@ CELLS = [w["name"] for w in M["workloads"]]
 def test_rehearsal_prints_the_contract_line(cell):
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
-         "--workload", cell, "--seed", str(2 ** 31 + 5), "--seconds", "2",
+         "--workload", cell, "--seed", str(2 ** 31 + 5), "--seconds", "4",
          "--trace", "1", "--platform-cpu"],
         capture_output=True, text=True, timeout=900, cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-2000:]

@@ -78,20 +78,23 @@ def test_the_file_is_the_program_at_the_cells_size(cell):
         assert cell.config["sizes"][group] == other[group], group
 
 
-def test_the_files_override_is_a_prompt_queue_that_never_waits(cell):
-    """Every window the same work: with the wait at 0 the queue forms no
-    batch (tests/test_queue.py), and nothing else differs from
-    ``lfm2_game_config``."""
+@pytest.mark.parametrize("name, factory", [
+    ("lfm2_rollover", "lfm2_game_config"),
+    ("qwen3next_rollover", "qwen3next_game_config")])
+def test_the_files_override_is_a_prompt_queue_that_never_waits(name, factory):
+    """Both LM-bound configurations: with the wait at 0 the queue forms no
+    batch by a timer's chance (tests/test_queue.py), so a window's work is
+    the program's choice, and nothing else differs from the factory's."""
     import dataclasses
 
-    from cassmantle_tpu.config import lfm2_game_config
+    from cassmantle_tpu import config as program
 
-    assert cell.config["overrides"] == {
-        "serving": {"max_queue_delay_ms": 0.0}}
-    cfg = stack.framework_config(cell.config, False)
-    assert cfg == lfm2_game_config().replace(serving=dataclasses.replace(
-        lfm2_game_config().serving, max_queue_delay_ms=0.0))
-    assert cell.config["overrides_why"]
+    config = Cell(load_manifest(), name).config
+    assert config["overrides"] == {"serving": {"max_queue_delay_ms": 0.0}}
+    made = getattr(program, factory)()
+    assert stack.framework_config(config, False) == made.replace(
+        serving=dataclasses.replace(made.serving, max_queue_delay_ms=0.0))
+    assert "never by a timer's chance" in config["overrides_why"]
 
 
 def test_lm_flops_book_the_routed_experts_not_the_held_ones(cell):
@@ -198,7 +201,9 @@ def test_the_metric_files_name_this_configurations_floor(cell):
     ours = {m["name"] for m in cell.per_layer}
     assert {"lfm2_moe_roofline_pct", "lfm2_moe_experts_pct",
             "lfm2_short_conv_pct", "image_ms", "lm_ms", "prompt_batch_mean",
-            "mfu.round", "device_idle_pct"} == ours
+            "mfu.round", "device_idle_pct", "round_ms",
+            "prompt_queue_wait_ms", "image_lock_wait_ms", "image_host_ms",
+            "image_batch_mean"} == ours
 
 
 def test_moe_floor_is_the_larger_of_bytes_and_flops_at_this_expert_size():

@@ -289,8 +289,8 @@ def main() -> int:
     if args.slice_s is not None:
         cell.traffic["trace_slice_s"] = args.slice_s
     # the cell's own warm-up and closed loop, with the profiler on for
-    # the slice from the window's start; the window closes at the first
-    # round that completes after the slice
+    # the slice from the window's start; the window closes at the opening
+    # room's first round that completes after the slice
     run = Run(cell, args.seed, cell.traffic["trace_slice_s"], True,
               args.platform_cpu)
     run.build()
