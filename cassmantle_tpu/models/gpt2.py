@@ -137,16 +137,19 @@ class GPT2LM(nn.Module):
     def decode_step(
         self,
         token: jax.Array,      # (B,) ids for position ``index``
-        index: jax.Array,      # scalar int32
+        index: jax.Array,      # scalar int32: the cache slot written
         cache: Tuple,
         valid: jax.Array,      # (B, max_len) cache validity incl. this step
+        positions: jax.Array,  # (B, 1) each row's position id
     ) -> Tuple[jax.Array, Tuple]:
         """One greedy-decode step; the S=1 case of :meth:`decode_chunk`
         (one code path, so the speculative verify forward and the plain
-        greedy scan run the exact same per-position computation).
+        greedy scan run the exact same per-position computation). A row
+        decoded in a wider prompt bucket's program than its own sits at
+        a position below its slot (ops/decode.py ``position_offset``).
         Returns (logits (B, V), updated cache)."""
         logits, new_cache = self.decode_chunk(
-            token[:, None], index, cache, valid)
+            token[:, None], index, cache, valid, positions)
         return logits[:, 0], new_cache
 
     def decode_chunk(
@@ -155,6 +158,7 @@ class GPT2LM(nn.Module):
         index: jax.Array,      # scalar int32: cache position of tokens[:, 0]
         cache: Tuple,
         valid: jax.Array,      # (B, max_len) cache validity incl. the chunk
+        positions: Optional[jax.Array] = None,  # (B, S); None: the slots
     ) -> Tuple[jax.Array, Tuple]:
         """Multi-token cached decode: score S positions in ONE forward.
 
@@ -170,8 +174,9 @@ class GPT2LM(nn.Module):
         Returns (logits (B, S, V), updated cache).
         """
         _, s = tokens.shape
-        positions = index + jnp.arange(s)
-        x = self.wte(tokens) + self.wpe(positions[None, :])
+        if positions is None:
+            positions = (index + jnp.arange(s))[None, :]
+        x = self.wte(tokens) + self.wpe(positions)
         mask = chunk_causal_mask(valid, index, s)
         new_cache = []
         for block, (ck, cv) in zip(self.blocks, cache):

@@ -254,16 +254,20 @@ class Lfm2MoeLM(nn.Module):
                                     "real": real_rows}
 
     def decode_step(self, token: jax.Array, index: jax.Array, cache: dict,
-                    valid: jax.Array) -> Tuple[jax.Array, dict]:
-        """One cached decode step: ``token`` (B,) sits at cache position
-        ``index`` of the attention layers; the convolution layers shift
-        their window. Returns (logits (B, V), new cache)."""
+                    valid: jax.Array, positions: jax.Array
+                    ) -> Tuple[jax.Array, dict]:
+        """One cached decode step: ``token`` (B,) is written to cache slot
+        ``index`` of the attention layers and rotated by its row's
+        ``positions`` (B, 1), which lies below the slot for a row decoded
+        in a wider prompt bucket's program than its own; the convolution
+        layers shift their window and know no position. Returns (logits
+        (B, V), new cache)."""
         x = self.embed(token[:, None]).astype(F32)
         real = cache["real"][:, None]
         mask = valid[:, None, None, :]
         entries, stats = [], cache["stats"]
         for layer, entry in zip(self.layers, cache["layers"]):
-            args = (dict(positions=index[None], mask=mask, kv_cache=entry,
+            args = (dict(positions=positions, mask=mask, kv_cache=entry,
                          index=index)
                     if layer.full_attention else dict(window=entry))
             x, entry, layer_stats = layer(x, real, False, **args)

@@ -274,15 +274,20 @@ class MistralLM(nn.Module):
     def decode_step(
         self,
         token: jax.Array,      # (B,) ids for position ``index``
-        index: jax.Array,      # scalar int32
+        index: jax.Array,      # scalar int32: the cache slot written
         cache: Tuple,
         valid: jax.Array,      # (B, max_len) cache validity incl. this step
+        positions: jax.Array,  # (B, 1) each row's position id
     ) -> Tuple[jax.Array, Tuple]:
         """One cached decode step; the S=1 case of :meth:`decode_chunk`
         (one code path shared with the speculative verify forward).
+        RoPE follows ``positions``; the sliding window is counted in
+        cache slots, so the serving layer hands this family rows of one
+        prompt bucket only, where the two agree
+        (serving/pipeline.py ``LMFamily.mixed_buckets``).
         Returns (logits (B, V), new cache)."""
         logits, new_cache = self.decode_chunk(
-            token[:, None], index, cache, valid)
+            token[:, None], index, cache, valid, positions)
         return logits[:, 0], new_cache
 
     def decode_chunk(
@@ -291,6 +296,7 @@ class MistralLM(nn.Module):
         index: jax.Array,      # scalar int32: cache position of tokens[:, 0]
         cache: Tuple,
         valid: jax.Array,      # (B, max_len) cache validity incl. the chunk
+        positions: Optional[jax.Array] = None,  # (B, S); None: the slots
     ) -> Tuple[jax.Array, Tuple]:
         """Multi-token cached decode (the GPT2LM.decode_chunk contract):
         RoPE follows the true positions ``index + j`` and the sliding
@@ -304,9 +310,9 @@ class MistralLM(nn.Module):
         _, s = tokens.shape
         mask = chunk_causal_mask(valid, index, s,
                                  window=cfg.sliding_window)
-        positions = index + jnp.arange(s)
-        cos, sin = rope_tables(positions[None, :], cfg.head_dim,
-                               cfg.rope_theta)
+        if positions is None:
+            positions = (index + jnp.arange(s))[None, :]
+        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
         x = self.embed(tokens)
         new_cache = []
         for block, (ck, cv) in zip(self.blocks, cache):

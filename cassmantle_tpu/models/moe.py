@@ -105,6 +105,47 @@ class MoEMLP(nn.Module):
         return out.reshape(b, s, d).astype(x.dtype)
 
 
+def stored_dot(x: jax.Array, kernel: jax.Array, step: bool) -> jax.Array:
+    """x (..., K) @ kernel (K, N) in its stored type -> (..., N) float32.
+    Prefill reads both operands in the stored type. The rows of a decode
+    ``step`` reach the matrix at float32's precision at every row count,
+    in whichever form the chip multiplies them fastest: ONE row is the
+    float32 product over the widened matrix (a multiply-reduce at the
+    memory's pace, which is what the compiler made of a one-row step
+    before this was written down: it drops a rounding the source writes
+    where it can keep more); two rows and more go through the MXU as two
+    operands of the stored type, a row's rounding and what the rounding
+    left (the row to 2**-17 under bfloat16), stacked so that the matrix
+    is read once.
+
+    Why: fed the rounded rows as written, a row in company read
+    ``lm_logit_gap`` higher than alone on 138 of 144 prompts, up to
+    ``lfm2_game``'s fp8 control (PERF.md section 2); with this its
+    readings in company spread as they do alone. It does not make a row's
+    tokens those of its solo decode: the prefill's products and every
+    float32 sum are ordered by the batch's shape, and each later rounding
+    to the stored type turns 1e-7 into 1e-3 (PERF.md section 6, PR 37;
+    every row as its own float32 multiply-reduce was 11% slower at four
+    rows and no closer). The rounding is ``reduce_precision`` because
+    that one the compiler may not drop: ``x - x.astype(stored).astype(
+    float32)`` it folds to 0."""
+    rows = x.size // x.shape[-1]
+    if not step or kernel.dtype.itemsize >= 4:
+        return jnp.dot(x.astype(kernel.dtype), kernel,
+                       preferred_element_type=jnp.float32)
+    flat = x.astype(jnp.float32).reshape(rows, x.shape[-1])
+    if rows == 1:
+        out = jnp.dot(flat, kernel.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    else:
+        kind = jnp.finfo(kernel.dtype)
+        hi = jax.lax.reduce_precision(flat, kind.nexp, kind.nmant)
+        out = jnp.dot(jnp.concatenate([hi, flat - hi]).astype(kernel.dtype),
+                      kernel, preferred_element_type=jnp.float32)
+        out = out[:rows] + out[rows:]
+    return out.reshape(*x.shape[:-1], -1)
+
+
 class HeldExperts(nn.Module):
     """Top-k routed SwiGLU experts of which ``experts_held`` live here,
     ids ``[first_expert, first_expert + experts_held)``, plus an optional
@@ -240,12 +281,9 @@ class HeldExperts(nn.Module):
                                     jnp.float32)
                 s_gate = self.param("shared_gate", dense_init, (d, 1),
                                     jnp.float32)
-                gu = jnp.dot(xb, s_gate_up.astype(self.dtype),
-                             preferred_element_type=jnp.float32)
+                gu = stored_dot(x32, s_gate_up.astype(self.dtype), not dense)
                 h = nn.silu(gu[:, :fs]) * gu[:, fs:]
-                shared = jnp.dot(h.astype(self.dtype),
-                                 s_down.astype(self.dtype),
-                                 preferred_element_type=jnp.float32)
+                shared = stored_dot(h, s_down.astype(self.dtype), not dense)
                 out = out + shared * jax.nn.sigmoid(jnp.dot(
                     x32, s_gate.astype(jnp.float32), precision=hi))
         return out, stats

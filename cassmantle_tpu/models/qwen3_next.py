@@ -44,15 +44,16 @@ import jax
 import jax.numpy as jnp
 
 from cassmantle_tpu.config import Qwen3NextConfig
-from cassmantle_tpu.models.moe import HeldExperts
+from cassmantle_tpu.models.moe import HeldExperts, stored_dot
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 
 
 class Linear(nn.Module):
-    """x @ kernel without a bias: operands in the kernel's storage dtype,
-    float32 out."""
+    """x (B, S, K) @ kernel without a bias: operands in the kernel's
+    storage dtype, float32 out; one token a row is a decode step, whose
+    rows keep float32's precision (models/moe.py ``stored_dot``)."""
 
     features: int
     dtype: jnp.dtype
@@ -61,8 +62,7 @@ class Linear(nn.Module):
     def __call__(self, x):
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (x.shape[-1], self.features), F32)
-        return jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
-                       preferred_element_type=F32)
+        return stored_dot(x, kernel.astype(self.dtype), x.shape[-2] == 1)
 
 
 class RMSNorm(nn.Module):
@@ -306,8 +306,10 @@ class Qwen3NextLM(nn.Module):
     def setup(self):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=F32,
-                              name="embed")
+        # rows are looked up in the stored type and widened after: asked
+        # for float32 rows, a step of two rows and more widened the whole
+        # table first (311 MB written a step in qwen3next_game)
+        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed")
         self.layers = [
             Qwen3NextLayer(cfg, cfg.is_full_attention(i), dtype,
                            name=f"layer_{i}")
@@ -331,7 +333,7 @@ class Qwen3NextLM(nn.Module):
         mask = (jnp.tril(jnp.ones((p, p), bool))[None]
                 & token_valid[:, None, :])[:, None]
         real = token_valid & real_rows[:, None]
-        x = self.embed(input_ids)
+        x = self.embed(input_ids).astype(F32)
         entries, stats = [], zero_stats(self.cfg)
         for layer in self.layers:
             args = (dict(positions=positions, mask=mask)
@@ -373,16 +375,20 @@ class Qwen3NextLM(nn.Module):
                                     "real": real_rows}
 
     def decode_step(self, token: jax.Array, index: jax.Array, cache: dict,
-                    valid: jax.Array) -> Tuple[jax.Array, dict]:
-        """One cached decode step: ``token`` (B,) sits at cache position
-        ``index`` of the full-attention layers; the linear layers step
-        their state. Returns (logits (B, V), new cache)."""
-        x = self.embed(token[:, None])
+                    valid: jax.Array, positions: jax.Array
+                    ) -> Tuple[jax.Array, dict]:
+        """One cached decode step: ``token`` (B,) is written to cache slot
+        ``index`` of the full-attention layers and rotated by its row's
+        ``positions`` (B, 1), which lies below the slot for a row decoded
+        in a wider prompt bucket's program than its own; the linear layers
+        step their state and know no position. Returns (logits (B, V),
+        new cache)."""
+        x = self.embed(token[:, None]).astype(F32)
         real = cache["real"][:, None]
         mask = valid[:, None, None, :]
         entries, stats = [], cache["stats"]
         for layer, entry in zip(self.layers, cache["layers"]):
-            args = (dict(positions=index[None], mask=mask, kv_cache=entry,
+            args = (dict(positions=positions, mask=mask, kv_cache=entry,
                          index=index)
                     if layer.full_attention else dict(state=entry))
             x, entry, layer_stats = layer(x, real, False, **args)

@@ -31,8 +31,8 @@ def make_apply_fns(model):
         return model.apply(params, ids, prompt_len, max_len, *row_mask,
                            method=cls.prefill)
 
-    def decode_step(params, token, index, cache, valid):
-        return model.apply(params, token, index, cache, valid,
+    def decode_step(params, token, index, cache, valid, positions):
+        return model.apply(params, token, index, cache, valid, positions,
                            method=cls.decode_step)
 
     def decode_chunk(params, tokens, index, cache, valid):
@@ -65,8 +65,19 @@ def greedy_decode(
     top_k: int = 40,
     row_mask=None,             # (B,) True = a request, False = batch padding
     cache_stats=None,          # static: final cache -> tree of counters
+    *,
+    position_offset: jax.Array,  # (B,) int32, <= 0: own bucket minus P
 ):
     """Returns (generated (B, max_new_tokens), gen_len (B,)).
+
+    Every row's generated token ``i`` is written to cache slot ``P + i``
+    and sits at position ``P + i + position_offset[row]``: a row whose
+    own prompt bucket is narrower than this program's ``P`` names the
+    difference, and decodes at the positions of a decode of its own
+    (serving/pipeline.py ``decode_ids_batch`` runs a batch in its widest
+    row's program). It is an operand of every program, zeros where each
+    row is in its own bucket's, so a mixed batch runs the program a
+    same-bucket one compiled.
 
     The cache is whatever tree the model's ``prefill`` returns; the scan
     only carries it. A model whose cache counts what it did (a sparse
@@ -110,15 +121,16 @@ def greedy_decode(
         token = jnp.where(done, jnp.int32(eos_token), token)
         emitted = token
         done = done | (token == eos_token)
-        # All rows decode at cache index P+i. Rows whose prompt is shorter
-        # than P see a small position-id offset; the serving layer keeps
-        # buckets tight so the offset stays negligible, and masked padding
-        # positions are never attended either way.
+        # All rows write cache slot P+i; each sits at its own position
+        # (a prompt shorter than its bucket leaves masked slots between,
+        # never attended).
         idx = jnp.int32(p + i)
         valid = prompt_valid | (
             (positions >= p) & (positions <= idx)
         )
-        logits, cache = decode_step_fn(params, token, idx, cache, valid)
+        logits, cache = decode_step_fn(
+            params, token, idx, cache, valid,
+            (idx + position_offset)[:, None])
         return (logits, cache, done), emitted
 
     init_done = jnp.zeros((b,), dtype=bool)
@@ -312,15 +324,16 @@ def speculative_decode(
                 # stale kv never accumulates to erode the accept rate.
                 sync_valid = prompt_valid | (
                     (positions >= p) & (positions <= idx - 1))
-                _, d_cache = draft.step_fn(draft_params, prev, idx - 1,
-                                           d_cache, sync_valid)
+                _, d_cache = draft.step_fn(
+                    draft_params, prev, idx - 1, d_cache, sync_valid,
+                    jnp.full((b, 1), idx - 1))
 
                 def d_step(state, _):
                     dc, cur, tok = state
                     valid = prompt_valid | (
                         (positions >= p) & (positions <= cur))
                     logits, dc = draft.step_fn(draft_params, tok, cur, dc,
-                                               valid)
+                                               valid, jnp.full((b, 1), cur))
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     return (dc, cur + 1, nxt), nxt
                 (d_cache, _, _), drafts = jax.lax.scan(

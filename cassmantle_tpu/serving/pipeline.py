@@ -55,7 +55,7 @@ from cassmantle_tpu.ops.ddim import (
 from cassmantle_tpu.ops.samplers import make_sampler
 from cassmantle_tpu.ops.decode import greedy_decode
 from cassmantle_tpu.serving import integrity
-from cassmantle_tpu.utils.locks import OrderedLock
+from cassmantle_tpu.utils.locks import OrderedLock, Turns
 from cassmantle_tpu.utils.logging import get_logger, metrics
 from cassmantle_tpu.utils.profiling import (
     block_timer,
@@ -634,7 +634,7 @@ class Text2ImagePipeline:
         # whole device dispatches, so nothing coarser may nest inside.
         self._dispatch_lock = OrderedLock(
             "pipeline.t2i_dispatch", rank=10,
-            wait_span="pipeline.image_lock_wait")
+            wait_span="pipeline.image_lock_wait", in_turn=True)
         # stage-disaggregated serving (serving/stages.py): built lazily
         # on the first staged generate; the supervisor is wired by
         # InferenceService so per-stage watchdog health fuses into
@@ -1037,8 +1037,11 @@ class LMFamily(NamedTuple):
     tokenizer and checkpoint are called), how to build the model, how to
     convert a checkpoint (None: no converter, ``weights_dir`` is refused),
     which options it serves, the parameters a token's forward touches
-    (``active_params(tree, mcfg)``: all of them for a dense LM) and the
-    counters its cache carries (``ops/decode.py`` ``cache_stats``)."""
+    (``active_params(tree, mcfg)``: all of them for a dense LM), the
+    counters its cache carries (``ops/decode.py`` ``cache_stats``) and
+    whether a decode batch may mix prompt buckets (``mixed_buckets``:
+    everything a decode step does with a position follows the row's own
+    position id, not its cache slot)."""
 
     name: str
     model: Callable
@@ -1047,6 +1050,7 @@ class LMFamily(NamedTuple):
     speculative: bool = True     # needs decode_chunk and a cache that rolls back
     active_params: Callable = lambda tree, mcfg: _dense_params(tree)
     cache_stats: Optional[Callable] = None
+    mixed_buckets: bool = True
 
 
 def _lm_families() -> Tuple[LMFamily, ...]:
@@ -1067,7 +1071,8 @@ def _lm_families() -> Tuple[LMFamily, ...]:
                  active_params=lfm2_moe.active_params,
                  cache_stats=lfm2_moe.cache_stats),
         LMFamily("mistral", MistralLM,
-                 lambda m: lambda t: convert_mistral(t, m.num_layers)),
+                 lambda m: lambda t: convert_mistral(t, m.num_layers),
+                 mixed_buckets=False),  # its window counts cache slots
         LMFamily("gpt2", GPT2LM,
                  lambda m: lambda t: convert_gpt2(t, m.num_layers,
                                                   m.hidden_size)),
@@ -1404,22 +1409,27 @@ class PromptGenerator:
     def decode_ids_batch(self, seed_texts: Sequence[str],
                          max_new_tokens: Optional[int] = None,
                          seed: Optional[int] = None):
-        """Batched continuation at the token level: N seed texts ->
-        one bucketed prefill + cached decode scan PER PROMPT BUCKET;
-        returns (tokens (N, max_new), gen_len (N,)).
+        """Batched continuation at the token level: N seed texts -> ONE
+        bucketed prefill + cached decode scan; returns (tokens (N,
+        max_new), gen_len (N,)).
 
-        Rows group by each prompt's OWN bucket — never the batch's
-        longest — because all rows of a (B, P) decode share cache
-        positions P+i: a short prompt co-batched into a longer prompt's
-        bucket would decode at different position ids than it would
-        alone, making round text depend on which requests happened to
-        batch with it. Grouping by own bucket keeps batch output
-        row-for-row IDENTICAL to single decodes (greedy; sampled rows
-        draw per-row independent Gumbel noise) while still coalescing
-        the common case — game seeds cluster in the same bucket. Each
-        group's batch dim pads to the next BATCH_BUCKETS size with
-        1-token dummy rows (decoded then dropped), keeping both shape
-        axes static across calls.
+        The batch runs in the program of its WIDEST row's prompt bucket.
+        A row whose own bucket is narrower carries the difference as its
+        position offset (``greedy_decode``): its generated token ``i``
+        sits at position ``own_bucket + i`` while its cache slot is
+        ``P + i``, so it decodes at the same positions and by the same
+        mathematics as a decode of its own, whatever it was batched
+        with; the masked slots between its prompt and its tokens differ
+        (greedy; sampled rows draw per-row independent Gumbel noise). The
+        batch dim pads to the next BATCH_BUCKETS size with 1-token dummy
+        rows (decoded then dropped), keeping both shape axes static
+        across calls.
+
+        Where rows cannot share a program, they group by each prompt's
+        OWN bucket, one dispatch a group: under speculative decode (its
+        lockstep commit and draft context are laid out by cache slot)
+        and for a family without ``mixed_buckets`` (Mistral: the sliding
+        window counts slots). No benchmark cell runs either.
 
         Decode mode comes from the config (text_temperature=0 -> greedy,
         the reference behavior; >0 -> top-k sampling keyed on ``seed``,
@@ -1435,11 +1445,13 @@ class PromptGenerator:
         if seed is None:
             seed = self._decode_calls
             self._decode_calls += 1
+        own = [self._bucket_for(len(toks), max_new, limit) for toks in rows]
         groups: dict = {}
-        for i, toks in enumerate(rows):
-            groups.setdefault(
-                self._bucket_for(len(toks), max_new, limit), []
-            ).append(i)
+        for i, bucket in enumerate(own):
+            groups.setdefault(bucket, []).append(i)
+        if self.family.mixed_buckets and not any(
+                self._spec_enabled(b, max_new) for b in groups):
+            groups = {max(groups): list(range(len(rows)))}
         out_tokens = np.zeros((len(rows), max_new), dtype=np.int32)
         out_len = np.zeros((len(rows),), dtype=np.int32)
         spec_stats = []
@@ -1467,11 +1479,13 @@ class PromptGenerator:
                           self.tokenizer.pad_id % m.vocab_size,
                           dtype=np.int32)
             lens = np.ones((n_pad,), dtype=np.int32)  # dummies: 1 pad token
+            offsets = np.zeros((n_pad,), dtype=np.int32)
             for row, src in enumerate(idxs):
                 toks = rows[src]
                 # lint: ignore[host-sync] — toks is a host token list
                 ids[row, : len(toks)] = np.asarray(toks) % m.vocab_size
                 lens[row] = max(1, len(toks))
+                offsets[row] = own[src] - bucket
             # an out-of-vocab eos (byte-fallback tokenizer vs a smaller
             # model vocab) can never be emitted: pass vocab_size as an
             # unreachable sentinel so early-stop is cleanly disabled — a
@@ -1517,13 +1531,13 @@ class PromptGenerator:
                         eos,
                         self.cfg.sampler.text_temperature,
                         self.cfg.sampler.text_top_k,
+                        position_offset=jnp.asarray(offsets),
                         **(dict(row_mask=jnp.asarray(np.arange(n_pad) < n),
                                 cache_stats=stats_fn) if stats_fn else {}),
                     )
                 routed += stats
-            # one sync per DISPATCHED bucket group (not per row): each
-            # group is a separate device computation whose result must
-            # land before its rows scatter into the output
+            # one sync per DISPATCH (not per row): its result must land
+            # before its rows scatter into the output
             toks_host = integrity.poison(
                 # lint: ignore[host-sync] — per-dispatch sync, not per-item
                 np.asarray(tokens[:n]), peer="prompt")
@@ -1541,8 +1555,8 @@ class PromptGenerator:
             # lint: ignore[host-sync] — per-dispatch sync, not per-item
             out_len[idxs] = np.asarray(gen_len[:n])
             if lm_w8a8_armed(self.cfg.models):
-                # one int8-kernel decode dispatch per bucket group (the
-                # gpt2_w8a8 bench A/B's proof the path engaged)
+                # one int8-kernel decode dispatch (the gpt2_w8a8 bench
+                # A/B's proof the path engaged)
                 metrics.inc("pipeline.w8a8_dispatches")
         self._record_spec_stats(spec_stats)
         note_moe_counters(routed)
@@ -1686,6 +1700,11 @@ class TPUContentBackend(ContentBackend):
         self.styles = styles or load_styles()
         self.rng = rng or random.Random(cfg.seed)
         self._round = 0
+        # rounds render in the order generate() was called (the order
+        # their texts came back), not the order their executor threads
+        # won the interpreter: the order of the rooms is then the same
+        # in every orbit of a loop that hands over several texts at once
+        self._turns = Turns()
 
     def _style_prompt(self, prompt: str) -> str:
         style = self.rng.choice(self.styles)
@@ -1722,6 +1741,14 @@ class TPUContentBackend(ContentBackend):
         # so the round-generation trace follows onto the worker thread
         # (the pipeline's block_timer stage spans land in it)
         ctx = contextvars.copy_context()
-        return await loop.run_in_executor(
-            None, ctx.run, self.generate_sync, seed, is_seed, text
-        )
+        ticket = self._turns.take()
+
+        def in_turn():
+            with self._turns.holding(ticket):
+                return self.generate_sync(seed, is_seed, text)
+
+        try:
+            return await loop.run_in_executor(None, ctx.run, in_turn)
+        finally:
+            # cancelled before its thread started: nobody waits for it
+            self._turns.leave(ticket)

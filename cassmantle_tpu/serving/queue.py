@@ -45,6 +45,12 @@ Overload control (ISSUE 13; serving/overload.py):
   background item still heads a batch after ``background_every``
   consecutive batches dispatched with background work pending — rounds
   keep rotating under sustained interactive load.
+
+Late binding (ISSUE 37): a queue told that the device has other work
+ahead of it (``hold_while=``) keeps its batch open past the coalescing
+window for as long as that is so. The device is one serial resource: a
+program enqueued behind a running one starts no sooner for being bound
+early, and every item that arrives meanwhile rides the same dispatch.
 """
 
 from __future__ import annotations
@@ -242,6 +248,16 @@ class BatchingQueue(Generic[T, R]):
     :class:`~cassmantle_tpu.serving.supervisor.ServingSupervisor`)
     receives overrun notifications and drives the degraded admission
     bound ``degraded_max_pending``.
+
+    ``hold_while`` (optional) answers "the device has other work ahead
+    of this queue". After its first item and its coalescing window the
+    collector keeps collecting while the answer is yes and the batch is
+    under ``max_batch``; it dispatches when the batch is full or the
+    answer turns no, with whatever arrived by then. Whoever changes the
+    answer calls :meth:`recheck_hold`, so the release is an event and
+    not a poll. With the device free there is no hold: a lone item is
+    dispatched as fast as without the argument. A held item keeps its
+    deadline, and ``stop()`` fails it like any collected one.
     """
 
     def __init__(
@@ -260,6 +276,7 @@ class BatchingQueue(Generic[T, R]):
         background_every: int = 8,
         on_dispatch_error: Optional[Callable[[BaseException], None]]
         = None,
+        hold_while: Optional[Callable[[], bool]] = None,
     ) -> None:
         # ``dispatcher``: a dedicated _DispatchWorker for this queue.
         # Default is the process-global worker (device work serializes
@@ -300,6 +317,13 @@ class BatchingQueue(Generic[T, R]):
         # collector and drained by stop()
         self._spill: List = []
         self._task: Optional[asyncio.Task] = None
+        self._hold_while = hold_while
+        # set by an arrival and by recheck_hold(): what a holding
+        # collector sleeps on
+        self._hold_wake = asyncio.Event()
+        # perf_counter span of the newest batch's hold: the part of its
+        # members' wait that the queue chose (_record_batch_obs)
+        self._held = (0.0, 0.0)
 
     def start(self) -> None:
         if self._task is None:
@@ -356,6 +380,12 @@ class BatchingQueue(Generic[T, R]):
                 # future a second time
                 fut._obs_t = None          # type: ignore[attr-defined]
             fut.set_exception(DeadlineExceeded(self.name))
+
+    def recheck_hold(self) -> None:
+        """What ``hold_while`` reads has changed: a holding collector
+        looks again now. Call it on the queue's event loop (from another
+        thread: ``loop.call_soon_threadsafe(queue.recheck_hold)``)."""
+        self._hold_wake.set()
 
     def depth(self) -> int:
         """Pending submissions across both priority tiers."""
@@ -444,20 +474,17 @@ class BatchingQueue(Generic[T, R]):
             metrics.inc(f"{self.name}.rejected")
             raise QueueFull(self.name)
         metrics.gauge(f"{self.name}.depth", self.depth())
+        self._hold_wake.set()
         if deadline_s is not None:
             handle = loop.call_later(deadline_s, self._expire, fut)
             fut.add_done_callback(lambda _f: handle.cancel())
         return await fut
 
-    async def _pop_one(self, timeout: Optional[float]):
-        """One pending item honoring priority: spilled items first,
-        then interactive ahead of background — UNLESS background has
-        sat out ``background_every`` consecutive batches (the
-        starvation bound: its oldest item heads this batch). Both
-        empty: await whichever tier produces first. Returns None on
-        timeout. An item a racing get() returns after losing the
-        FIRST_COMPLETED race (or after cancellation was requested)
-        lands in ``self._spill`` — never lost, consumed next pop."""
+    def _pop_nowait(self):
+        """One pending item honoring priority, or None: spilled items
+        first, then interactive ahead of background — UNLESS background
+        has sat out ``background_every`` consecutive batches (the
+        starvation bound: its oldest item heads this batch)."""
         if self._spill:
             return self._spill.pop(0)
         starving = (self._bg_queue.qsize() > 0
@@ -469,6 +496,17 @@ class BatchingQueue(Generic[T, R]):
                 return q.get_nowait()
             except asyncio.QueueEmpty:
                 pass
+        return None
+
+    async def _pop_one(self, timeout: Optional[float]):
+        """One pending item in :meth:`_pop_nowait`'s order. Both tiers
+        empty: await whichever produces first. Returns None on
+        timeout. An item a racing get() returns after losing the
+        FIRST_COMPLETED race (or after cancellation was requested)
+        lands in ``self._spill`` — never lost, consumed next pop."""
+        item = self._pop_nowait()
+        if item is not None:
+            return item
         getters = (
             # asyncio.Queue.get() is a COROUTINE here, not the blocking
             # queue.Queue.get — it runs as a task and is awaited below
@@ -530,6 +568,8 @@ class BatchingQueue(Generic[T, R]):
                 if nxt is None:
                     break
                 batch.append(nxt)
+            if self._hold_while is not None:
+                await self._hold(batch, loop, opened)
             # how long the window actually held the first item before
             # dispatch: ~0 under load (bucket fills instantly), ~the
             # full max_delay under trickle traffic — the knob's cost
@@ -541,6 +581,32 @@ class BatchingQueue(Generic[T, R]):
                     fut.set_exception(QueueStopped(self.name))
             raise
         return batch
+
+    async def _hold(self, batch: List, loop, opened: float) -> None:
+        """Late binding: keep ``batch`` open while the device has other
+        work ahead of this queue. Every arrival joins it; when the
+        answer turns no, what arrived in the same turn of the loop as
+        the news still rides along. Items land in ``batch`` as they are
+        popped, so a cancelled collector fails them all."""
+        held, entered = False, time.perf_counter()
+        while len(batch) < self.max_batch:
+            busy = self._hold_while()
+            held = held or busy
+            if not held:        # the device free: as without the signal
+                break
+            nxt = self._pop_nowait()
+            if nxt is not None:
+                batch.append(nxt)
+            elif busy:
+                self._hold_wake.clear()
+                await self._hold_wake.wait()
+            else:
+                break
+        reason = ("full" if len(batch) >= self.max_batch
+                  else "device_free" if held else "timer")
+        self._held = (entered, time.perf_counter())
+        metrics.observe(f"{self.name}.hold_s", loop.time() - opened)
+        metrics.inc(f"{self.name}.release", labels={"reason": reason})
 
     async def _run(self) -> None:
         while True:
@@ -692,8 +758,11 @@ class BatchingQueue(Generic[T, R]):
         if self.admission is not None and status == "ok" and player:
             # the AIMD signal: the batch's end-to-end latency is its
             # service time plus its slowest member's queue wait (error
-            # batches excluded — a handler bug is not a latency signal)
-            waits = [t_dispatch - t
+            # batches excluded — a handler bug is not a latency signal).
+            # The part of a wait spent held for the device is left out:
+            # a wait the queue chose is not load
+            held_from, held_to = self._held
+            waits = [t_dispatch - t - max(0.0, held_to - max(t, held_from))
                      for t in (getattr(f, "_obs_t", None)
                                for f in player) if t is not None]
             self.admission.observe_batch(

@@ -272,7 +272,7 @@ class SDXLPipeline:
 
         self._dispatch_lock = OrderedLock(
             "pipeline.sdxl_dispatch", rank=11,
-            wait_span="pipeline.image_lock_wait")
+            wait_span="pipeline.image_lock_wait", in_turn=True)
         # stage-disaggregated serving (serving/stages.py); supervisor is
         # wired by InferenceService, same as the SD1.5 pipeline
         self.supervisor = None

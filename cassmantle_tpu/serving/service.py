@@ -117,8 +117,22 @@ class InferenceService:
         # live promotion, or several Game instances sharing one service)
         # coalesce their LM decodes into one batched greedy_decode
         # dispatch (PromptGenerator.decode_ids_batch) instead of
-        # serializing single-prompt scans on the dispatch thread.
+        # serializing single-prompt scans on the dispatch thread. The
+        # queue binds its batch late: while a round of this service is
+        # between its text and its image's RETURN (_generate_content
+        # keeps the count) the device has that image ahead of any LM
+        # program, and the prompts that arrive meanwhile ride one
+        # dispatch. The count, not the image lock's state: the lock is
+        # taken on an executor thread some ms after the text returns,
+        # when the collector has already looked, and the round whose
+        # image returns asks for its next text in the same turn of the
+        # loop, so a release at the device's last instruction leaves
+        # without it (tests/test_served_loop_model.py; the price is the
+        # image's host tail, 4.6 ms of idle chip a dispatch). The hold
+        # has no bound but its items' deadlines.
         from cassmantle_tpu.serving.pipeline import PromptGenerator
+
+        self._rendering = 0
 
         self.prompt_queue: BatchingQueue = BatchingQueue(
             handler=self._prompt_batch,
@@ -133,6 +147,7 @@ class InferenceService:
             admission=make_admission("prompt", cfg),
             background_every=cfg.serving.background_every_batches,
             on_dispatch_error=self.recovery.note_dispatch_exception,
+            hold_while=lambda: self._rendering > 0,
         )
 
     # handlers run on the dispatch thread
@@ -291,6 +306,9 @@ class InferenceService:
                 log.warning(
                     "prompt queue failed (%s); decoding %r in-backend",
                     type(exc).__name__, seed[:40])
+        # from here to the image's return the device has this round's
+        # image ahead of the prompt queue, which holds its batch for it
+        self._rendering += 1
         try:
             if text is not None:
                 return await self.backend.generate(seed, is_seed,
@@ -302,6 +320,9 @@ class InferenceService:
             # exceptions classify here; rounds.py owns the retry ladder
             self.recovery.note_dispatch_exception(exc)
             raise
+        finally:
+            self._rendering -= 1
+            self.prompt_queue.recheck_hold()
 
     @property
     def content_backend(self):

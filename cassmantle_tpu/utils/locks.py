@@ -30,10 +30,11 @@ flight recorder.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import traceback
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 from cassmantle_tpu.utils.logging import get_logger, metrics
 
@@ -92,6 +93,55 @@ def _site() -> str:
     return "<unknown>"
 
 
+class Turns:
+    """The order in which threads pass an ``OrderedLock(in_turn=True)``,
+    fixed where the order is known: tickets are taken on ONE thread (an
+    event loop, in the order the work was handed over), each worker
+    thread names its own with :meth:`holding`, and the lock lets them
+    through strictly by ticket, whichever thread reached it first
+    (threads started in one turn of a loop race for the interpreter, and
+    a plain lock wakes its waiters in no promised order). A ticket that
+    never reaches the lock is passed over when its ``holding`` ends, so
+    a failed or lock-free path holds nobody up."""
+
+    def __init__(self) -> None:
+        self._cond = threading.Condition()
+        self._issued = 0
+        self._serving = 0
+        self._left: set = set()
+
+    def take(self) -> int:
+        with self._cond:
+            self._issued += 1
+            return self._issued - 1
+
+    @contextlib.contextmanager
+    def holding(self, ticket: int) -> Iterator[None]:
+        """This thread's acquisitions of in-turn locks wait for
+        ``ticket``'s turn; the turn moves on at the lock's release, or
+        here if the lock was never taken."""
+        _tls.turn = (self, ticket)
+        try:
+            yield
+        finally:
+            _tls.turn = None
+            self.leave(ticket)
+
+    def _wait(self, ticket: int) -> None:
+        with self._cond:
+            self._cond.wait_for(lambda: self._serving >= ticket)
+
+    def leave(self, ticket: int) -> None:
+        """``ticket`` has passed, or never will (safe to say twice)."""
+        with self._cond:
+            if ticket >= self._serving:
+                self._left.add(ticket)
+            while self._serving in self._left:
+                self._left.remove(self._serving)
+                self._serving += 1
+            self._cond.notify_all()
+
+
 class OrderedLock:
     """Drop-in ``threading.Lock`` with hierarchy/order instrumentation.
 
@@ -105,15 +155,20 @@ class OrderedLock:
     ``host_span``; 0 when the lock is free). Only a lock whose wait is
     a term of a request's latency sets it — the image pipelines'
     dispatch lock — so no other acquisition pays the clock reads.
+    ``in_turn``: a thread that holds a ticket (:class:`Turns`) passes in
+    its turn, the wait for it being part of the wait for the lock; a
+    thread without one passes as at any lock.
     """
 
-    __slots__ = ("name", "rank", "wait_span", "_inner")
+    __slots__ = ("name", "rank", "wait_span", "in_turn", "_inner")
 
     def __init__(self, name: str, rank: Optional[int] = None,
-                 wait_span: Optional[str] = None) -> None:
+                 wait_span: Optional[str] = None,
+                 in_turn: bool = False) -> None:
         self.name = name
         self.rank = rank
         self.wait_span = wait_span
+        self.in_turn = in_turn
         self._inner = threading.Lock()
 
     def __repr__(self) -> str:
@@ -174,19 +229,28 @@ class OrderedLock:
             # must raise instead of deadlocking the test that seeds it
             self._check(_held())
         if self.wait_span is None:
-            acquired = self._inner.acquire(blocking, timeout)
+            acquired = self._acquire(blocking, timeout)
         else:
             # lazy import: utils.profiling pulls in jax
             from cassmantle_tpu.utils.profiling import host_span
 
             with host_span(self.wait_span):
-                acquired = self._inner.acquire(blocking, timeout)
+                acquired = self._acquire(blocking, timeout)
         if acquired:
             _held().append(self)
         return acquired
 
+    def _acquire(self, blocking: bool, timeout: float) -> bool:
+        turn = getattr(_tls, "turn", None) if self.in_turn else None
+        if turn is not None and blocking and timeout < 0:
+            turn[0]._wait(turn[1])
+        return self._inner.acquire(blocking, timeout)
+
     def release(self) -> None:
         self._inner.release()
+        turn = getattr(_tls, "turn", None) if self.in_turn else None
+        if turn is not None:
+            turn[0].leave(turn[1])
         held = _held()
         for i in range(len(held) - 1, -1, -1):
             if held[i] is self:

@@ -178,6 +178,26 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.slow)
 
 
+@pytest.fixture
+def decode_dispatches(monkeypatch):
+    """The ``greedy_decode`` dispatches ``PromptGenerator`` makes while
+    the test runs: (rows, prompt bucket) of the program and the rows'
+    position offsets, one entry a dispatch."""
+    import numpy as np
+
+    from cassmantle_tpu.serving import pipeline
+
+    seen, inner = [], pipeline.greedy_decode
+
+    def recording(pair, params, ids, *args, **kwargs):
+        seen.append((tuple(ids.shape),
+                     np.asarray(kwargs["position_offset"]).tolist()))
+        return inner(pair, params, ids, *args, **kwargs)
+
+    monkeypatch.setattr(pipeline, "greedy_decode", recording)
+    return seen
+
+
 @pytest.fixture(autouse=True)
 def _lock_sentinel():
     """Arm the OrderedLock deadlock sentinel (utils/locks.py) in raising

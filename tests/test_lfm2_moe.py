@@ -126,6 +126,29 @@ def test_a_row_does_not_depend_on_its_company(lm):
     assert np.abs(swapped[0] - together[0]).max() < tol
 
 
+def test_a_row_in_a_wider_buckets_program_decodes_at_its_own_positions(lm):
+    """Rows of bucket 16 in the program of a batch whose widest row is of
+    bucket 32: each row's logits are the reference's with its generated
+    tokens at ``own bucket + i``, and its solo decode's in its own
+    bucket's program (the masked slots between differ, nothing else)."""
+    model, params, sizes = lm
+    tol = {"float32": 1e-4, "bfloat16": 0.04}[sizes["dtype"]]
+    wide = np.arange(150, 170)
+    prompts, own = PROMPTS[:2] + [wide], [16, 16, 32]
+    mixed, _ = decode_through_cache(model, params, prompts, GENERATED, 32,
+                                    own=own)
+    for r, prompt in enumerate(prompts):
+        want = reference_of_row(params, sizes, prompt, GENERATED[r], own[r])
+        assert error(mixed[r], want, sizes["dtype"]) \
+            < TOLERANCE[sizes["dtype"]], r
+        alone, _ = decode_through_cache(model, params, [prompt],
+                                        GENERATED[r:r + 1], own[r])
+        assert np.abs(alone[0] - mixed[r]).max() < tol, r
+    # the slot's position in the row's place reads otherwise
+    slots, _ = decode_through_cache(model, params, prompts, GENERATED, 32)
+    assert np.abs(slots[0] - mixed[0]).max() > 10 * tol
+
+
 def test_pads_change_no_window(lm):
     """A convolution layer's window after prefill is the row's own last
     two gated inputs at its ``prompt_len``: the same in a wider bucket
@@ -282,10 +305,15 @@ def test_prompt_generator_serves_the_family_and_publishes_its_routing(
     assert 0 < delta["moe.experts_touched"] <= delta["moe.assignments_held"]
 
 
-def test_batched_rows_decode_as_they_would_alone(generator):
+def test_batched_rows_decode_as_they_would_alone(generator,
+                                                 decode_dispatches):
+    """Rows of prompt buckets 32, 32 and 64 are ONE dispatch, the widest
+    row's program, and each decodes the tokens of its solo decode in its
+    own bucket's program."""
     texts = ["The quiet harbor at dawn", "Salt wind",
              "Clockwork birds over the old city walls"]
     together, _ = generator.decode_ids_batch(texts)
+    assert decode_dispatches == [((4, 64), [-32, -32, 0, 0])]
     for i, text in enumerate(texts):
         alone, _ = generator.decode_ids_batch([text])
         np.testing.assert_array_equal(np.asarray(alone[0]),
@@ -303,7 +331,8 @@ def test_a_decode_program_counts_its_expert_layers_by_path(generator):
         jax.ShapeDtypeStruct((3, 24), jnp.int32),
         jax.ShapeDtypeStruct((3,), jnp.int32), jax.random.PRNGKey(0), 5,
         257, 0.0, 40, row_mask=jax.ShapeDtypeStruct((3,), jnp.bool_),
-        cache_stats=cache_stats)
+        cache_stats=cache_stats,
+        position_offset=jax.ShapeDtypeStruct((3,), jnp.int32))
     after = dispatch_counts()
     assert {k: after[k] - before.get(k, 0) for k in after} == {
         "dense": 4, "walk_xla": 4, **{k: 0 for k in after
@@ -312,7 +341,8 @@ def test_a_decode_program_counts_its_expert_layers_by_path(generator):
     tokens, _, stats = greedy_decode(
         make_apply_pair(generator.model), generator.params, ids,
         jnp.asarray([5, 1]), jax.random.PRNGKey(0), 4, 257, 0.0, 40,
-        row_mask=jnp.asarray([True, False]), cache_stats=cache_stats)
+        row_mask=jnp.asarray([True, False]), cache_stats=cache_stats,
+        position_offset=jnp.zeros((2,), jnp.int32))
     assert tokens.shape == (2, 4)
     assert int(stats["assignments"]) == (5 + 4) * 4 * 2
 

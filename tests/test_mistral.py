@@ -132,6 +132,7 @@ def test_cached_decode_matches_forward(model_and_params):
         idx = jnp.int32(p + i)
         valid = positions <= idx
         last, cache = model.apply(params, tok, idx, cache, valid,
+                                  jnp.full((1, 1), idx),
                                   method=MistralLM.decode_step)
         full = model.apply(params, seq)
         np.testing.assert_allclose(
@@ -177,7 +178,8 @@ def test_greedy_decode_integration(model_and_params):
     plen = jnp.asarray([8, 4], dtype=jnp.int32)
     tokens, gen_len = greedy_decode(
         make_apply_pair(model), params, ids, plen,
-        jax.random.PRNGKey(0), 6, 0
+        jax.random.PRNGKey(0), 6, 0,
+        position_offset=jnp.zeros((2,), jnp.int32),
     )
     assert tokens.shape == (2, 6)
     assert (np.asarray(gen_len) <= 6).all()
@@ -245,7 +247,7 @@ def test_tp_sharding_rules_cover_mistral(model_and_params):
     assert all(s == P("tp", None) for s in get("attn']['out']['kernel"))
 
 
-def test_prompt_generator_mistral_family(tmp_path):
+def test_prompt_generator_mistral_family(tmp_path, decode_dispatches):
     """PromptGenerator serves the Mistral family end to end (byte
     tokenizer fallback, random weights): text comes back non-empty."""
     import dataclasses as dc
@@ -263,3 +265,9 @@ def test_prompt_generator_mistral_family(tmp_path):
     assert isinstance(gen.model, cls_check)
     text = gen.generate("An old ship left the harbor", max_new_tokens=4)
     assert isinstance(text, str) and len(text) > 0
+    # its sliding window counts cache slots: a batch of two prompt
+    # buckets stays two dispatches, each row in its own bucket's program
+    decode_dispatches.clear()
+    gen.decode_ids_batch(["An old ship", "An old ship left the harbor "
+                          "under a sky of slate and rust"], max_new_tokens=2)
+    assert decode_dispatches == [((1, 32), [0]), ((1, 61), [0])]

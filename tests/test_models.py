@@ -124,7 +124,7 @@ def test_gpt2_kv_cache_matches_full_forward(tiny):
         jnp.arange(max_len)[None, :] == 4
     )
     step_logits, cache = model.apply(
-        params, next_tok, jnp.int32(4), cache, valid,
+        params, next_tok, jnp.int32(4), cache, valid, jnp.full((1, 1), 4),
         method=GPT2LM.decode_step,
     )
     ext = jnp.concatenate([ids[:, :4], next_tok[:, None]], axis=1)
@@ -132,6 +132,43 @@ def test_gpt2_kv_cache_matches_full_forward(tiny):
     np.testing.assert_allclose(
         step_logits, full_ext[:, 4], rtol=2e-4, atol=2e-4
     )
+
+
+def test_gpt2_row_in_a_wider_buckets_program_sits_at_its_own_positions(tiny):
+    """A row of prompt bucket 6 decoded in the program of bucket 10 (its
+    batch's widest row's): written to cache slots ``10 + i``, at position
+    ``6 + i``. Its logits are the plain forward's over its own tokens at
+    those positions and its solo decode's in bucket 6; the slot's
+    position in the row's place (what a mixed batch read before
+    ``greedy_decode`` took offsets) reads otherwise."""
+    model = GPT2LM(tiny.gpt2)
+    prompt = jnp.array([[3, 7, 11, 2]], dtype=jnp.int32)
+    generated = jnp.array([[5, 9, 1]], dtype=jnp.int32)
+    plen = jnp.array([4], dtype=jnp.int32)
+    params = init_params(model, 0, prompt)
+
+    def through_the_cache(bucket: int, own: int):
+        ids = jnp.pad(prompt, ((0, 0), (0, bucket - 4)))
+        max_len = bucket + 3
+        logits, cache = model.apply(params, ids, plen, max_len,
+                                    method=GPT2LM.prefill)
+        out, slots = [logits], jnp.arange(max_len)[None, :]
+        for i in range(2):
+            valid = (slots < 4) | ((slots >= bucket) & (slots <= bucket + i))
+            logits, cache = model.apply(
+                params, generated[:, i], jnp.int32(bucket + i), cache, valid,
+                jnp.full((1, 1), own + i), method=GPT2LM.decode_step)
+            out.append(logits)
+        return np.stack([np.asarray(x[0]) for x in out])
+
+    seq = jnp.concatenate([prompt, generated[:, :2]], axis=1)
+    positions = jnp.array([[0, 1, 2, 3, 6, 7]])
+    want = np.asarray(model.apply(params, seq, None, positions))[0, 3:]
+    mixed = through_the_cache(10, own=6)
+    np.testing.assert_allclose(mixed, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(mixed, through_the_cache(6, own=6),
+                               rtol=2e-4, atol=2e-4)
+    assert np.abs(through_the_cache(10, own=10)[1:] - want[1:]).max() > 0.01
 
 
 def test_minilm_embeddings(tiny):

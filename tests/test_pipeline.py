@@ -106,12 +106,12 @@ def test_prompt_generator_decodes(promptgen):
 
 
 def test_batched_decode_matches_single(promptgen):
-    """decode_ids_batch(N texts) row-for-row equals N single decodes:
-    rows group by each prompt's OWN bucket (a short prompt co-batched
-    with a long one must not decode at the long bucket's cache
-    positions — that would make round text depend on which requests
-    happened to batch together). The third text is long enough to land
-    in a different prompt bucket than the first two."""
+    """decode_ids_batch(N texts) row-for-row equals N single decodes: a
+    short prompt co-batched with a long one runs in the long one's
+    program but at its OWN bucket's positions (decoding at the long
+    bucket's would make round text depend on which requests happened to
+    batch together). The third text is long enough to land in a
+    different prompt bucket than the first two."""
     texts = ["the storm rolled", "a quiet harbor town",
              "the last train left the station long after midnight, "
              "its lanterns swinging over the empty platform boards"]
@@ -122,6 +122,25 @@ def test_batched_decode_matches_single(promptgen):
         np.testing.assert_array_equal(np.asarray(tok_b[i]),
                                       np.asarray(tok_1[0]))
         assert int(len_b[i]) == int(len_1[0])
+
+
+def test_a_batch_of_mixed_prompt_buckets_is_one_dispatch(
+        promptgen, decode_dispatches):
+    """One program a batch, its widest row's, padded to the batch bucket
+    (the tiny model's 64 positions leave 59 for a prompt before 4 new
+    tokens, the widest bucket here); each row carries its own bucket less
+    the program's as its position offset, and a batch of one bucket
+    carries zeros: the same operand, so the same compiled program."""
+    texts = ["the storm rolled", "a quiet harbor town by the grey sea",
+             "the last train left the station long after midnight, "
+             "its lanterns swinging over the empty platform boards"]
+    promptgen.decode_ids_batch(texts, max_new_tokens=4)
+    promptgen.decode_ids_batch(texts[:2] * 2, max_new_tokens=4)
+    promptgen.decode_ids_batch(texts[:1] * 2, max_new_tokens=4)
+    assert decode_dispatches == [
+        ((4, 59), [32 - 59, 0, 0, 0]),
+        ((4, 59), [32 - 59, 0, 32 - 59, 0]),
+        ((2, 32), [0, 0])]
 
 
 def test_generate_batch_matches_single(promptgen):

@@ -77,7 +77,8 @@ def lm_decode(monkeypatch):
     return greedy_decode.lower(
         (gen._prefill, gen._step), gen.params,
         jnp.zeros((1, 32), jnp.int32), jnp.ones((1,), jnp.int32),
-        jax.random.PRNGKey(0), 8, 255, 0.0, 40)
+        jax.random.PRNGKey(0), 8, 255, 0.0, 40,
+        position_offset=jnp.zeros((1,), jnp.int32))
 
 
 def scorer_encode(monkeypatch):

@@ -46,10 +46,10 @@ async def test_respects_max_batch():
 
 @pytest.mark.asyncio
 async def test_a_queue_that_does_not_wait_hands_over_one_item_a_dispatch():
-    """``max_delay_ms`` 0 closes the coalescing window before it opens:
-    ten requests pending at once are ten dispatches of one, in order
-    (what ``lfm2_game``'s file sets for the prompt queue, so that every
-    window of its cell does the same work)."""
+    """With no hold signal ``max_delay_ms`` 0 closes the coalescing window
+    before it opens: ten requests pending at once are ten dispatches of
+    one, in order (``lfm2_game``'s file sets it for the prompt queue,
+    whose batches since PR 35 form under the hold below instead)."""
     batches = []
 
     def handler(items):
@@ -61,6 +61,201 @@ async def test_a_queue_that_does_not_wait_hands_over_one_item_a_dispatch():
     assert results == list(range(10))
     assert batches == [[i] for i in range(10)]
     await q.stop()
+
+
+# -- late binding: the batch stays open while the device has other work ----
+
+
+class Device:
+    """What a queue's ``hold_while`` reads in these tests, and who tells
+    the queue when it changes."""
+
+    def __init__(self, busy: bool) -> None:
+        self.busy = busy
+        self.queue = None
+
+    def __call__(self) -> bool:
+        return self.busy
+
+    def set(self, busy: bool) -> None:
+        self.busy = busy
+        self.queue.recheck_hold()
+
+
+def held_queue(busy: bool, batches: list, **kwargs):
+    device = Device(busy)
+
+    def handler(items):
+        batches.append(list(items))
+        return items
+
+    kwargs.setdefault("max_delay_ms", 0)
+    device.queue = BatchingQueue(handler, hold_while=device, **kwargs)
+    return device, device.queue
+
+
+def series(name: str) -> dict:
+    """``reason -> value`` of one counter, or ``(sum, count)`` of one
+    histogram, as the registry holds them now."""
+    from cassmantle_tpu.utils.logging import metrics
+
+    state = metrics.dump_state()
+    found = {dict(labels).get("reason"): value
+             for n, labels, value in state["counters"] if n == name}
+    for n, _labels, _bounds, _counts, total, count in state["hists"]:
+        if n == name:
+            found = (total, count)
+    return found
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("delay_ms", [0, 20], ids=["no_window", "window"])
+async def test_a_busy_device_holds_the_batch_open_for_later_arrivals(
+        delay_ms):
+    """Items that arrive one by one while the device has other work ride
+    ONE dispatch, bound when the device is free: also under a coalescing
+    window of 0, which without the signal forms no batch at all."""
+    batches = []
+    device, q = held_queue(True, batches, max_batch=8, name="hold_a",
+                           max_delay_ms=delay_ms)
+    futs = []
+    for i in range(3):
+        futs.append(asyncio.ensure_future(q.submit(i)))
+        await asyncio.sleep(0.03)       # past the window each time
+    assert batches == [] and not any(f.done() for f in futs)
+    device.set(False)
+    assert await asyncio.gather(*futs) == [0, 1, 2]
+    assert batches == [[0, 1, 2]]
+    await q.stop()
+
+
+@pytest.mark.asyncio
+async def test_the_release_is_an_event_and_takes_what_came_with_it():
+    """The news that the device is free reaches the collector within a
+    turn of the loop, not at a poll; an item submitted in the same turn
+    as the news (the room whose image just returned asks for its next
+    text at once) still rides the batch."""
+    batches = []
+    device, q = held_queue(True, batches, max_batch=8, name="hold_b")
+    first = asyncio.ensure_future(q.submit("held"))
+    await asyncio.sleep(0.02)
+    loop = asyncio.get_running_loop()
+
+    async def room():
+        device.set(False)               # its image is back, and in the
+        return await q.submit("with the news")  # same turn it asks again
+
+    t0 = loop.time()
+    assert await asyncio.gather(first, room()) == ["held", "with the news"]
+    assert loop.time() - t0 < 0.05      # a thread hop and back, no timer
+    assert batches == [["held", "with the news"]]
+    await q.stop()
+
+
+@pytest.mark.asyncio
+async def test_a_full_batch_is_dispatched_though_the_device_is_busy():
+    batches = []
+    device, q = held_queue(True, batches, max_batch=2, name="hold_c")
+    before = series("hold_c.release")
+    assert await asyncio.gather(q.submit(1), q.submit(2)) == [1, 2]
+    assert batches == [[1, 2]] and device.busy
+    after = series("hold_c.release")
+    assert after["full"] - before.get("full", 0) == 1
+    await q.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("signal", ["device_free", "none"])
+async def test_nothing_is_held_with_the_device_free_or_with_no_signal(
+        signal):
+    """An idle device means no hold: a lone item is dispatched as fast as
+    by a queue without the argument (the score queue), and a window of 0
+    still hands over one item a dispatch."""
+    batches = []
+    if signal == "none":
+        q = BatchingQueue(lambda items: batches.append(list(items)) or items,
+                          max_batch=4, max_delay_ms=0, name="hold_d")
+        assert q._hold_while is None
+    else:
+        _, q = held_queue(False, batches, max_batch=4, name="hold_d")
+    loop = asyncio.get_running_loop()
+    t0 = loop.time()
+    assert await q.submit("lone") == "lone"
+    assert loop.time() - t0 < 0.05
+    assert await asyncio.gather(*(q.submit(i) for i in range(3))) \
+        == [0, 1, 2]
+    assert batches == [["lone"], [0], [1], [2]]
+    await q.stop()
+
+
+@pytest.mark.asyncio
+async def test_a_held_item_expires_at_its_deadline():
+    """The hold is no exemption: a held item fails with DeadlineExceeded
+    on time, and the batch that goes when the device is free carries only
+    the items somebody still waits for."""
+    batches = []
+    device, q = held_queue(True, batches, max_batch=8, name="hold_e")
+    impatient = asyncio.ensure_future(q.submit("late", deadline_s=0.05))
+    patient = asyncio.ensure_future(q.submit("kept"))
+    with pytest.raises(DeadlineExceeded):
+        await impatient
+    assert batches == [] and not patient.done()
+    device.set(False)
+    assert await patient == "kept"
+    assert batches == [["kept"]]
+    await q.stop()
+
+
+@pytest.mark.asyncio
+async def test_stop_fails_held_futures():
+    device, q = held_queue(True, [], max_batch=8, name="hold_f")
+    futs = [asyncio.ensure_future(q.submit(i)) for i in range(2)]
+    await asyncio.sleep(0.02)           # both popped, both held
+    assert q.depth() == 0
+    await q.stop()
+    for fut in futs:
+        with pytest.raises(QueueStopped):
+            await fut
+
+
+@pytest.mark.asyncio
+async def test_the_hold_is_counted_and_the_limiter_is_not_told_of_it():
+    """``<name>.hold_s`` (first item bound -> dispatch) and
+    ``<name>.release{reason}`` say how often the mechanism engaged; the
+    adaptive limiter's wait signal leaves the held time out (a wait the
+    queue chose is not load: a 1.2 s hold behind four images would walk
+    ``<name>.admit_limit`` to its floor), while ``<name>.queue_wait_s``
+    keeps the whole wait a member felt."""
+    seen = []
+
+    class Limiter:
+        def admit(self, depth, priority, deadline_s):
+            return None
+
+        def observe_batch(self, wait_s, service_s, n):
+            seen.append((wait_s, n))
+
+    batches = []
+    device, q = held_queue(True, batches, max_batch=8, name="hold_g",
+                           admission=Limiter())
+    before = (series("hold_g.release"), series("hold_g.hold_s") or (0.0, 0),
+              series("hold_g.queue_wait_s") or (0.0, 0))
+    fut = asyncio.ensure_future(q.submit("x"))
+    await asyncio.sleep(0.1)
+    late = asyncio.ensure_future(q.submit("joined the hold"))
+    await asyncio.sleep(0.05)
+    device.set(False)
+    await asyncio.gather(fut, late)
+    await q.submit("y")                 # the device free: no hold
+    await q.stop()
+    release, (total, count) = series("hold_g.release"), series("hold_g.hold_s")
+    assert release["device_free"] - before[0].get("device_free", 0) == 1
+    assert release["timer"] - before[0].get("timer", 0) == 1
+    assert count - before[1][1] == 2 and total - before[1][0] >= 0.15
+    waited, members = series("hold_g.queue_wait_s")
+    assert members - before[2][1] == 3 and waited - before[2][0] >= 0.2
+    assert seen[0][0] < 0.05 and seen[0][1] == 2, seen
+    assert seen[1][0] < 0.05, seen
 
 
 @pytest.mark.asyncio

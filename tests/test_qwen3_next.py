@@ -63,10 +63,15 @@ def lm(request):
     return model, params, sizes_of(cfg)
 
 
-def decode_through_cache(model, params, prompts, generated, bucket):
+def decode_through_cache(model, params, prompts, generated, bucket,
+                         own=None):
     """Logits (rows, n_gen, V) that predict each generated token: prefill
-    of the right-padded bucket, then cached steps at ``bucket + i``."""
+    of the right-padded bucket, then cached steps into slot ``bucket + i``
+    at position ``own[row] + i`` (``own``: each row's own bucket where the
+    program's is wider, as ``decode_ids_batch`` runs a mixed batch)."""
     rows, n_gen = len(prompts), generated.shape[1]
+    own = np.full((rows, 1), bucket) if own is None \
+        else np.asarray(own)[:, None]
     ids = np.full((rows, bucket), 258, np.int32)
     lens = np.asarray([len(p) for p in prompts], np.int32)
     for r, prompt in enumerate(prompts):
@@ -82,7 +87,8 @@ def decode_through_cache(model, params, prompts, generated, bucket):
         valid = (positions < lens[:, None]) | (
             (positions >= bucket) & (positions <= bucket + i))
         logits, cache = step(params, jnp.asarray(generated[:, i]),
-                             jnp.int32(bucket + i), cache, jnp.asarray(valid))
+                             jnp.int32(bucket + i), cache, jnp.asarray(valid),
+                             jnp.asarray(own + i))
         out.append(logits)
     return np.stack([np.asarray(x) for x in out], axis=1), cache
 
@@ -156,6 +162,56 @@ def test_a_row_does_not_depend_on_its_company(lm):
     other = [PROMPTS[0], np.arange(200, 215)]
     swapped, _ = decode_through_cache(model, params, other, GENERATED[:2], 16)
     assert np.abs(swapped[0] - together[0]).max() < tol
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4])
+def test_a_decode_steps_rows_reach_the_matrix_at_float32s_precision(rows):
+    """``stored_dot``: the rows of a decode step are multiplied with a
+    bfloat16 matrix at float32's precision, one row as a float32 product
+    and more as two bfloat16 operands, the float32 rows' product to
+    2**-16 either way and under ``jit`` too (a compiler that drops excess
+    rounding must not drop the split); prefill rounds its rows to the
+    stored type as written."""
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, 1, 256))
+    kernel = jax.random.normal(jax.random.PRNGKey(7), (256, 128)
+                               ).astype(jnp.bfloat16)
+    exact = np.asarray(jnp.dot(x, kernel.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    scale = np.abs(exact).max()
+    step = np.asarray(jax.jit(moe.stored_dot, static_argnums=2)(
+        x, kernel, True))
+    rounded = np.asarray(jax.jit(moe.stored_dot, static_argnums=2)(
+        x, kernel, False))
+    assert step.shape == exact.shape == rounded.shape
+    assert np.abs(rounded - exact).max() > 1e-3 * scale
+    assert np.abs(step - exact).max() < 2e-5 * scale
+    # a float32 matrix needs no split
+    np.testing.assert_allclose(
+        np.asarray(moe.stored_dot(x, kernel.astype(jnp.float32), True)),
+        exact, atol=1e-4 * scale)
+
+
+def test_a_row_in_a_wider_buckets_program_decodes_at_its_own_positions(lm):
+    """Rows of bucket 16 in the program of a batch whose widest row is of
+    bucket 32: each row's logits are the reference's with its generated
+    tokens at ``own bucket + i``, and its solo decode's in its own
+    bucket's program (the masked slots between differ, nothing else)."""
+    model, params, sizes = lm
+    tol = {"float32": 1e-4, "bfloat16": 0.04}[sizes["dtype"]]
+    wide = np.arange(150, 170)
+    prompts, own = PROMPTS[:2] + [wide], [16, 16, 32]
+    mixed, _ = decode_through_cache(model, params, prompts, GENERATED, 32,
+                                    own=own)
+    for r, prompt in enumerate(prompts):
+        want = reference_of_row(params, sizes, prompt, GENERATED[r], own[r])
+        assert error(mixed[r], want, sizes["dtype"]) \
+            < TOLERANCE[sizes["dtype"]], r
+        alone, _ = decode_through_cache(model, params, [prompt],
+                                        GENERATED[r:r + 1], own[r])
+        assert np.abs(alone[0] - mixed[r]).max() < tol, r
+    # the slot's position in the row's place reads otherwise
+    slots, _ = decode_through_cache(model, params, prompts, GENERATED, 32)
+    assert np.abs(slots[0] - mixed[0]).max() > 10 * tol
 
 
 def test_pads_change_no_state(lm):
@@ -547,10 +603,15 @@ def test_prompt_generator_serves_the_family_and_publishes_its_routing(
     assert 0 < delta["moe.experts_touched"] <= delta["moe.assignments_held"]
 
 
-def test_batched_rows_decode_as_they_would_alone(generator):
+def test_batched_rows_decode_as_they_would_alone(generator,
+                                                 decode_dispatches):
+    """Rows of prompt buckets 32, 32 and 64 are ONE dispatch, the widest
+    row's program, and each decodes the tokens of its solo decode in its
+    own bucket's program."""
     texts = ["The quiet harbor at dawn", "Salt wind",
              "Clockwork birds over the old city walls"]
     together, _ = generator.decode_ids_batch(texts)
+    assert decode_dispatches == [((4, 64), [-32, -32, 0, 0])]
     for i, text in enumerate(texts):
         alone, _ = generator.decode_ids_batch([text])
         np.testing.assert_array_equal(np.asarray(alone[0]),
@@ -564,7 +625,7 @@ def test_greedy_decode_hands_back_the_cache_counters(generator):
     tokens, _, stats = greedy_decode(
         make_apply_pair(model), params, ids, lens, jax.random.PRNGKey(0), 4,
         257, 0.0, 40, row_mask=jnp.asarray([True, False]),
-        cache_stats=cache_stats)
+        cache_stats=cache_stats, position_offset=jnp.zeros((2,), jnp.int32))
     assert tokens.shape == (2, 4)
     assert int(stats["assignments"]) == (5 + 4) * 4 * 2
 
@@ -580,7 +641,8 @@ def test_a_decode_program_counts_its_expert_layers_by_path(generator):
         jax.ShapeDtypeStruct((3, 24), jnp.int32),
         jax.ShapeDtypeStruct((3,), jnp.int32), jax.random.PRNGKey(0), 5,
         257, 0.0, 40, row_mask=jax.ShapeDtypeStruct((3,), jnp.bool_),
-        cache_stats=cache_stats)
+        cache_stats=cache_stats,
+        position_offset=jax.ShapeDtypeStruct((3,), jnp.int32))
     after = dispatch_counts()
     assert {k: after[k] - before.get(k, 0) for k in after} == {
         "dense": 4, "walk_xla": 4, **{k: 0 for k in after

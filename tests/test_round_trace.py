@@ -172,3 +172,82 @@ def test_only_a_lock_that_names_its_wait_is_timed():
         pass
     assert hist_count("pipeline.image_lock_wait_s") == count
     assert metrics.hist_totals("test.plain_s") is None
+
+
+# -- threads pass an in-turn lock in the order their tickets were taken -----
+
+
+def test_threads_pass_an_in_turn_lock_by_ticket_not_by_arrival():
+    """Four workers take their tickets in order 0..3 and reach the lock
+    in the reverse order (the later the ticket, the shorter its way
+    there); they pass by ticket. A ticket that never reaches the lock
+    (its work failed before) holds nobody up, a thread with no ticket
+    passes as at any lock, and a lock that is not ``in_turn`` knows no
+    tickets."""
+    import time
+
+    from cassmantle_tpu.utils.locks import OrderedLock, Turns
+
+    turns, passed = Turns(), []
+    lock = OrderedLock("test.in_turn", in_turn=True)
+    plain = OrderedLock("test.plain")
+    tickets = [turns.take() for _ in range(5)]
+
+    def work(ticket):
+        with turns.holding(ticket):
+            time.sleep(0.02 * (4 - ticket))     # the last ticket is first
+            if ticket == 2:
+                return                          # never reaches the lock
+            with plain:                         # no order here
+                pass
+            with lock:
+                passed.append(ticket)
+                time.sleep(0.01)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in tickets]
+    for thread in threads:
+        thread.start()
+    with lock:                                  # no ticket: no waiting
+        passed.append("no ticket")
+    for thread in threads:
+        thread.join(timeout=5)
+    assert not any(thread.is_alive() for thread in threads)
+    assert passed == ["no ticket", 0, 1, 3, 4]
+    turns.leave(4)                              # said twice: harmless
+    late = turns.take()
+    with turns.holding(late), lock:
+        passed.append(late)
+    assert passed[-1] == 5
+
+
+def test_rounds_render_in_the_order_generate_was_called(backend,
+                                                        monkeypatch):
+    """Four rounds handed to the backend in one turn of the loop (the
+    prompt queue delivers a batch's texts so) pass the image dispatch
+    lock in the order of the calls, though the later the call the sooner
+    its thread is there: the order of the rooms in one orbit is then
+    their order in the next."""
+    import time
+
+    import numpy as np
+
+    lock, rendered = backend.t2i._dispatch_lock, []
+
+    def generate(prompts, seed=0, deadline_s=None):
+        call = int(prompts[0].rsplit(" ", 1)[1])
+        time.sleep(0.03 * (3 - call))
+        with lock:
+            rendered.append(call)
+            time.sleep(0.01)
+        return [np.zeros((8, 8, 3), np.uint8)]
+
+    monkeypatch.setattr(backend.t2i, "generate", generate)
+
+    async def rounds():
+        await asyncio.gather(*(
+            backend.generate(f"title {i}", True,
+                             text=f"the keeper lit the lamp at dusk {i}")
+            for i in range(4)))
+
+    asyncio.run(rounds())
+    assert rendered == [0, 1, 2, 3]

@@ -25,14 +25,22 @@ from cassmantle_tpu.config import (
 from cassmantle_tpu.config import test_config as _tiny_config
 from cassmantle_tpu.models.gpt2 import GPT2LM
 from cassmantle_tpu.models.mistral import MistralLM
+from cassmantle_tpu.ops import decode
 from cassmantle_tpu.ops.decode import (
     ModelDraft,
     NgramDraft,
-    greedy_decode,
     make_apply_fns,
     speculative_decode,
 )
 from cassmantle_tpu.serving.pipeline import PromptGenerator
+
+
+def greedy_decode(pair, params, ids, lens, *rest):
+    """``ops.decode.greedy_decode``, every row in its own bucket's
+    program (the speculative path serves no other)."""
+    return decode.greedy_decode(
+        pair, params, ids, lens, *rest,
+        position_offset=jnp.zeros(ids.shape[:1], jnp.int32))
 
 
 @pytest.fixture(scope="module")
@@ -82,7 +90,8 @@ def test_decode_chunk_matches_step_sequence_gpt2(gpt2_lm):
     for j in range(5):
         idx = jnp.int32(8 + j)
         valid = prompt_valid | ((positions >= 8) & (positions <= idx))
-        logits, cache = step(params, toks[:, j], idx, cache, valid)
+        logits, cache = step(params, toks[:, j], idx, cache, valid,
+                             jnp.full((2, 1), idx))
         stepped.append(logits)
     stepped = jnp.stack(stepped, axis=1)               # (B, 5, V)
 
@@ -122,7 +131,8 @@ def test_decode_chunk_matches_step_sequence_mistral():
     for j in range(s):
         idx = jnp.int32(p + j)
         valid = prompt_valid | ((positions >= p) & (positions <= idx))
-        logits, cache = step(params, toks[:, j], idx, cache, valid)
+        logits, cache = step(params, toks[:, j], idx, cache, valid,
+                             jnp.full((2, 1), idx))
         stepped.append(logits)
     stepped = jnp.stack(stepped, axis=1)
 

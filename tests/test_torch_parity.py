@@ -199,7 +199,8 @@ def _our_decode(model, params, ids_np, prompt_len, max_new, vocab):
     toks, n = greedy_decode(
         make_apply_pair(model), params, jnp.asarray(ids_np),
         jnp.asarray([prompt_len], jnp.int32), jax.random.PRNGKey(0),
-        max_new, vocab)  # vocab = unreachable eos -> no early stop
+        max_new, vocab,  # vocab = unreachable eos -> no early stop
+        position_offset=jnp.zeros((1,), jnp.int32))
     return np.asarray(toks[0])
 
 

@@ -240,6 +240,20 @@ def _prefetches_in_the_decode_step(text: str) -> int:
     return max(counts.values())[1]
 
 
+def _rows_rounded_in_the_decode_step(text: str, rows: int) -> set:
+    """The scopes, below their layer, of the decode step's instructions
+    whose result is a ``bf16[rows, N]``: the places where a step's
+    activation is rounded to the stored type."""
+    found = set()
+    for line in text.splitlines():
+        hit = re.search(r'= bf16\[%d,\d+\]\S* .*op_name="([^"]*)"' % rows,
+                        line)
+        if hit and "lm_decode_step" in hit.group(1):
+            found.add(re.sub(r".*decode_step/(layer_\d+/)?", "",
+                             hit.group(1)))
+    return found
+
+
 def _sparse_cut(name: str):
     """(model, bytes of its weights in bfloat16 (low, high), the shapes of
     an expert's two matrices in the compiled text, ``cache_stats``, the
@@ -247,7 +261,9 @@ def _sparse_cut(name: str):
     most its temporaries may take) of a served cut of a sparse prompt LM.
     ``lfm2_game``'s largest program read 245 MB of temporaries; 537 MB
     more when the embedding's look-up widened the whole tied table to
-    float32 in every decode step (PR 34: a step of 3.15 ms for 1.58)."""
+    float32 in every decode step (PR 34: a step of 3.15 ms for 1.58).
+    ``qwen3next_game``'s read 235 MB, and 427 while its steps of two rows
+    and more did the same to its table (PR 37: 0.78 ms of a 3 ms step)."""
     from cassmantle_tpu import config as configs
     from cassmantle_tpu.models import lfm2_moe, qwen3_next
 
@@ -255,15 +271,16 @@ def _sparse_cut(name: str):
         cfg = configs.qwen3next_game_config().models.qwen3_next
         return (qwen3_next.Qwen3NextLM(cfg), (7.3e9, 7.4e9),
                 ("[2048,1024]", "[512,2048]"), qwen3_next.cache_stats, 100,
-                1.5e9)
+                0.3e9)
     cfg = configs.lfm2_game_config().models.lfm2_moe
     return (lfm2_moe.Lfm2MoeLM(cfg), (10.3e9, 10.4e9),
             ("[2048,3072]", "[1536,2048]"), lfm2_moe.cache_stats, 80, 0.5e9)
 
 
 @pytest.mark.parametrize("cut", ["qwen3next", "lfm2"])
-@pytest.mark.parametrize("rows, bucket", [(4, 64), (1, 32)],
-                         ids=["batch4_bucket64", "batch1_bucket32"])
+@pytest.mark.parametrize("rows, bucket", [(4, 64), (2, 32), (1, 32)],
+                         ids=["batch4_bucket64", "batch2_bucket32",
+                              "batch1_bucket32"])
 def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
         v5e, monkeypatch, rows, bucket, cut):
     """``greedy_decode`` over a sparse prompt LM at its served cut
@@ -278,9 +295,20 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
     pieces within what the compiler gives a kernel. The compiler still
     prefetches the step's other weights into on-chip memory (132 pieces
     a step around the loop; none at all around a kernel that states no
-    cost, which made a dispatch 12 ms slower on the chip, PR 32). (The
-    rule asks ``on_tpu``; a described chip is not attached, so the test
-    answers for it.)"""
+    cost, which made a dispatch 12 ms slower on the chip, PR 32). The
+    rows' position offsets are an operand, as the serving path always
+    hands them over: a batch of mixed prompt buckets runs this very
+    program. And a row decodes in company as alone: at one row the
+    compiler keeps a step's activation in float32 into every projection
+    (it drops the rounding the source wrote), and at two and four rows no
+    projection takes a ``bf16[rows, N]`` activation either
+    (models/moe.py ``stored_dot``; before, the mixers' and the shared
+    expert's did, and no prompt was served the same tokens in a pair as
+    alone). What is rounded to the stored type is what is rounded at one
+    row too: the row the walk kernel multiplies one assignment at a time,
+    the cache's new entries, the embedding's stored row. (The rule asks
+    ``on_tpu``; a described chip is not attached, so the test answers
+    for it.)"""
     from cassmantle_tpu.models import moe
     from cassmantle_tpu.ops import moe_walk
     from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
@@ -302,7 +330,8 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
         make_apply_pair(model), tree, on_chip((rows, bucket), jnp.int32),
         on_chip((rows,), jnp.int32), on_chip((2,), jnp.uint32), 96, 257, 0.0,
         40, row_mask=on_chip((rows,), jnp.bool_),
-        cache_stats=cache_stats).compile()
+        cache_stats=cache_stats,
+        position_offset=on_chip((rows,), jnp.int32)).compile()
     memory = compiled.memory_analysis()
     low, high = weight_bytes
     assert low < memory.argument_size_in_bytes < high
@@ -322,6 +351,11 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
     copies = [line for line in step_experts
               if " copy(" in line and any(m in line for m in matrices)]
     assert not copies, copies[:2]
+    rounded = _rows_rounded_in_the_decode_step(text, rows)
+    assert "moe/convert_element_type" in rounded
+    assert not [scope for scope in rounded if not (
+        scope == "moe/convert_element_type" or scope.startswith("embed/")
+        or scope.endswith("_attn/mixer/convert_element_type"))], rounded
 
 
 @pytest.mark.parametrize("d, f", [(2048, 512), (2048, 1536)],

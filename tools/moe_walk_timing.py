@@ -166,7 +166,9 @@ def dispatches(args, out_path: str) -> int:
                 return jax.block_until_ready(greedy_decode(
                     pair, params, *operands,
                     row_mask=jnp.ones((rows,), bool),
-                    cache_stats=cache_stats))
+                    cache_stats=cache_stats,
+                    # the served program: the offsets are an operand
+                    position_offset=jnp.zeros((rows,), jnp.int32)))
 
             t0 = time.perf_counter()
             _, _, stats = dispatch()
