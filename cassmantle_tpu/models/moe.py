@@ -146,6 +146,22 @@ def stored_dot(x: jax.Array, kernel: jax.Array, step: bool) -> jax.Array:
     return out.reshape(*x.shape[:-1], -1)
 
 
+def walk_operands(local, weight, here, load):
+    """What a step's routing hands the walk, from ``local`` (T, top_k)
+    held ids, ``weight`` (T, top_k), ``here`` (T, top_k) (the assignment
+    landed, from a real row) and ``load`` (held,) (landed assignments an
+    expert): ``experts`` (held,), the ones some row chose first in
+    ascending id, each read once for every row of the step, so a row adds
+    its experts in that order whatever its company; and ``combine`` (T,
+    held), each row's weight for each expert, 0 where it did not choose
+    it. How many were chosen is ``sum(load > 0)``."""
+    t, held = local.shape[0], load.shape[0]
+    combine = jnp.zeros((t, held), jnp.float32).at[
+        jnp.arange(t)[:, None], local].add(jnp.where(here, weight, 0.0))
+    experts = jnp.argsort(load == 0, stable=True)
+    return experts, combine
+
+
 class HeldExperts(nn.Module):
     """Top-k routed SwiGLU experts of which ``experts_held`` live here,
     ids ``[first_expert, first_expert + experts_held)``, plus an optional
@@ -163,21 +179,26 @@ class HeldExperts(nn.Module):
     last). No assignment is dropped: ``dense=True``
     runs every held expert over every token and combines by the routing
     weights (prefill: with hundreds of tokens every expert is touched
-    anyway); ``dense=False`` walks the assignments that landed here, one
-    expert's weights a step (decode: a handful of rows touch a handful
-    of the held experts, and the step is bound by the weights it reads).
-    ``real`` (T,) marks tokens that count: padding is neither computed in
-    the walk nor counted in ``stats`` (``assignments``,
-    ``assignments_held``, ``experts_touched``, ``load`` (experts_held,)).
+    anyway); ``dense=False`` walks the held experts that some row chose,
+    one expert's weights a step, each read once and multiplied against
+    every row, which keeps its own weight for it or 0 (decode: a handful
+    of rows touch a handful of the held experts, and the step is bound by
+    the weights it reads; rows that chose the same expert share its
+    read). ``real`` (T,) marks tokens that count: padding is neither
+    computed in the walk nor counted in ``stats`` (``assignments``,
+    ``assignments_held``, ``experts_touched``, ``load`` (experts_held,),
+    and ``walk_reads_saved``: landed assignments less the experts the walk
+    read, 0 in the dense form).
 
     Which form the walk takes is a rule on what the code can see, and
     nothing sets it: on the TPU, at widths the kernel tiles
-    (``moe_walk_fits``), one Pallas call a layer that copies the landed
-    assignments' matrices back to back while the one before multiplies
+    (``moe_walk_fits``), one Pallas call a layer that copies the chosen
+    experts' matrices back to back while the one before multiplies
     (ops/moe_walk.py, ``walk_kernel``); anywhere else ``_walk``'s
     ``fori_loop`` of dependent products (``walk_xla``), which is also the
     form the kernel is tested against. Counted under
-    ``moe.dispatch{path}`` once a site a trace, with ``dense``. On one
+    ``moe.dispatch{path}`` once a site a trace, with ``dense``. Until the
+    walk went by expert it read once an assignment; on one
     v5e a layer call at 1 / 2 / 4 rows, net of the scan around it, took
     36.6 / 81.6 / 163.3 us as the loop and 23.4 / 43.0 / 88.2 as the
     kernel against a read of 19.1 / 39.0 / 78.7, and a whole batch-1
@@ -249,27 +270,26 @@ class HeldExperts(nn.Module):
                                  jnp.float32).astype(self.dtype)
             down = self.param("down", init, (held_n, f, d),
                               jnp.float32).astype(self.dtype)
+            experts, combine = walk_operands(local, top_p, here, load)
             if dense:
                 metrics.inc("moe.dispatch", labels={"path": "dense"})
-                combine = jnp.zeros((t, held_n), jnp.float32).at[
-                    jnp.arange(t)[:, None], local].add(
-                        jnp.where(here, top_p, 0.0))
+                stats["walk_reads_saved"] = jnp.zeros((), jnp.int32)
                 gu = jnp.einsum("td,edf->tef", xb, gate_up,
                                 preferred_element_type=jnp.float32)
                 h = nn.silu(gu[..., :f]) * gu[..., f:] * combine[..., None]
                 out = jnp.einsum("tef,efd->td", h.astype(self.dtype), down,
                                  preferred_element_type=jnp.float32)
             else:
-                # the assignments that landed here first, each row's in
-                # its own order: a row's sum does not depend on its company
-                order = jnp.argsort(~here.reshape(-1), stable=True)
+                touched = stats["experts_touched"]
+                stats["walk_reads_saved"] = (stats["assignments_held"]
+                                             - touched)
                 kernel = on_tpu() and moe_walk_fits(
                     d, f, jnp.dtype(self.dtype).itemsize)
                 metrics.inc("moe.dispatch", labels={
                     "path": "walk_kernel" if kernel else "walk_xla"})
-                out = (moe_walk if kernel else self._walk)(
-                    xb, gate_up, down, local.reshape(-1), top_p.reshape(-1),
-                    order, jnp.sum(here))
+                walk = (functools.partial(moe_walk, top_k=k) if kernel
+                        else self._walk)
+                out = walk(xb, gate_up, down, experts, combine, touched)
 
         if self.shared_intermediate:
             with jax.named_scope("moe_shared"):
@@ -288,29 +308,25 @@ class HeldExperts(nn.Module):
                     x32, s_gate.astype(jnp.float32), precision=hi))
         return out, stats
 
-    def _walk(self, xb, gate_up, down, expert, weight, order, count):
+    def _walk(self, xb, gate_up, down, experts, combine, count):
         """The walk as XLA runs it, and what ops/moe_walk.py is tested
-        against: one assignment a loop trip in ``order``, ``count``
-        trips; ``expert`` and ``weight`` by slot, slot // top_k the row."""
+        against: one expert a loop trip, ``experts[i]`` for ``i`` below
+        ``count``, multiplied against every row and added into each row
+        times its column of ``combine`` (T, held): 0 adds nothing."""
         f = self.intermediate
-        top_k = expert.shape[0] // xb.shape[0]
 
         def body(i, acc):
-            slot = order[i]
-            row = slot // top_k
-            e = expert[slot]
+            e = experts[i]
             gu = jnp.dot(
-                jax.lax.dynamic_slice_in_dim(xb, row, 1, axis=0),
-                jax.lax.dynamic_index_in_dim(gate_up, e, keepdims=False),
+                xb, jax.lax.dynamic_index_in_dim(gate_up, e, keepdims=False),
                 preferred_element_type=jnp.float32)
             h = nn.silu(gu[:, :f]) * gu[:, f:]
             y = jnp.dot(h.astype(self.dtype),
                         jax.lax.dynamic_index_in_dim(down, e,
                                                      keepdims=False),
                         preferred_element_type=jnp.float32)
-            return jax.lax.dynamic_update_slice_in_dim(
-                acc, jax.lax.dynamic_slice_in_dim(acc, row, 1, axis=0)
-                + weight[slot] * y, row, axis=0)
+            w = jax.lax.dynamic_index_in_dim(combine, e, axis=1)
+            return acc + jnp.where(w != 0.0, w * y, 0.0)
 
         return jax.lax.fori_loop(
             0, count, body,

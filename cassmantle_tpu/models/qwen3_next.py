@@ -272,7 +272,7 @@ class Qwen3NextLayer(nn.Module):
 def zero_stats(cfg: Qwen3NextConfig) -> dict:
     zero = jnp.zeros((), jnp.int32)
     return {"assignments": zero, "assignments_held": zero,
-            "experts_touched": zero,
+            "experts_touched": zero, "walk_reads_saved": zero,
             "load": jnp.zeros((cfg.experts_held,), jnp.int32)}
 
 

@@ -3,50 +3,60 @@ matrices stream through on-chip memory back to back, in pieces.
 
 ``models/moe.py::HeldExperts`` at decode has a handful of rows, each
 routed to ``top_k`` experts of which a few (or all) live on this chip.
-The XLA form (``HeldExperts._walk``) is a ``fori_loop`` whose trip count
-is known only on the device: every trip starts the read of one expert's
-``gate_up`` (D, 2F) and ``down`` (F, D), waits for it, multiplies, and
-only then may the next trip's read start. This kernel is the same walk as
-one call a layer. The matrices stay in HBM; the kernel copies them by
-hand in pieces of whole rows (``walk_plan``: at most ``PIECE_BYTES``
-each) through two rings of slots in on-chip memory, one for ``gate_up``'s
-pieces and one for ``down``'s (``RING_BYTES``). Piece ``c`` of assignment
-``i`` is number ``i * pieces + c`` of its matrix's stream and lands in
-slot ``number % slots``; when a piece has been multiplied, the piece that
-takes its slot next, ``slots`` further on in the stream and so of this
-assignment or of a later one, is started. The sizes of a piece and of a
-ring follow from the widths and not from the expert's size: an expert of
-6.3 MB (D 2048, F 512: 4 + 2 pieces, rings that hold two experts) and one
-of 18.9 MB (D 2048, F 1536: more than the 16 MiB a kernel may have, so it
-passes through its rings in more than one turn) take the same code. The
-reads follow each other without a gap across assignments, a product runs
-under the reads behind it, and only the last piece's product of a call is
-under none. The loop runs ``count`` times, the number of assignments that
-landed: nothing is read when nothing landed, and no byte more than the
-loop reads. ``order``, ``expert``, ``weight`` and ``count`` arrive as
-scalar-prefetch operands, so no gather runs beside the kernel.
+The walk goes over the held experts that some real row chose, each once,
+in ascending id: rows that chose the same expert share one read of its
+matrices. The XLA form (``HeldExperts._walk``) is a ``fori_loop`` whose
+trip count is known only on the device: every trip starts the read of one
+expert's ``gate_up`` (D, 2F) and ``down`` (F, D), waits for it,
+multiplies every row, and only then may the next trip's read start. This
+kernel is the same walk as one call a layer. The matrices stay in HBM;
+the kernel copies them by hand in pieces of whole rows (``walk_plan``: at
+most ``PIECE_BYTES`` each) through two rings of slots in on-chip memory,
+one for ``gate_up``'s pieces and one for ``down``'s (``RING_BYTES``).
+Piece ``c`` of the ``i``-th expert read is number ``i * pieces + c`` of
+its matrix's stream and lands in slot ``number % slots``; when a piece
+has been multiplied, the piece that takes its slot next, ``slots``
+further on in the stream and so of this expert or of a later one, is
+started. The sizes of a piece and of a ring follow from the widths and
+not from the expert's size: an expert of 6.3 MB (D 2048, F 512: 4 + 2
+pieces, rings that hold two experts) and one of 18.9 MB (D 2048, F 1536:
+more than the 16 MiB a kernel may have, so it passes through its rings in
+more than one turn) take the same code. The reads follow each other
+without a gap across experts, a product runs under the reads behind it,
+and only the last piece's product of a call is under none. The loop runs
+``count`` times, the number of distinct experts that some real row chose
+(``experts_touched``): nothing is read when nothing landed, and no byte
+more than the loop reads. ``experts``, the (T, held) table of each row's
+weight for each expert and ``count`` arrive as scalar-prefetch operands,
+so no gather runs beside the kernel.
 
 Arithmetic, as the compiled ``_walk`` has it: operands in the stored
 type, float32 accumulation, the routing weight applied in float32, a
-row's assignments summed in their own order into that row of a (T, D)
-float32 block. ``_walk`` writes ``h`` rounded to the stored type before
-``down``, but its compiled form is a float32 multiply-reduce and the
-compiler drops the rounding (it may keep more precision than asked): on
-the chip the loop agrees with a float32 reference to 1.3e-7 where a
-kernel that rounds ``h`` is 9.3e-4 off (outputs of size 0.66). The kernel
-keeps what the program has served: ``h`` goes through ``down`` as two
-operands of the stored type, its rounding and what the rounding left,
-which is ``h`` to 2**-17; ``lm_logit_gap``'s limit leaves no room for a
-second source of near-tie expert swaps (PERF.md section 2). Every row
-goes through the MXU and the assignment's row is kept: with 1 to 4 rows the unit's
-time is the load of the matrix, whatever the rows, and it hides under the
-next read as the VPU's multiply-reduce does (timed, below).
+row's experts summed in ascending id into that row of a (T, D) float32
+block; a row that did not choose the expert adds an exact 0, so a row's
+sum is the same whatever rows share the call. ``_walk`` writes ``h``
+rounded to the stored type before ``down``, but at one row its compiled
+form is a float32 multiply-reduce and the compiler drops the rounding (it
+may keep more precision than asked): on the chip the loop agrees with a
+float32 reference to 1.3e-7 where a kernel that rounds ``h`` is 9.3e-4
+off (outputs of size 0.66). The kernel keeps what the program has
+served: ``h`` goes through ``down`` as two operands of the stored type,
+its rounding and what the rounding left, which is ``h`` to 2**-17;
+``lm_logit_gap``'s limit leaves no room for a second source of near-tie
+expert swaps (PERF.md section 2). Every row goes through the MXU against
+the expert and keeps its own weight times the product: with 1 to 4 rows
+the unit's time is the load of the matrix, whatever the rows, and it
+hides under the next read as the VPU's multiply-reduce does (timed, below).
 
 On-chip memory: the two rings are 8 + 4 MiB at most (12 MiB at F 512,
 11.5 at F 1536, bfloat16); the call asks for ``VMEM_LIMIT_BYTES``, under
 the 16 MiB the v5e's compiler gives a kernel (it refused 32 MiB in PR
 22). ``moe_walk_fits`` answers from the plan's bytes, not from lanes
 alone.
+
+The readings below are of the walk before it went by expert, one read
+an assignment: at one row the reads are the same, at two and four rows
+the walk by expert reads only the distinct experts (not yet timed).
 
 What the chip said (one v5e, ``tools/moe_walk_timing.py``, PR 32: one
 layer of the ``qwen3next_game`` cut, 64 dependent calls, us a call at 1 /
@@ -166,16 +176,17 @@ def walk_plan(d: int, f: int, itemsize: int, piece_bytes: int = PIECE_BYTES,
     return plan if min(plan.gate_up_slots, plan.down_slots) >= 2 else None
 
 
-def _walk_kernel(order_ref, expert_ref, weight_ref, count_ref, x_ref,
-                 gate_up_hbm, down_hbm, out_ref, gate_up_buf, down_buf,
-                 gate_up_sems, down_sems, *, top_k: int):
+def _walk_kernel(experts_ref, weight_ref, count_ref, x_ref, gate_up_hbm,
+                 down_hbm, out_ref, gate_up_buf, down_buf, gate_up_sems,
+                 down_sems):
     count = count_ref[0]
+    t = x_ref.shape[0]
     f = down_hbm.shape[1]
 
     class Ring:
-        """One matrix's pieces through its slots: piece ``c`` of
-        assignment ``i`` is number ``i * pieces + c`` of the stream and
-        lands in slot ``number % slots``; the piece that takes a slot
+        """One matrix's pieces through its slots: piece ``c`` of the
+        ``i``-th expert read is number ``i * pieces + c`` of the stream
+        and lands in slot ``number % slots``; the piece that takes a slot
         next is started when the one in it has been multiplied."""
 
         def __init__(self, hbm, buf, sems):
@@ -184,9 +195,8 @@ def _walk_kernel(order_ref, expert_ref, weight_ref, count_ref, x_ref,
             self.pieces = hbm.shape[1] // self.rows
 
         def copy(self, i, c, slot):
-            e = expert_ref[order_ref[i]]
             return pltpu.make_async_copy(
-                self.hbm.at[e, pl.ds(c * self.rows, self.rows)],
+                self.hbm.at[experts_ref[i], pl.ds(c * self.rows, self.rows)],
                 self.buf.at[slot], self.sems.at[slot])
 
         def start(self, i, c, slot):
@@ -201,7 +211,7 @@ def _walk_kernel(order_ref, expert_ref, weight_ref, count_ref, x_ref,
 
         def product(self, i, lhs_parts, width):
             """sum over the pieces of parts[:, piece] @ matrix[piece]."""
-            acc = jnp.zeros((x_ref.shape[0], width), F32)
+            acc = jnp.zeros((t, width), F32)
             for c in range(self.pieces):
                 slot = jax.lax.rem(i * self.pieces + c, self.slots)
                 self.copy(i, c, slot).wait()
@@ -218,8 +228,9 @@ def _walk_kernel(order_ref, expert_ref, weight_ref, count_ref, x_ref,
     out_ref[...] = jnp.zeros_like(out_ref)
     gate_up.fill()
     down.fill()
+    rows = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
 
-    def assignment(i, carry):
+    def expert(i, carry):
         gu = gate_up.product(i, (x_ref,), 2 * f)
         h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
         # h at float32's precision from two products in the stored type:
@@ -227,13 +238,16 @@ def _walk_kernel(order_ref, expert_ref, weight_ref, count_ref, x_ref,
         h_hi = h.astype(down_buf.dtype)
         h_lo = (h - h_hi.astype(F32)).astype(down_buf.dtype)
         y = down.product(i, (h_hi, h_lo), out_ref.shape[1])
-        at = order_ref[i]
-        rows = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
-        out_ref[...] += jnp.where(rows == at // top_k, weight_ref[at] * y,
-                                  0.0)
+        # each row's weight for this expert, 0 where the row did not
+        # choose it: such a row adds an exact 0
+        column = experts_ref[i] * t
+        w = jnp.zeros(out_ref.shape, F32)
+        for r in range(t):
+            w = jnp.where(rows == r, weight_ref[column + r], w)
+        out_ref[...] += jnp.where(w != 0.0, w * y, 0.0)
         return carry
 
-    jax.lax.fori_loop(0, count, assignment, 0)
+    jax.lax.fori_loop(0, count, expert, 0)
 
 
 def moe_walk_fits(d: int, f: int, itemsize: int = 2) -> bool:
@@ -245,31 +259,39 @@ def moe_walk_fits(d: int, f: int, itemsize: int = 2) -> bool:
                                  + PIECE_BYTES <= VMEM_LIMIT_BYTES)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "plan"))
+@functools.partial(jax.jit,
+                   static_argnames=("top_k", "interpret", "plan"))
 def moe_walk(x: jax.Array, gate_up: jax.Array, down: jax.Array,
-             expert: jax.Array, weight: jax.Array, order: jax.Array,
-             count: jax.Array, interpret=None,
+             experts: jax.Array, combine: jax.Array, count: jax.Array,
+             top_k: int, interpret=None,
              plan: Optional[WalkPlan] = None) -> jax.Array:
     """``HeldExperts._walk`` as one kernel call. ``x`` (T, D) in the
-    stored type, ``gate_up`` (E, D, 2F), ``down`` (E, F, D); ``expert``
-    and ``weight`` (T · top_k,) by assignment slot (slot // top_k is the
-    row), ``order`` the slots with the ``count`` landed ones first.
+    stored type, ``gate_up`` (E, D, 2F), ``down`` (E, F, D); ``experts``
+    (E,) local ids with the ``count`` that some row chose first, in
+    ascending order; ``combine`` (T, E) each row's routing weight for
+    each expert, 0 where the row did not choose it (``top_k`` a row).
     ``plan`` is ``walk_plan``'s for the widths unless a test hands one.
-    Returns (T, D) float32: each row's landed experts' outputs times
-    their weights, summed in ``order``; zeros for a row none landed
-    for."""
+    Returns (T, D) float32: each row's chosen experts' outputs times
+    their weights, summed in ascending expert id; zeros for a row none
+    of whose experts is held here."""
     t, d = x.shape
-    f = down.shape[1]
-    steps = expert.shape[0]
+    held, f = down.shape[0], down.shape[1]
     expert_size = 3 * d * f
+    # the count is known only on the device, so the compiler is told the
+    # most a call can read: an expert for every assignment of the call,
+    # or every held expert if there are fewer (exact where every expert
+    # is held and no two rows share one). It prefetches the step's other
+    # weights into on-chip memory only under instructions it believes
+    # long (module docstring)
+    reads = min(t * top_k, held)
     if plan is None:
         plan = walk_plan(d, f, gate_up.dtype.itemsize)
     if interpret is None:
         interpret = not on_tpu()
     return pl.pallas_call(
-        functools.partial(_walk_kernel, top_k=steps // t),
+        _walk_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3,
             grid=(1,),
             in_specs=[
                 pl.BlockSpec((t, d), lambda i, *_: (0, 0)),
@@ -290,18 +312,14 @@ def moe_walk(x: jax.Array, gate_up: jax.Array, down: jax.Array,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
-        # the count is known only on the device, so the compiler is told
-        # the most a call can read: every slot's assignment landed (exact
-        # where every expert is held). It prefetches the step's other
-        # weights into on-chip memory only under instructions it believes
-        # long (module docstring)
         cost_estimate=pl.CostEstimate(
-            flops=2 * steps * expert_size, transcendentals=steps * f,
-            bytes_accessed=steps * expert_size * gate_up.dtype.itemsize),
+            flops=2 * reads * expert_size, transcendentals=reads * f,
+            bytes_accessed=reads * expert_size * gate_up.dtype.itemsize),
         interpret=bool(interpret),
         # what a device trace calls the kernel (the HLO instruction and a
         # scope of its op_name), under the caller's ``moe_experts``
         name="moe_walk",
-    )(order.astype(jnp.int32), expert.astype(jnp.int32),
-      weight.astype(F32), jnp.reshape(count, (1,)).astype(jnp.int32),
-      x, gate_up, down)
+    )(experts.astype(jnp.int32),
+      # by expert, then row: expert e's weights are [e * T, (e + 1) * T)
+      jnp.transpose(combine).reshape(-1).astype(F32),
+      jnp.reshape(count, (1,)).astype(jnp.int32), x, gate_up, down)

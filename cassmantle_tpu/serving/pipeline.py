@@ -287,13 +287,17 @@ def note_moe_counters(routed) -> None:
     ``moe.assignments`` (real rows x tokens x layers x top-k),
     ``moe.assignments_held`` (those that landed on an expert held here),
     ``moe.experts_touched`` (held experts with at least one real token,
-    summed over the expert layers' calls) and the gauge
-    ``moe.load_max_over_mean`` over the held experts of a dispatch."""
+    summed over the expert layers' calls), ``moe.walk_reads_saved``
+    (landed assignments less the experts the decode walk read, summed
+    over its calls: reads that rows choosing the same expert shared) and
+    the gauge ``moe.load_max_over_mean`` over the held experts of a
+    dispatch."""
     if not routed:
         return
     # ONE transfer for every dispatch of the batch, already computed
     trees = jax.device_get(routed)
-    for name in ("assignments", "assignments_held", "experts_touched"):
+    for name in ("assignments", "assignments_held", "experts_touched",
+                 "walk_reads_saved"):
         metrics.inc("moe." + name, sum(t[name].item() for t in trees))
     load = trees[-1]["load"]
     if load.sum() > 0:

@@ -303,6 +303,20 @@ def test_prompt_generator_serves_the_family_and_publishes_its_routing(
     assert delta["moe.assignments"] == tokens * 4 * 2
     assert delta["moe.assignments_held"] == delta["moe.assignments"]
     assert 0 < delta["moe.experts_touched"] <= delta["moe.assignments_held"]
+    # three rows of a step (of 8 experts, top-2) choose the same expert
+    # somewhere in 8 steps x 4 layers: the walk read it once for them
+    assert 0 < delta["moe.walk_reads_saved"] < delta["moe.assignments_held"]
+
+
+def test_a_one_row_dispatch_saves_no_read(generator):
+    """A lone row's experts are distinct in every step: the walk reads
+    each once, as many reads as assignments that landed."""
+    before = counters()
+    generator.decode_ids_batch(["The quiet harbor at dawn"])
+    after = counters()
+    delta = {k: after[k] - before.get(k, 0.0) for k in after}
+    assert delta["moe.assignments_held"] > 0
+    assert delta["moe.walk_reads_saved"] == 0
 
 
 def test_batched_rows_decode_as_they_would_alone(generator,

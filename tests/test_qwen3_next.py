@@ -296,12 +296,17 @@ def test_the_walk_and_the_dense_form_agree_and_padding_is_not_counted(
     walk, stats_w = layer.apply(params, x, real, False)
     np.testing.assert_allclose(np.asarray(walk)[:20], np.asarray(dense)[:20],
                                atol=2e-5)
-    for name in stats_d:
+    for name in set(stats_d) - {"walk_reads_saved"}:
         np.testing.assert_array_equal(np.asarray(stats_d[name]),
                                       np.asarray(stats_w[name]))
     assert int(stats_d["assignments"]) == 40
     assert int(stats_d["experts_touched"]) == int(
         (np.asarray(stats_d["load"]) > 0).sum())
+    # the walk reads each touched expert once for all 20 rows; the dense
+    # form reads every held expert and saves nothing
+    assert int(stats_d["walk_reads_saved"]) == 0
+    assert int(stats_w["walk_reads_saved"]) == 40 - int(
+        stats_w["experts_touched"]) > 0
 
 
 SIGMOID_RULE = dict(scoring="sigmoid", selection_bias=True, norm_eps=1e-6,
@@ -469,15 +474,20 @@ def tolerance(layer, form="walk_kernel"):
 
 def assert_forms_agree(layer, forms, rows=slice(None)):
     """Every form's ``rows`` against the loop's, and its ``stats`` equal
-    to the loop's."""
+    to the loop's; but the reads the walk saved, which are the landed
+    assignments less the experts touched in a walk and none in the
+    dense form."""
     want, stats = forms["walk_xla"]
     for form, (out, form_stats) in forms.items():
         np.testing.assert_allclose(
             np.asarray(out)[rows], np.asarray(want)[rows],
             atol=tolerance(layer, form), err_msg=form)
-        for name in stats:
+        for name in set(stats) - {"walk_reads_saved"}:
             np.testing.assert_array_equal(np.asarray(form_stats[name]),
                                           np.asarray(stats[name]), form)
+        saved = 0 if form == "dense" else (int(stats["assignments_held"])
+                                           - int(stats["experts_touched"]))
+        assert int(form_stats["walk_reads_saved"]) == saved, form
 
 
 @pytest.mark.parametrize("rows", [1, 2, 4],
@@ -557,6 +567,95 @@ def test_a_row_alone_in_the_walk_kernel_equals_the_row_in_company(
         atol={"float32": 1e-6, "bfloat16": 4e-3}[jnp.dtype(layer.dtype).name])
 
 
+def routed_by_row(params, x, choices):
+    """Row r's ten choices are ``choices[r]``, in that order: the rows of
+    one list raise one input column (the others' are 0) and that row of
+    the router favours the list."""
+    lists = sorted(set(map(tuple, choices)))
+    router = np.asarray(params["params"]["router"], np.float32).copy()
+    x = np.asarray(x).copy()
+    router[:len(lists)] = 0.0
+    x[:, :len(lists)] = 0.0
+    for column, experts in enumerate(lists):
+        router[column, list(experts)] = 50.0 - np.arange(len(experts))
+    for row, experts in enumerate(choices):
+        x[row, lists.index(tuple(experts))] = 3.0
+    dtype = params["params"]["router"].dtype
+    return ({"params": dict(params["params"],
+                            router=jnp.asarray(router, dtype))},
+            jnp.asarray(x))
+
+
+ABSENT = list(range(16, 32))
+#: {case: (each row's ten choices, assignments that land, experts they
+#: touch)}; the layer holds 8..15. Rows list their held experts out of id
+#: order, so a row adds them otherwise than it chose them
+SHARING = {
+    "all_four_rows_share": ([[11, 8, 10, 9] + ABSENT[:6]] * 4, 16, 4),
+    "two_of_four_rows_share": ([[14, 9, 12] + ABSENT[:7]] * 2
+                               + [[10, 8] + ABSENT[7:15],
+                                  [15, 11, 13] + ABSENT[8:15]], 11, 8),
+    "no_two_rows_share": ([[15, 8] + ABSENT[:8], [9, 14] + ABSENT[:8],
+                           [13, 10] + ABSENT[:8], [11, 12] + ABSENT[:8]],
+                          8, 8),
+}
+
+
+@pytest.mark.parametrize("sharing", list(SHARING))
+def test_rows_that_chose_the_same_expert_share_one_read_of_it(
+        routed, sharing, monkeypatch):
+    """Each walk's loop runs once for every expert some row chose, in
+    ascending id, whatever number of rows chose it (``experts_touched``
+    trips, not ``assignments_held``); each row's column of weights is its
+    routing weight or 0; the kernel, the loop and the dense form agree,
+    and a row's output alone is its output in this company."""
+    layer, params, x = routed
+    choices, held, touched = SHARING[sharing]
+    params, x = routed_by_row(params, x, choices)
+    walks = []
+    loop = HeldExperts._walk
+
+    def loop_seen(self, xb, gate_up, down, experts, combine, count):
+        walks.append(("walk_xla", experts, combine, count))
+        return loop(self, xb, gate_up, down, experts, combine, count)
+
+    def kernel_seen(xb, gate_up, down, experts, combine, count, **kw):
+        walks.append(("walk_kernel", experts, combine, count))
+        return moe_walk.moe_walk(xb, gate_up, down, experts, combine, count,
+                                 plan=PLANS[layer.intermediate], **kw)
+
+    monkeypatch.setattr(HeldExperts, "_walk", loop_seen)
+    forms = three_forms(layer, params, x, jnp.ones((4,), bool), monkeypatch)
+    stats = forms["walk_xla"][1]
+    assert int(stats["assignments_held"]) == held
+    assert int(stats["experts_touched"]) == touched
+    assert int(stats["walk_reads_saved"]) == held - touched
+    assert_forms_agree(layer, forms)
+    with monkeypatch.context() as patch:
+        as_on_the_chip(patch, layer)
+        patch.setattr(moe, "moe_walk", kernel_seen)
+        together, _ = layer.apply(params, x, jnp.ones((4,), bool), False)
+        alone = [layer.apply(params, x[r:r + 1], jnp.ones((1,), bool),
+                             False)[0][0] for r in range(4)]
+    chosen = sorted({e - 8 for row in choices for e in row if 8 <= e < 16})
+    assert [w[0] for w in walks] == ["walk_xla"] + ["walk_kernel"] * 5
+    for form, experts, combine, count in walks[:2]:
+        assert int(count) == touched, form
+        np.testing.assert_array_equal(np.asarray(experts)[:touched], chosen)
+        row_chose = np.zeros((4, 8), bool)
+        for row, experts_of_row in enumerate(choices):
+            row_chose[row, [e - 8 for e in experts_of_row if 8 <= e < 16]] = 1
+        assert ((np.asarray(combine) > 0) == row_chose).all(), form
+    np.testing.assert_allclose(
+        np.asarray(together), forms["walk_kernel"][0], rtol=0, atol=0)
+    # outputs of order 1 (the router gives a row's first choice 0.63 of
+    # its weight): the interpreter's product over one row and over four
+    # blocks its float32 sums otherwise, 1.5e-6 apart at most
+    np.testing.assert_allclose(
+        np.stack(alone), np.asarray(together),
+        atol={"float32": 4e-6, "bfloat16": 4e-3}[jnp.dtype(layer.dtype).name])
+
+
 def test_widths_the_kernel_does_not_tile_keep_the_loop(whole_layer,
                                                        monkeypatch):
     """The rule reads the platform, ``dense`` and the widths: a layer 32
@@ -601,6 +700,20 @@ def test_prompt_generator_serves_the_family_and_publishes_its_routing(
     assert delta["moe.assignments"] == tokens * 4 * 2
     assert delta["moe.assignments_held"] == delta["moe.assignments"]
     assert 0 < delta["moe.experts_touched"] <= delta["moe.assignments_held"]
+    # three rows of a step (of 8 experts, top-2) choose the same expert
+    # somewhere in 8 steps x 4 layers: the walk read it once for them
+    assert 0 < delta["moe.walk_reads_saved"] < delta["moe.assignments_held"]
+
+
+def test_a_one_row_dispatch_saves_no_read(generator):
+    """A lone row's experts are distinct in every step: the walk reads
+    each once, as many reads as assignments that landed."""
+    before = counters()
+    generator.decode_ids_batch(["The quiet harbor at dawn"])
+    after = counters()
+    delta = {k: after[k] - before.get(k, 0.0) for k in after}
+    assert delta["moe.assignments_held"] > 0
+    assert delta["moe.walk_reads_saved"] == 0
 
 
 def test_batched_rows_decode_as_they_would_alone(generator,

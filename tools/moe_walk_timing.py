@@ -28,7 +28,8 @@ interpreted: no time it prints is a measurement).
 
 ``--dispatch`` times one whole ``greedy_decode`` dispatch of the served
 LM instead (its layers, 96 new tokens, seeded weights, ``--rows`` rows in
-the ``--bucket`` prompt bucket), the expert layers' rule answered here, in
+the ``--bucket`` prompt bucket, row r prompted with line r of
+``data/seeds.txt``), the expert layers' rule answered here, in
 the tool: what the walk costs between its neighbours, which the compiler
 schedules around it otherwise than around a layer alone (PR 32: the
 kernel won 13 us a layer call alone and lost 12 ms a dispatch until the
@@ -53,7 +54,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from cassmantle_tpu import config as configs  # noqa: E402
-from cassmantle_tpu.models.moe import HeldExperts  # noqa: E402
+from cassmantle_tpu.models.moe import HeldExperts, walk_operands  # noqa: E402
 from cassmantle_tpu.ops.moe_walk import moe_walk, walk_plan  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9  # one v5e chip (benchmarks/harness/peaks.py)
@@ -108,12 +109,19 @@ def routing(layer: HeldExperts, rows: int, steps: int, seed: int):
 
 
 def walk_of(layer: HeldExperts, form: str, rehearse: bool):
-    """(x, gate_up, down, expert, weight, landed) -> (T, D) float32."""
+    """(x, gate_up, down, expert, weight, landed) -> (T, D) float32; the
+    walk's operands are built from the slots as ``HeldExperts`` builds
+    them (``walk_operands``), inside the timed call in every form."""
     def walk(x, gate_up, down, expert, weight, landed):
-        order = jnp.argsort(~landed, stable=True)
-        count = jnp.sum(landed)
+        rows, held = x.shape[0], layer.experts_held
+        load = jnp.zeros((held,), jnp.int32).at[expert].add(
+            landed.astype(jnp.int32))
+        experts, combine = walk_operands(
+            *(a.reshape(rows, -1) for a in (expert, weight, landed)), load)
+        count = jnp.sum(load > 0)
         if form == "scan":
-            return x.astype(jnp.float32) * (weight[order[0]] + count)
+            return x.astype(jnp.float32) * (combine[:, experts[0]][:, None]
+                                            + count)
         if form == "reference":
             # every slot's expert in float32 at full precision, the
             # landed ones summed: what both forms are held against
@@ -128,10 +136,9 @@ def walk_of(layer: HeldExperts, form: str, rehearse: bool):
             y = jnp.where(landed[:, None], weight[:, None] * y, 0.0)
             return y.reshape(x.shape[0], layer.top_k, -1).sum(axis=1)
         if form == "xla":
-            return layer._walk(x, gate_up, down, expert, weight, order,
-                               count)
-        return moe_walk(x, gate_up, down, expert, weight, order, count,
-                        interpret=rehearse)
+            return layer._walk(x, gate_up, down, experts, combine, count)
+        return moe_walk(x, gate_up, down, experts, combine, count,
+                        top_k=layer.top_k, interpret=rehearse)
 
     return walk
 
@@ -150,13 +157,20 @@ def dispatches(args, out_path: str) -> int:
             key, jnp.zeros((1, 8), jnp.int32))))(
                 jax.random.PRNGKey(args.seed))
     new_tokens = cfg.sampler.max_new_tokens
-    title = np.frombuffer(b"The lighthouse keeper's storm", np.uint8)
+    # a row its own title, as the rooms of a served dispatch have: rows
+    # of one title route alike and share every expert read
+    with open(os.path.join(ROOT, "data", "seeds.txt"), "rb") as fh:
+        titles = [np.frombuffer(line.strip(), np.uint8)
+                  for line in fh if line.strip()]
     pair = make_apply_pair(model)
     for rows in (int(r) for r in args.rows.split(",")):
         ids = np.full((rows, args.bucket), 258, np.int32)
-        ids[:, :len(title)] = title
+        rows_titles = [t[:args.bucket] for t in titles[:rows]]
+        for row, title in enumerate(rows_titles):
+            ids[row, :len(title)] = title
+        lens = [len(t) for t in rows_titles]
         operands = (
-            jnp.asarray(ids), jnp.full((rows,), len(title), jnp.int32),
+            jnp.asarray(ids), jnp.asarray(lens, jnp.int32),
             jax.random.PRNGKey(0), new_tokens, 257, 0.0, 40)
         for form in args.forms.split(","):
             moe.on_tpu = lambda form=form: form == "kernel"
@@ -186,6 +200,7 @@ def dispatches(args, out_path: str) -> int:
                 "ms_min_max": [1e3 * min(seconds), 1e3 * max(seconds)],
                 "assignments_held": int(stats["assignments_held"]),
                 "experts_touched": int(stats["experts_touched"]),
+                "walk_reads_saved": int(stats["walk_reads_saved"]),
                 "compile_s": compile_s,
                 "device": jax.devices()[0].device_kind,
                 "rehearsal": args.rehearse,
@@ -256,14 +271,19 @@ def main() -> int:
             if want is None:
                 want, want_whole = first, whole
             mean_landed = float(jnp.mean(jnp.sum(landed, axis=1)))
+            # distinct experts a call: what a walk has to read
+            mean_read = float(np.mean([
+                len(set(np.asarray(e)[np.asarray(l)]))
+                for e, l in zip(expert, landed)]))
             call_us = 1e6 * statistics.median(seconds) / args.steps
             line = {
                 "config": args.config, "form": form, "rows": rows,
                 "steps": args.steps, "plan": plan and list(plan),
                 "landed_a_call": mean_landed,
+                "experts_a_call": mean_read,
                 "us_a_call": call_us,
-                "us_an_assignment": call_us / mean_landed,
-                "floor_us_a_call": 1e6 * mean_landed * assignment_bytes
+                "us_an_expert_read": call_us / mean_read,
+                "floor_us_a_call": 1e6 * mean_read * assignment_bytes
                 / HBM_BYTES_PER_S,
                 "max_abs_diff_from_first_form": float(
                     np.abs(first - want).max()),
