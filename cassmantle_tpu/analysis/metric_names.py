@@ -49,7 +49,7 @@ CATALOG_DOC = REPO / "docs" / "OBSERVABILITY.md"
 
 RULE = "metric-name"
 
-_METHODS = {"inc", "gauge", "observe", "timer"}
+_METHODS = {"inc", "gauge", "observe", "observe_nowait", "timer"}
 _SEGMENT = re.compile(r"^[a-z0-9_*]+$")
 _CATALOG_NAME = re.compile(r"`([a-z0-9_.<>*]+\.[a-z0-9_.<>*]+)`")
 
@@ -90,8 +90,9 @@ def _is_registry_receiver(expr: ast.expr) -> bool:
 
 def extract_sites(source: str, path: str) -> List[Tuple[str, str, int]]:
     """(name_pattern, method, lineno) for every literal metrics call —
-    ``<registry>.inc/gauge/observe/timer(...)`` on any registry-shaped
-    receiver (the ``metrics`` global, ``self._registry``, …) plus
+    ``<registry>.inc/gauge/observe/observe_nowait/timer(...)`` on any
+    registry-shaped receiver (the ``metrics`` global, ``self._registry``,
+    …) plus
     ``block_timer(...)`` (utils/profiling.py's metric-emitting stage
     timer, linted as an ``observe`` so device-stage names can't drift
     off the catalog) and the two spellings of a timed host region,
@@ -159,7 +160,8 @@ def load_catalog() -> List[str]:
 _TYPES = ("counter", "gauge", "histogram")
 # the method -> declared-type contract the type-agreement rule enforces
 _TYPE_FOR_METHOD = {"inc": "counter", "gauge": "gauge",
-                    "observe": "histogram", "timer": "histogram"}
+                    "observe": "histogram", "observe_nowait": "histogram",
+                    "timer": "histogram"}
 
 
 def load_catalog_types() -> Dict[str, str]:
@@ -225,7 +227,7 @@ class MetricNamePass(LintPass):
                     RULE, module.rel, lineno,
                     f"{name!r} has non-[a-z0-9_] segment(s) {bad}")
                 continue
-            if method in ("observe", "timer") and \
+            if method in ("observe", "observe_nowait", "timer") and \
                     not (segs[-1].endswith("_s")
                          or segs[-1].endswith("_size")):
                 yield Finding(

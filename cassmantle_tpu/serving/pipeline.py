@@ -877,30 +877,35 @@ class Text2ImagePipeline:
             note_w8a8_counter(self.cfg.models, self.cfg.sampler,
                               len(prompts))
             return images
-        sample_fn, scfg = (
-            degraded if degraded is not None
-            else (self._sample, self.cfg.sampler))
-        padded, n = pad_prompts_to_dp(prompts, self.dp)
-        ids = jnp.asarray(self._tokenize(padded))
-        uncond = jnp.asarray(self._tokenize(
-            [scfg.negative_prompt] * len(padded)))
-        rng = jax.random.PRNGKey(seed)
-        per_image = self._dispatch_flops(sample_fn, scfg)
-        metrics.observe("pipeline.image_batch_size", n,
-                        buckets=IMAGE_BATCH_BUCKETS)
+        # the host's part before the lock: CLIP tokens and their copies
+        # to the device, the key, the dispatch's FLOPs
+        with host_span("pipeline.image_prep"):
+            sample_fn, scfg = (
+                degraded if degraded is not None
+                else (self._sample, self.cfg.sampler))
+            padded, n = pad_prompts_to_dp(prompts, self.dp)
+            ids = jnp.asarray(self._tokenize(padded))
+            uncond = jnp.asarray(self._tokenize(
+                [scfg.negative_prompt] * len(padded)))
+            rng = jax.random.PRNGKey(seed)
+            per_image = self._dispatch_flops(sample_fn, scfg)
+            metrics.observe("pipeline.image_batch_size", n,
+                            buckets=IMAGE_BATCH_BUCKETS)
         # the wait for the lock is its own span (the lock's wait_span);
         # block_timer = metric + device-synchronized trace span (the
         # whole CLIP->denoise->VAE jit is ONE XLA computation; its
         # stages are named scopes inside it, in a device trace's op
         # metadata) + roofline attribution: flops_est on the span, live
-        # pipeline.mxu_utilization{pipeline="t2i"} vs the chip ceiling
+        # pipeline.mxu_utilization{pipeline="t2i"} vs the chip ceiling;
+        # inside it, the jitted call's own dispatch
         with self._dispatch_lock, block_timer(
                 "pipeline.t2i_s",
                 flops_est=(per_image * len(padded)) if per_image
                 else None,
                 pipeline="t2i", attrs={"padded_rows": len(padded)}):
-            fault_point("device.lost", peer="t2i")
-            images = sample_fn(self._params, ids, uncond, rng)
+            with host_span("pipeline.image_enqueue"):
+                fault_point("device.lost", peer="t2i")
+                images = sample_fn(self._params, ids, uncond, rng)
             # the dispatch lock exists to serialize device work; blocking
             # on the result under it is the point
             # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
@@ -1103,9 +1108,10 @@ class PromptGenerator:
         self._decode_calls = 0  # auto-advancing sampling key (decode_ids)
         # one in-flight decode per generator (see Text2ImagePipeline's
         # dispatch lock; the prompt queue usually serializes decodes, but
-        # direct generate() callers can race it)
-        self._dispatch_lock = OrderedLock("pipeline.prompt_dispatch",
-                                          rank=12)
+        # direct generate() callers can race it, so the wait is timed)
+        self._dispatch_lock = OrderedLock(
+            "pipeline.prompt_dispatch", rank=12,
+            wait_span="pipeline.lm_lock_wait")
         assert not (cfg.models.lm_int8 and cfg.models.lm_w8a8), (
             "lm_w8a8 and lm_int8 are mutually exclusive: both rewrite "
             "the same kernel leaves")
@@ -1442,89 +1448,95 @@ class PromptGenerator:
         m = self.mcfg
         max_new = max_new_tokens or self.cfg.sampler.max_new_tokens
         limit = m.max_positions - max_new - 1
-        rows = []
-        for text in seed_texts:
-            toks = self.tokenizer.encode(text)
-            rows.append(toks[-limit:] if len(toks) > limit else toks)
-        if seed is None:
-            seed = self._decode_calls
-            self._decode_calls += 1
-        own = [self._bucket_for(len(toks), max_new, limit) for toks in rows]
-        groups: dict = {}
-        for i, bucket in enumerate(own):
-            groups.setdefault(bucket, []).append(i)
-        if self.family.mixed_buckets and not any(
-                self._spec_enabled(b, max_new) for b in groups):
-            groups = {max(groups): list(range(len(rows)))}
-        out_tokens = np.zeros((len(rows), max_new), dtype=np.int32)
-        out_len = np.zeros((len(rows),), dtype=np.int32)
+        out_tokens = np.zeros((len(seed_texts), max_new), dtype=np.int32)
+        out_len = np.zeros((len(seed_texts),), dtype=np.int32)
         spec_stats = []
         routed = []  # a sparse LM's routing counters, one tree a dispatch
+        dispatched = []  # (row indices, real rows, tokens, gen_len)
         dispatch_flops = 0.0
         self._decode_flops_tls.value = 0.0  # failed decodes attr nothing
         self._decode_invalid_tls.value = ()
         bad_members: set = set()
-        for bucket, idxs in groups.items():
-            n = len(idxs)
-            fault_point("device.lost", peer="prompt")
-            n_pad = next((b for b in self.BATCH_BUCKETS if n <= b), n)
-            # roofline attribution: the dispatched shapes are fixed —
-            # n_pad rows prefill `bucket` tokens then run max_new decode
-            # steps regardless of eos (masked, not skipped), so the
-            # device work is exactly these tokens (spec decode bounds
-            # the same budget; greedy-equivalent estimate)
-            dispatch_flops += self._token_flops() * n_pad * (
-                bucket + max_new)
-            # pad id normalized into the MODEL's vocab: the byte-fallback
-            # tokenizer's pad (258) can exceed a small model vocab, and an
-            # out-of-range id NaN-fills flax Embed's take — the NaN then
-            # leaks through prefill into every decoded token
-            ids = np.full((n_pad, bucket),
-                          self.tokenizer.pad_id % m.vocab_size,
-                          dtype=np.int32)
-            lens = np.ones((n_pad,), dtype=np.int32)  # dummies: 1 pad token
-            offsets = np.zeros((n_pad,), dtype=np.int32)
-            for row, src in enumerate(idxs):
-                toks = rows[src]
-                # lint: ignore[host-sync] — toks is a host token list
-                ids[row, : len(toks)] = np.asarray(toks) % m.vocab_size
-                lens[row] = max(1, len(toks))
-                offsets[row] = own[src] - bucket
-            # an out-of-vocab eos (byte-fallback tokenizer vs a smaller
-            # model vocab) can never be emitted: pass vocab_size as an
-            # unreachable sentinel so early-stop is cleanly disabled — a
-            # modulo here would ALIAS a real token as a phantom
-            # terminator and silently truncate generations
-            eos = (self.tokenizer.eos_id
-                   if self.tokenizer.eos_id < m.vocab_size
-                   else m.vocab_size)
-            if self._spec_enabled(bucket, max_new):
-                from cassmantle_tpu.ops.decode import speculative_decode
+        # the host's part before the device takes the batch: tokenizer,
+        # buckets, the arrays and their copies to the device, the key and
+        # the jitted call's own dispatch (pipeline.lm_prep); the wait for
+        # the lock is its own span, before it
+        with self._dispatch_lock, host_span("pipeline.lm_prep"):
+            rows = []
+            for text in seed_texts:
+                toks = self.tokenizer.encode(text)
+                rows.append(toks[-limit:] if len(toks) > limit else toks)
+            if seed is None:
+                seed = self._decode_calls
+                self._decode_calls += 1
+            own = [self._bucket_for(len(toks), max_new, limit)
+                   for toks in rows]
+            groups: dict = {}
+            for i, bucket in enumerate(own):
+                groups.setdefault(bucket, []).append(i)
+            if self.family.mixed_buckets and not any(
+                    self._spec_enabled(b, max_new) for b in groups):
+                groups = {max(groups): list(range(len(rows)))}
+            for bucket, idxs in groups.items():
+                n = len(idxs)
+                fault_point("device.lost", peer="prompt")
+                n_pad = next((b for b in self.BATCH_BUCKETS if n <= b), n)
+                # roofline attribution: the dispatched shapes are fixed —
+                # n_pad rows prefill `bucket` tokens then run max_new
+                # decode steps regardless of eos (masked, not skipped), so
+                # the device work is exactly these tokens (spec decode
+                # bounds the same budget; greedy-equivalent estimate)
+                dispatch_flops += self._token_flops() * n_pad * (
+                    bucket + max_new)
+                # pad id normalized into the MODEL's vocab: the
+                # byte-fallback tokenizer's pad (258) can exceed a small
+                # model vocab, and an out-of-range id NaN-fills flax
+                # Embed's take — the NaN then leaks through prefill into
+                # every decoded token
+                ids = np.full((n_pad, bucket),
+                              self.tokenizer.pad_id % m.vocab_size,
+                              dtype=np.int32)
+                lens = np.ones((n_pad,), dtype=np.int32)  # dummies: 1 pad
+                offsets = np.zeros((n_pad,), dtype=np.int32)
+                for row, src in enumerate(idxs):
+                    toks = rows[src]
+                    # lint: ignore[host-sync] — toks is a host token list
+                    ids[row, : len(toks)] = np.asarray(toks) % m.vocab_size
+                    lens[row] = max(1, len(toks))
+                    offsets[row] = own[src] - bucket
+                # an out-of-vocab eos (byte-fallback tokenizer vs a smaller
+                # model vocab) can never be emitted: pass vocab_size as an
+                # unreachable sentinel so early-stop is cleanly disabled —
+                # a modulo here would ALIAS a real token as a phantom
+                # terminator and silently truncate generations
+                eos = (self.tokenizer.eos_id
+                       if self.tokenizer.eos_id < m.vocab_size
+                       else m.vocab_size)
+                if self._spec_enabled(bucket, max_new):
+                    from cassmantle_tpu.ops.decode import speculative_decode
 
-                with self._dispatch_lock, \
-                        block_timer("decode.verify_s") as sink:
-                    # draft + verify fuse into one device computation;
-                    # the spec_draft/spec_verify named scopes split the
-                    # two in a device trace
-                    tokens, gen_len, stats = speculative_decode(
-                        (self._prefill, self._step, self._chunk),
-                        self.params,
-                        jnp.asarray(ids),
-                        jnp.asarray(lens),
-                        max_new,
-                        eos,
-                        self.cfg.spec_decode.gamma,
-                        self._spec_draft,
-                        self._spec_draft_params,
-                        # dummy pad rows must not throttle the lockstep
-                        # accept-min; their rows are dropped below anyway
-                        jnp.asarray(np.arange(n_pad) < n),
-                    )
-                    sink.append(tokens)  # device-synchronized span
-                spec_stats.append(stats)
-            else:
-                stats_fn = self.family.cache_stats
-                with self._dispatch_lock:
+                    with block_timer("decode.verify_s") as sink:
+                        # draft + verify fuse into one device computation;
+                        # the spec_draft/spec_verify named scopes split
+                        # the two in a device trace
+                        tokens, gen_len, stats = speculative_decode(
+                            (self._prefill, self._step, self._chunk),
+                            self.params,
+                            jnp.asarray(ids),
+                            jnp.asarray(lens),
+                            max_new,
+                            eos,
+                            self.cfg.spec_decode.gamma,
+                            self._spec_draft,
+                            self._spec_draft_params,
+                            # dummy pad rows must not throttle the lockstep
+                            # accept-min; their rows are dropped below
+                            jnp.asarray(np.arange(n_pad) < n),
+                        )
+                        sink.append(tokens)  # device-synchronized span
+                    spec_stats.append(stats)
+                else:
+                    stats_fn = self.family.cache_stats
                     tokens, gen_len, *stats = greedy_decode(
                         (self._prefill, self._step),
                         self.params,
@@ -1539,34 +1551,41 @@ class PromptGenerator:
                         **(dict(row_mask=jnp.asarray(np.arange(n_pad) < n),
                                 cache_stats=stats_fn) if stats_fn else {}),
                     )
-                routed += stats
-            # one sync per DISPATCH (not per row): its result must land
-            # before its rows scatter into the output
-            toks_host = integrity.poison(
-                # lint: ignore[host-sync] — per-dispatch sync, not per-item
-                np.asarray(tokens[:n]), peer="prompt")
-            if not integrity.integrity_disabled():
-                # token-range validity on the just-transferred array —
-                # no extra sync. Tokens are ints, so finiteness can't
-                # carry the verdict here; range IS the sentinel: a dead
-                # runtime hands back garbage buffers, and the chaos
-                # poison fills -1 — both land outside [0, vocab).
-                ok = ((toks_host >= 0)
-                      & (toks_host < m.vocab_size)).all(axis=1)
-                bad_members.update(
-                    idxs[row] for row in np.nonzero(~ok)[0])
-            out_tokens[idxs] = toks_host
+                    routed += stats
+                dispatched.append((idxs, n, tokens, gen_len))
+        # one sync per DISPATCH (not per row): its result must land
+        # before its rows scatter into the output
+        landed = [integrity.poison(
             # lint: ignore[host-sync] — per-dispatch sync, not per-item
-            out_len[idxs] = np.asarray(gen_len[:n])
-            if lm_w8a8_armed(self.cfg.models):
-                # one int8-kernel decode dispatch (the gpt2_w8a8 bench
-                # A/B's proof the path engaged)
-                metrics.inc("pipeline.w8a8_dispatches")
-        self._record_spec_stats(spec_stats)
-        note_moe_counters(routed)
-        self._decode_flops_tls.value = dispatch_flops
-        self._decode_invalid_tls.value = tuple(sorted(bad_members))
-        return jnp.asarray(out_tokens), jnp.asarray(out_len)
+            np.asarray(tokens[:n]), peer="prompt")
+            for _idxs, n, tokens, _len in dispatched]
+        # the host's tail, tokens on the host -> return (pipeline.lm_tail;
+        # generate_batch's own follows)
+        with host_span("pipeline.lm_tail"):
+            for (idxs, n, _tokens, gen_len), toks_host in zip(dispatched,
+                                                              landed):
+                if not integrity.integrity_disabled():
+                    # token-range validity on the just-transferred array —
+                    # no extra sync. Tokens are ints, so finiteness can't
+                    # carry the verdict here; range IS the sentinel: a dead
+                    # runtime hands back garbage buffers, and the chaos
+                    # poison fills -1 — both land outside [0, vocab).
+                    ok = ((toks_host >= 0)
+                          & (toks_host < m.vocab_size)).all(axis=1)
+                    bad_members.update(
+                        idxs[row] for row in np.nonzero(~ok)[0])
+                out_tokens[idxs] = toks_host
+                # lint: ignore[host-sync] — per-dispatch sync, not per-item
+                out_len[idxs] = np.asarray(gen_len[:n])
+                if lm_w8a8_armed(self.cfg.models):
+                    # one int8-kernel decode dispatch (the gpt2_w8a8 bench
+                    # A/B's proof the path engaged)
+                    metrics.inc("pipeline.w8a8_dispatches")
+            self._record_spec_stats(spec_stats)
+            note_moe_counters(routed)
+            self._decode_flops_tls.value = dispatch_flops
+            self._decode_invalid_tls.value = tuple(sorted(bad_members))
+            return jnp.asarray(out_tokens), jnp.asarray(out_len)
 
     def _record_spec_stats(self, spec_stats) -> None:
         """ONE host transfer for the whole decode batch's spec counters
@@ -1618,27 +1637,29 @@ class PromptGenerator:
             out_tokens, gen_len = self.decode_ids_batch(
                 seed_texts, max_new_tokens)
             sink.append(out_tokens)
-        # ONE device->host transfer for the whole batch: the per-row
-        # int(gen_len[i]) / np.asarray(out_tokens[i]) this loop used to
-        # do was a sync per text (the host-sync lint's serialization
-        # hazard, tools/check_concurrency.py)
-        out_tokens = np.asarray(out_tokens)
-        lengths = np.asarray(gen_len).tolist()
-        bad = frozenset(
-            getattr(self._decode_invalid_tls, "value", ()) or ())
-        if bad:
-            integrity.note_invalid("prompt", "decode", sorted(bad))
-        texts = []
-        for i in range(len(seed_texts)):
-            if i in bad:
-                # never decode a rejected row — garbage/poisoned ids
-                # must not reach the tokenizer, let alone a player
-                texts.append(integrity.OutputInvalid(
-                    "prompt", "decode", [i]))
-                continue
-            texts.append(two_sentences(
-                self.tokenizer.decode(out_tokens[i, : lengths[i]].tolist())))
-        return texts
+        # the rest of the host's tail (decode_ids_batch observed its own
+        # part): ONE device->host transfer for the whole batch — the
+        # per-row int(gen_len[i]) / np.asarray(out_tokens[i]) this loop
+        # used to do was a sync per text (the host-sync lint's
+        # serialization hazard, tools/check_concurrency.py) — detokenizing
+        with host_span("pipeline.lm_tail"):
+            out_tokens = np.asarray(out_tokens)
+            lengths = np.asarray(gen_len).tolist()
+            bad = frozenset(
+                getattr(self._decode_invalid_tls, "value", ()) or ())
+            if bad:
+                integrity.note_invalid("prompt", "decode", sorted(bad))
+            texts = []
+            for i in range(len(seed_texts)):
+                if i in bad:
+                    # never decode a rejected row — garbage/poisoned ids
+                    # must not reach the tokenizer, let alone a player
+                    texts.append(integrity.OutputInvalid(
+                        "prompt", "decode", [i]))
+                    continue
+                texts.append(two_sentences(self.tokenizer.decode(
+                    out_tokens[i, : lengths[i]].tolist())))
+            return texts
 
     def generate(self, seed_text: str, max_new_tokens: Optional[int] = None
                  ) -> str:

@@ -570,11 +570,6 @@ class BatchingQueue(Generic[T, R]):
                 batch.append(nxt)
             if self._hold_while is not None:
                 await self._hold(batch, loop, opened)
-            # how long the window actually held the first item before
-            # dispatch: ~0 under load (bucket fills instantly), ~the
-            # full max_delay under trickle traffic — the knob's cost
-            metrics.gauge(f"{self.name}.coalesce_wait_s",
-                          loop.time() - opened)
         except asyncio.CancelledError:
             for _, fut in batch:
                 if not fut.done():

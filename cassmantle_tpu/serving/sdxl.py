@@ -494,28 +494,31 @@ class SDXLPipeline:
             note_w8a8_counter(self.cfg.models, self.cfg.sampler,
                               len(prompts))
             return images
-        sample_fn, scfg = (
-            degraded if degraded is not None
-            else (self._sample, self.cfg.sampler))
         from cassmantle_tpu.serving.pipeline import pad_prompts_to_dp
 
-        padded, n = pad_prompts_to_dp(prompts, self.dp)
-        ids = jnp.asarray(self._tokenize(padded))
-        uncond = jnp.asarray(self._tokenize(
-            [scfg.negative_prompt] * len(padded)))
-        rng = jax.random.PRNGKey(seed)
-        per_image = self._dispatch_flops(sample_fn, scfg)
-        metrics.observe("pipeline.image_batch_size", n,
-                        buckets=IMAGE_BATCH_BUCKETS)
-        # lock wait, device-synchronized dispatch and host tail: the
-        # same three spans as Text2ImagePipeline.generate
+        # host preparation, lock wait, device-synchronized dispatch with
+        # its enqueue inside and host tail: the same spans as
+        # Text2ImagePipeline.generate
+        with host_span("pipeline.image_prep"):
+            sample_fn, scfg = (
+                degraded if degraded is not None
+                else (self._sample, self.cfg.sampler))
+            padded, n = pad_prompts_to_dp(prompts, self.dp)
+            ids = jnp.asarray(self._tokenize(padded))
+            uncond = jnp.asarray(self._tokenize(
+                [scfg.negative_prompt] * len(padded)))
+            rng = jax.random.PRNGKey(seed)
+            per_image = self._dispatch_flops(sample_fn, scfg)
+            metrics.observe("pipeline.image_batch_size", n,
+                            buckets=IMAGE_BATCH_BUCKETS)
         with self._dispatch_lock, block_timer(
                 "pipeline.sdxl_s",
                 flops_est=(per_image * len(padded)) if per_image
                 else None,
                 pipeline="sdxl", attrs={"padded_rows": len(padded)}):
-            fault_point("device.lost", peer="sdxl")
-            images = sample_fn(self._params, ids, uncond, rng)
+            with host_span("pipeline.image_enqueue"):
+                fault_point("device.lost", peer="sdxl")
+                images = sample_fn(self._params, ids, uncond, rng)
             # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
             images = jax.block_until_ready(images)
         with host_span("pipeline.image_host"):

@@ -34,6 +34,7 @@ from cassmantle_tpu.serving.queue import (
 )
 from cassmantle_tpu.serving.supervisor import ServingSupervisor
 from cassmantle_tpu.utils.logging import get_logger, metrics
+from cassmantle_tpu.utils.profiling import install_gc_region
 
 log = get_logger("service")
 
@@ -62,6 +63,9 @@ class InferenceService:
         if mesh is None:
             mesh = default_serving_mesh(cfg)
         self.cfg = cfg
+        # every collection of the cyclic collector as the host span
+        # host.gc: a stopped dispatch thread is idle chip
+        install_gc_region()
         # shared with the Game in production (build_game) so breaker
         # trips here and in the engine fuse into one /readyz signal
         self.supervisor = supervisor or ServingSupervisor()

@@ -153,8 +153,10 @@ class OrderedLock:
     the lock is held, to histogram ``<wait_span>_s``, a span in the
     ambient trace and a profiler annotation (utils/profiling.py
     ``host_span``; 0 when the lock is free). Only a lock whose wait is
-    a term of a request's latency sets it — the image pipelines'
-    dispatch lock — so no other acquisition pays the clock reads.
+    a term of a request's latency sets it — the image pipelines' and the
+    prompt LM's dispatch locks — so no other acquisition pays the clock
+    reads. A wait span's name ends ``_wait``: a reading of a trace by
+    span name (docs/OBSERVABILITY.md) tells waits from work so.
     ``in_turn``: a thread that holds a ticket (:class:`Turns`) passes in
     its turn, the wait for it being part of the wait for the lock; a
     thread without one passes as at any lock.

@@ -265,6 +265,27 @@ class Metrics:
                             self._exemplar_pending.popitem(last=False)
                     slots.append((hist, idx, float(value), time.time()))
 
+    def observe_nowait(self, name: str, values: Sequence[float]) -> bool:
+        """Record ``values`` into the series' histogram, all of them or,
+        when the registry's lock is taken, none: False, at once. For
+        code that can run while its own thread holds that lock, where
+        :meth:`observe` would wait for ever: a ``gc.callbacks`` entry
+        runs wherever a collection starts, the sections under this lock
+        included. No exemplar."""
+        key = _series_key(name, None)
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            hist = self._hists.get(key)
+            if hist is None:
+                hist = Histogram(self._default_buckets)
+                self._hists[key] = hist
+            for value in values:
+                hist.observe(value)
+        finally:
+            self._lock.release()
+        return True
+
     # -- exemplars (ISSUE 18) ---------------------------------------------
     def set_exemplar_source(self, fn) -> None:
         """Install the trace-association callback ``fn() -> None |

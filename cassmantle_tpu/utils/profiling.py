@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import gc
 import time
 from typing import Callable, Iterator, Optional
 
@@ -31,10 +32,43 @@ from cassmantle_tpu.utils.logging import metrics
 
 def host_region(name: str):
     """Name a region of HOST code in the profiler's trace. The one door
-    to ``TraceAnnotation``: ``Tracer.span``, ``host_span`` and
-    ``block_timer`` come through here (with no profiler session active
-    a TraceMe is a flag test)."""
+    to ``TraceAnnotation``: ``Tracer.span``, ``host_span``,
+    ``block_timer`` and the collector's ``host.gc`` come through here
+    (with no profiler session active a TraceMe is a flag test)."""
     return jax.profiler.TraceAnnotation(name)
+
+
+#: the collection in progress (the collector runs one at a time) and the
+#: seconds of the collections whose histogram observation is still due
+_gc_open: list = []
+_gc_unobserved: list = []
+
+
+def _gc_region(phase: str, _info: dict) -> None:
+    """A ``gc.callbacks`` entry: each collection of Python's cyclic
+    collector is the host region ``host.gc`` on the collecting thread and
+    an observation of ``host.gc_s``. A collection starts wherever an
+    allocation tips the collector's count, inside the metrics registry's
+    locked sections too, so the observation does not wait for that lock:
+    a collection that finds it taken is observed with the next one."""
+    if phase == "start":
+        region = host_region("host.gc")
+        region.__enter__()
+        _gc_open.append((region, time.perf_counter()))
+    elif _gc_open:
+        region, start = _gc_open.pop()
+        _gc_unobserved.append(time.perf_counter() - start)
+        region.__exit__(None, None, None)
+        if metrics.observe_nowait("host.gc_s", _gc_unobserved):
+            _gc_unobserved.clear()
+
+
+def install_gc_region() -> None:
+    """Time every collection of the cyclic collector as ``host.gc``;
+    once a process, however often called. Between collections it costs
+    nothing."""
+    if _gc_region not in gc.callbacks:
+        gc.callbacks.append(_gc_region)
 
 
 def named_jit(fn: Callable, name: str, **jit_kwargs):

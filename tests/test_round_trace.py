@@ -4,7 +4,9 @@
 wait/batch spans, the prompt decode, the wait for the image pipeline's
 dispatch lock, the image dispatch and the host tail all land under it,
 each beside the histogram observed at the same place — the terms a
-benchmark adds up to a round's time. Tiny test size, real pipelines.
+benchmark adds up to a round's time — and so do the host's steps of each
+dispatch that run while the device may be idle (preparation, enqueue,
+tail). Tiny test size, real pipelines.
 """
 
 import asyncio
@@ -17,19 +19,32 @@ from cassmantle_tpu.obs.trace import tracer
 from cassmantle_tpu.utils.logging import metrics
 
 # span -> parent span, as docs/OBSERVABILITY.md draws the round's tree
+# (a span inside a block_timer is the ambient span's child: the stage's
+# own span is recorded when it ends)
 ROUND_TREE = {
     "prompt.queue_wait": "round.content",
     "prompt.batch": "round.content",
     "prompt.batch_service": "round.content",
     "pipeline.prompt_s": "prompt.batch",
+    "pipeline.lm_lock_wait": "prompt.batch",
+    "pipeline.lm_prep": "prompt.batch",
+    "pipeline.lm_tail": "prompt.batch",
+    "pipeline.image_prep": "round.content",
     "pipeline.image_lock_wait": "round.content",
     "pipeline.t2i_s": "round.content",
+    "pipeline.image_enqueue": "round.content",
     "pipeline.image_host": "round.content",
 }
+#: spans a round records twice: the LM's tail, in decode_ids_batch inside
+#: pipeline.prompt_s and in generate_batch after it
+TWICE = ("pipeline.lm_tail",)
 ROUND_HISTOGRAMS = (
     "round.content_s", "prompt.queue_wait_s", "prompt.batch_size",
-    "pipeline.prompt_s", "pipeline.image_lock_wait_s", "pipeline.t2i_s",
-    "pipeline.image_batch_size", "pipeline.image_host_s")
+    "pipeline.prompt_s", "pipeline.lm_lock_wait_s", "pipeline.lm_prep_s",
+    "pipeline.lm_tail_s", "pipeline.image_prep_s",
+    "pipeline.image_lock_wait_s", "pipeline.t2i_s",
+    "pipeline.image_enqueue_s", "pipeline.image_batch_size",
+    "pipeline.image_host_s")
 
 
 def hist_count(name: str) -> int:
@@ -98,12 +113,12 @@ def test_a_round_is_one_trace_with_the_tables_spans(
                    "round.generate" if ambient_root else None})
     if ambient_root:
         tree["round.generate"] = None
-    assert sorted(s["name"] for s in spans) == sorted(tree)
-    for name, parent in tree.items():
-        span = by_name[name]
+    assert sorted(s["name"] for s in spans) == sorted(list(tree) + list(TWICE))
+    for span in spans:
+        name = span["name"]
         assert span["trace_id"] == new_traces[0]
         got = by_id[span["parent_id"]]["name"] if span["parent_id"] else None
-        assert got == parent, (name, got)
+        assert got == tree[name], (name, got)
         assert span["start_ns"] == round(span["start_ts"] * 1e9)
     assert by_name["pipeline.t2i_s"]["attrs"]["padded_rows"] == 1
     # the terms lie inside the round, in the round's order
@@ -117,9 +132,34 @@ def test_a_round_is_one_trace_with_the_tables_spans(
     assert inside["start_ns"] <= starts[0]
     last = by_name[order[-1]]
     assert last["start_ns"] + last["duration_s"] * 1e9 <= end_ns + 1e6
-    # one observation in each histogram, at the same place
+
+    def bounds(span):
+        return span["start_ns"], span["start_ns"] + span["duration_s"] * 1e9
+
+    def within(inner, outer):
+        (i0, i1), (o0, o1) = bounds(inner), bounds(outer)
+        return o0 - 1e6 <= i0 and i1 <= o1 + 1e6
+
+    # the host's steps of each dispatch, where the docs draw them: the
+    # LM's lock wait, preparation and first tail inside pipeline.prompt_s,
+    # its second tail after it; the image's preparation before its lock,
+    # its enqueue inside pipeline.t2i_s
+    prompt_s, t2i = by_name["pipeline.prompt_s"], by_name["pipeline.t2i_s"]
+    tails = sorted((s for s in spans if s["name"] == "pipeline.lm_tail"),
+                   key=lambda s: s["start_ns"])
+    for inner in ("pipeline.lm_lock_wait", "pipeline.lm_prep"):
+        assert within(by_name[inner], prompt_s), inner
+    assert within(tails[0], prompt_s)
+    assert tails[1]["start_ns"] >= bounds(prompt_s)[1] - 1e6
+    assert bounds(by_name["pipeline.lm_prep"])[1] <= tails[0]["start_ns"]
+    assert bounds(by_name["pipeline.image_prep"])[1] <= \
+        by_name["pipeline.image_lock_wait"]["start_ns"] + 1e6
+    assert within(by_name["pipeline.image_enqueue"], t2i)
+    # one observation in each histogram, at the same place (the LM's tail
+    # in its two places)
     for hist in ROUND_HISTOGRAMS:
-        assert after[hist] - before[hist] == 1, hist
+        twice = hist[:-2] in TWICE
+        assert after[hist] - before[hist] == (2 if twice else 1), hist
 
 
 @pytest.mark.parametrize("contended", [False, True],
