@@ -11,6 +11,7 @@ implements for tests (engine/content.py).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import contextvars
 import os
 import random
@@ -399,6 +400,77 @@ def pad_prompts_to_dp(prompts: Sequence[str], dp: int):
     return list(prompts) + [""] * ((-n) % dp), n
 
 
+class ImageHandOver:
+    """An image pipeline's dispatch lock, passed to the next room once
+    this room's program is in the device's queue and the program ahead
+    of it has finished, so the device finds the next image waiting.
+
+    A dispatch takes the lock in its turn (``in_turn``: rounds reach the
+    device in the order their texts came back), enqueues its program,
+    waits under the lock for the pipeline's previous program if that is
+    still running (``pipeline.image_ahead_wait``), releases the lock and
+    the room's ticket with it, and waits for its own result outside.
+    At most two of one pipeline's programs are on the device at once:
+    the one running and the next. ``pipeline.image_queued_behind_size``
+    is 1 for a dispatch that found the program ahead unfinished, else
+    0. The stage timer (``pipeline.t2i_s`` / ``pipeline.sdxl_s``) runs
+    from the later of the enqueue and the moment this thread saw the
+    program ahead finish, so it never counts the time queued behind
+    another image."""
+
+    def __init__(self, name: str, rank: int) -> None:
+        # outermost hierarchy tier (docs/STATIC_ANALYSIS.md): held from
+        # the enqueue until the image ahead is done, so nothing coarser
+        # may nest inside
+        self.lock = OrderedLock(name, rank=rank,
+                                wait_span="pipeline.image_lock_wait",
+                                in_turn=True)
+        # the last program enqueued here: written under the lock, and
+        # cleared without it by a device-loss rebuild, whose dispatch
+        # may be the one holding the lock
+        self._ahead = None
+
+    def forget(self) -> None:
+        """Device-loss rebuild: the program ahead ran on the dead runtime,
+        so no dispatch waits for it."""
+        self._ahead = None
+
+    def dispatch(self, enqueue: Callable[[], object],
+                 timer: Callable[[], contextlib.AbstractContextManager],
+                 peer: str):
+        """Run ``enqueue`` (the sampler's jitted call) in turn and return
+        its result once ready, timed by ``timer()`` (the pipeline's
+        ``block_timer``)."""
+        with contextlib.ExitStack() as timed:
+            with self.lock:
+                ahead = self._ahead
+                try:
+                    queued = ahead is not None and not ahead.is_ready()
+                except RuntimeError:
+                    # failed, or its runtime is gone: finished for whoever
+                    # is behind it; the dispatch that made it raises it
+                    queued = False
+                metrics.observe("pipeline.image_queued_behind_size",
+                                int(queued), buckets=(0, 1))
+                if not queued:
+                    timed.enter_context(timer())
+                with host_span("pipeline.image_enqueue"):
+                    fault_point("device.lost", peer=peer)
+                    result = enqueue()
+                self._ahead = result
+                if queued:
+                    with host_span("pipeline.image_ahead_wait"):
+                        try:
+                            # the lock keeps a third program off the
+                            # device; waiting under it is the point
+                            # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
+                            jax.block_until_ready(ahead)
+                        except RuntimeError:
+                            pass        # raised by the dispatch that made it
+                    timed.enter_context(timer())
+            return jax.block_until_ready(result)
+
+
 def tokenize_clip_prompts(tokenizer, prompts: Sequence[str], pad_len: int,
                           vocab_size: int) -> np.ndarray:
     """Right-padded CLIP token ids: encode, trim, append EOS, pad.
@@ -628,17 +700,12 @@ class Text2ImagePipeline:
         self._flops_cache: dict = {}
         self._flops_lock = threading.Lock()
         self._flops_pending: set = set()
-        # One in-flight device batch per pipeline: concurrent round
-        # buffering calls generate() from multiple executor threads, and
-        # the device executes serially regardless — serializing dispatch
-        # here costs nothing and removes a whole deadlock class
-        # (concurrent executions of one compiled computation have
-        # deadlocked the CPU backend under some jaxlib builds).
-        # Outermost hierarchy tier (docs/STATIC_ANALYSIS.md): held for
-        # whole device dispatches, so nothing coarser may nest inside.
-        self._dispatch_lock = OrderedLock(
-            "pipeline.t2i_dispatch", rank=10,
-            wait_span="pipeline.image_lock_wait", in_turn=True)
+        # Concurrent round buffering calls generate() from several
+        # executor threads; they reach the device one program after
+        # another, each enqueued while the one ahead still runs
+        # (ImageHandOver). img2img holds the same lock for its whole
+        # dispatch.
+        self._hand_over = ImageHandOver("pipeline.t2i_dispatch", rank=10)
         # stage-disaggregated serving (serving/stages.py): built lazily
         # on the first staged generate; the supervisor is wired by
         # InferenceService so per-stage watchdog health fuses into
@@ -673,7 +740,8 @@ class Text2ImagePipeline:
         jitted fns stay valid; the recovery manager's warm pass
         verifies zero recompiles. The staged slot server held device
         state tied to the dead runtime: stop and drop it here — it
-        rebuilds lazily on the next staged generate."""
+        rebuilds lazily on the next staged generate; so did the image
+        dispatch's last program, which no dispatch waits for now."""
         staged = self._staged
         if staged is not None:
             self._staged = None
@@ -682,6 +750,7 @@ class Text2ImagePipeline:
             # lint: ignore[swallowed-error] — the staged server is dropped and rebuilt regardless; recovery's warm-pass counters cover the reload outcome
             except Exception:
                 log.exception("staged server stop during reload failed")
+        self._hand_over.forget()
         self._param_loader()
         self._publish_params()
         if getattr(self, "vae_enc", None) is not None:
@@ -898,18 +967,14 @@ class Text2ImagePipeline:
         # metadata) + roofline attribution: flops_est on the span, live
         # pipeline.mxu_utilization{pipeline="t2i"} vs the chip ceiling;
         # inside it, the jitted call's own dispatch
-        with self._dispatch_lock, block_timer(
+        images = self._hand_over.dispatch(
+            lambda: sample_fn(self._params, ids, uncond, rng),
+            lambda: block_timer(
                 "pipeline.t2i_s",
                 flops_est=(per_image * len(padded)) if per_image
                 else None,
-                pipeline="t2i", attrs={"padded_rows": len(padded)}):
-            with host_span("pipeline.image_enqueue"):
-                fault_point("device.lost", peer="t2i")
-                images = sample_fn(self._params, ids, uncond, rng)
-            # the dispatch lock exists to serialize device work; blocking
-            # on the result under it is the point
-            # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
-            images = jax.block_until_ready(images)
+                pipeline="t2i", attrs={"padded_rows": len(padded)}),
+            peer="t2i")
         # the round's host tail: device result ready -> generate returns
         with host_span("pipeline.image_host"):
             out = integrity.poison(np.asarray(images[:n]), peer="t2i")
@@ -1020,7 +1085,7 @@ class Text2ImagePipeline:
         uncond = jnp.asarray(self._tokenize(
             [self.cfg.sampler.negative_prompt] * len(prompts)))
         params = dict(self._params, vae_enc=self.enc_params)
-        with self._dispatch_lock, block_timer("pipeline.i2i_s"):
+        with self._hand_over.lock, block_timer("pipeline.i2i_s"):
             out = self._i2i_fn(k)(
                 params, ids, uncond, imgf, jax.random.PRNGKey(seed)
             )

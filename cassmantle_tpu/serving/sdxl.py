@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from cassmantle_tpu.chaos import fault_point
 from cassmantle_tpu.config import FrameworkConfig
 from cassmantle_tpu.models.clip_text import ClipTextEncoder
 from cassmantle_tpu.models.layers import timestep_embedding
@@ -265,14 +264,12 @@ class SDXLPipeline:
 
         self._sample, self.dp = dp_sharded_sampler(
             self._sample_impl, mesh, "sdxl_sample")
-        # one in-flight device batch per pipeline (see Text2ImagePipeline:
-        # concurrent executions of one compiled computation have
-        # deadlocked the CPU backend under some jaxlib builds)
+        # rooms' images reach the device back to back, in turn (see
+        # Text2ImagePipeline and serving/pipeline.py::ImageHandOver)
+        from cassmantle_tpu.serving.pipeline import ImageHandOver
         from cassmantle_tpu.utils.locks import OrderedLock
 
-        self._dispatch_lock = OrderedLock(
-            "pipeline.sdxl_dispatch", rank=11,
-            wait_span="pipeline.image_lock_wait", in_turn=True)
+        self._hand_over = ImageHandOver("pipeline.sdxl_dispatch", rank=11)
         # stage-disaggregated serving (serving/stages.py); supervisor is
         # wired by InferenceService, same as the SD1.5 pipeline
         self.supervisor = None
@@ -307,7 +304,8 @@ class SDXLPipeline:
         boot load path and republish the tree (see
         Text2ImagePipeline.reload_params — same contract: params are
         jit ARGUMENTS, so nothing recompiles; the staged slot server is
-        dropped and rebuilds lazily)."""
+        dropped and rebuilds lazily, and the image dispatch forgets its
+        last program)."""
         staged = self._staged
         if staged is not None:
             self._staged = None
@@ -316,6 +314,7 @@ class SDXLPipeline:
             # lint: ignore[swallowed-error] — the staged server is dropped and rebuilt regardless; recovery's warm-pass counters cover the reload outcome
             except Exception:
                 log.exception("staged server stop during reload failed")
+        self._hand_over.forget()
         self._param_loader()
         self._publish_params()
 
@@ -496,8 +495,8 @@ class SDXLPipeline:
             return images
         from cassmantle_tpu.serving.pipeline import pad_prompts_to_dp
 
-        # host preparation, lock wait, device-synchronized dispatch with
-        # its enqueue inside and host tail: the same spans as
+        # host preparation, lock wait, the hand-over's enqueue and
+        # device-synchronized dispatch, host tail: the same spans as
         # Text2ImagePipeline.generate
         with host_span("pipeline.image_prep"):
             sample_fn, scfg = (
@@ -511,16 +510,14 @@ class SDXLPipeline:
             per_image = self._dispatch_flops(sample_fn, scfg)
             metrics.observe("pipeline.image_batch_size", n,
                             buckets=IMAGE_BATCH_BUCKETS)
-        with self._dispatch_lock, block_timer(
+        images = self._hand_over.dispatch(
+            lambda: sample_fn(self._params, ids, uncond, rng),
+            lambda: block_timer(
                 "pipeline.sdxl_s",
                 flops_est=(per_image * len(padded)) if per_image
                 else None,
-                pipeline="sdxl", attrs={"padded_rows": len(padded)}):
-            with host_span("pipeline.image_enqueue"):
-                fault_point("device.lost", peer="sdxl")
-                images = sample_fn(self._params, ids, uncond, rng)
-            # lint: ignore[lock-blocking-call] — intentional sync under dispatch lock
-            images = jax.block_until_ready(images)
+                pipeline="sdxl", attrs={"padded_rows": len(padded)}),
+            peer="sdxl")
         with host_span("pipeline.image_host"):
             out = integrity.poison(np.asarray(images[:n]), peer="sdxl")
             # host-side degenerate-frame sentinel on the transferred

@@ -143,3 +143,50 @@ def test_the_spans_are_on_the_profilers_host_lines(backend, tmp_path):
                  "pipeline.t2i_s", "pipeline.image_host", "host.gc"):
         assert name in names, (name, sorted(set(names)))
     assert names.count("pipeline.lm_tail") == 2
+
+
+def test_an_image_behind_an_unfinished_one_waits_for_it_under_its_own_span(
+        backend, tmp_path):
+    """Two rooms' images at once (the CPU backend runs a tiny image for
+    about a second): the second is enqueued while the first runs, waits
+    for it under ``pipeline.image_ahead_wait`` and times its own
+    dispatch, ``pipeline.t2i_s``, from that wait's end; both spans are
+    on the profiler's host lines."""
+    from benchmarks.harness import host_trace
+    from benchmarks.harness.xplane import load_planes
+    from cassmantle_tpu.utils.locks import Turns
+
+    backend.t2i.generate(["a lighthouse"], seed=1)     # compiled first
+    turns = Turns()
+    tickets = [turns.take() for _ in range(2)]
+
+    def room(index):
+        with turns.holding(tickets[index]):
+            backend.t2i.generate([f"a lighthouse {index}"], seed=index)
+
+    before = totals(["pipeline.image_ahead_wait_s"])
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        threads = [threading.Thread(target=room, args=(i,))
+                   for i in range(2)]
+        for thread in threads:
+            thread.start()
+        for thread in threads:
+            thread.join(60)
+    finally:
+        jax.profiler.stop_trace()
+    assert not any(thread.is_alive() for thread in threads)
+    got = delta(before, totals(["pipeline.image_ahead_wait_s"]))
+    assert got["pipeline.image_ahead_wait_s"][1] == 1
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    spans = host_trace.HostTrace(load_planes(path)).spans
+    (wait,) = [(s, d) for s, d, n in spans
+               if n == "pipeline.image_ahead_wait"]
+    timed = sorted(s for s, _d, n in spans if n == "pipeline.t2i_s")
+    assert len(timed) == 2
+    # the first image's dispatch is timed from before the wait, the
+    # second's from its end
+    assert timed[0] < wait[0] and timed[1] >= wait[0] + wait[1]

@@ -195,6 +195,18 @@ def test_every_lock_wait_span_is_named_as_a_wait():
     assert all(host_trace.is_wait(name) for name in found), found
 
 
+def test_the_wait_for_the_image_ahead_is_a_wait():
+    """An image dispatch that waits under the lock for the image ahead
+    (``pipeline.image_ahead_wait``) waits: idle under it goes to the
+    work span open around it, here the round."""
+    assert host_trace.is_wait("pipeline.image_ahead_wait")
+    spans = [(0, 1000, "round.content"),
+             (100, 300, "pipeline.image_ahead_wait")]
+    devices = {"/device:TPU:0": [(0, 100), (400, 600)]}
+    assert host_trace.idle_by_span(devices, spans, window=(0, 1000)) == {
+        "round.content": pytest.approx(0.3)}
+
+
 class FakeWindow:
     """``runner.Window``'s reading of two histogram snapshots."""
 

@@ -167,7 +167,7 @@ def test_a_round_is_one_trace_with_the_tables_spans(
 def test_lock_wait_is_observed_once_per_acquisition(backend, contended):
     """The image dispatch lock times the request for it until it is
     held: near 0 when free, the holder's remaining time when not."""
-    lock = backend.t2i._dispatch_lock
+    lock = backend.t2i._hand_over.lock
     assert lock.wait_span == "pipeline.image_lock_wait"
     hist = "pipeline.image_lock_wait_s"
     if not contended:
@@ -271,7 +271,7 @@ def test_rounds_render_in_the_order_generate_was_called(backend,
 
     import numpy as np
 
-    lock, rendered = backend.t2i._dispatch_lock, []
+    lock, rendered = backend.t2i._hand_over.lock, []
 
     def generate(prompts, seed=0, deadline_s=None):
         call = int(prompts[0].rsplit(" ", 1)[1])
