@@ -300,6 +300,57 @@ class Lfm2MoeConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class OuroConfig:
+    """Ouro-class looped causal LM (models/ouro.py): one stack of
+    ``num_hidden_layers`` dense layers (multi-head attention, SwiGLU MLP,
+    four RMSNorms a layer) run ``total_ut_steps`` times a token over one
+    set of weights, the final norm between passes, an untied head. Field
+    names are the published ``config.json``'s; defaults are Ouro-2.6B's.
+
+    ``early_exit_threshold`` 1 runs every token through every pass: the
+    exit gate is held but decides nothing, and no other value is served
+    (rows that leave the loop at different passes need a scheduler that
+    serves them). ``tiny()`` is the CPU-test variant, four passes kept.
+    """
+
+    vocab_size: int = 49152
+    hidden_size: int = 2048
+    intermediate_size: int = 5632
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    head_dim: int = 128
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    total_ut_steps: int = 4
+    early_exit_threshold: float = 1.0
+    max_position_embeddings: int = 65536
+    tie_word_embeddings: bool = False
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        assert self.num_key_value_heads == self.num_attention_heads, (
+            "grouped key/value heads are not served for this family")
+        assert self.early_exit_threshold == 1.0, (
+            "early exit is not served: every token runs every pass")
+        assert not self.tie_word_embeddings, "a tied head is not served"
+
+    @property
+    def max_positions(self) -> int:
+        """The name the serving layer knows the position limit by."""
+        return self.max_position_embeddings
+
+    @staticmethod
+    def tiny() -> "OuroConfig":
+        """Two layers of 4 heads, four passes."""
+        return OuroConfig(
+            vocab_size=300, hidden_size=32, intermediate_size=48,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=4, head_dim=8,
+            max_position_embeddings=128, dtype="float32")
+
+
+@dataclasses.dataclass(frozen=True)
 class MiniLMConfig:
     """all-MiniLM-L6-v2-class sentence encoder for guess scoring."""
 
@@ -332,6 +383,10 @@ class ModelZooConfig:
     # attention layers, a dense leading layer, sigmoid-routed experts);
     # when set it is the prompt LM, with the same refusals as qwen3_next.
     lfm2_moe: Optional[Lfm2MoeConfig] = None
+    # Optional Ouro-class looped prompt LM (one dense stack run several
+    # times a token); when set it is the prompt LM, with the same
+    # refusals as qwen3_next.
+    ouro: Optional[OuroConfig] = None
     minilm: MiniLMConfig = dataclasses.field(default_factory=MiniLMConfig)
     # Directory holding safetensors checkpoints; None -> deterministic
     # random-init (fixed PRNG) so the full pipeline runs without artifacts.
@@ -944,6 +999,17 @@ def lfm2_game_config() -> FrameworkConfig:
         sampler=SamplerConfig(consistency=True, num_steps=4))
 
 
+def ouro_game_config() -> FrameworkConfig:
+    """The game with an Ouro-2.6B-class looped story model and the
+    few-step image model of ``qwen3next_game_config``. Nothing is cut:
+    all 48 layers, all 4 passes, the whole vocabulary and the untied head
+    at the published widths, 2.67 B parameters held once on one chip."""
+
+    return FrameworkConfig(
+        models=ModelZooConfig(ouro=OuroConfig()),
+        sampler=SamplerConfig(consistency=True, num_steps=4))
+
+
 def test_config() -> FrameworkConfig:
     """A tiny config for CPU tests: small models, fast rounds, 64px images."""
 
@@ -998,6 +1064,15 @@ def test_lfm2_config() -> FrameworkConfig:
     return base.replace(
         models=dataclasses.replace(base.models,
                                    lfm2_moe=Lfm2MoeConfig.tiny()),
+        sampler=dataclasses.replace(base.sampler, consistency=True))
+
+
+def test_ouro_config() -> FrameworkConfig:
+    """``ouro_game_config`` at the CPU-test size."""
+
+    base = test_config()
+    return base.replace(
+        models=dataclasses.replace(base.models, ouro=OuroConfig.tiny()),
         sampler=dataclasses.replace(base.sampler, consistency=True))
 
 

@@ -1099,6 +1099,19 @@ class Text2ImagePipeline:
         return out
 
 
+def note_loop_counters(stepped) -> None:
+    """Publish what a looped prompt LM's decode steps did in the
+    dispatches just synced (``cache_stats`` trees, as
+    ``note_moe_counters``): ``ouro.pass_steps`` (decode steps times the
+    passes each ran) and ``ouro.kv_slots`` (valid cache slots the real
+    rows attended, summed over steps, passes and layers)."""
+    if not stepped:
+        return
+    trees = jax.device_get(stepped)
+    for name in ("pass_steps", "kv_slots"):
+        metrics.inc("ouro." + name, sum(t[name].item() for t in trees))
+
+
 def _dense_params(tree) -> int:
     from cassmantle_tpu.obs import costmodel
 
@@ -1111,11 +1124,12 @@ class LMFamily(NamedTuple):
     tokenizer and checkpoint are called), how to build the model, how to
     convert a checkpoint (None: no converter, ``weights_dir`` is refused),
     which options it serves, the parameters a token's forward touches
-    (``active_params(tree, mcfg)``: all of them for a dense LM), the
-    counters its cache carries (``ops/decode.py`` ``cache_stats``) and
-    whether a decode batch may mix prompt buckets (``mixed_buckets``:
-    everything a decode step does with a position follows the row's own
-    position id, not its cache slot)."""
+    (``active_params(tree, mcfg)``: all of them for a dense LM; a looped
+    one counts the stack once a pass), the counters its cache carries
+    (``ops/decode.py`` ``cache_stats``) and what publishes them once a
+    dispatch (``note_counters``), and whether a decode batch may mix
+    prompt buckets (``mixed_buckets``: everything a decode step does with
+    a position follows the row's own position id, not its cache slot)."""
 
     name: str
     model: Callable
@@ -1124,6 +1138,7 @@ class LMFamily(NamedTuple):
     speculative: bool = True     # needs decode_chunk and a cache that rolls back
     active_params: Callable = lambda tree, mcfg: _dense_params(tree)
     cache_stats: Optional[Callable] = None
+    note_counters: Callable = note_moe_counters
     mixed_buckets: bool = True
 
 
@@ -1131,7 +1146,7 @@ def _lm_families() -> Tuple[LMFamily, ...]:
     """The families in the order they are looked for: the first whose
     config attribute is set on ``cfg.models`` is the prompt LM; GPT-2,
     always set, comes last."""
-    from cassmantle_tpu.models import lfm2_moe, qwen3_next
+    from cassmantle_tpu.models import lfm2_moe, ouro, qwen3_next
     from cassmantle_tpu.models.mistral import MistralLM
     from cassmantle_tpu.models.weights import convert_mistral
 
@@ -1144,6 +1159,11 @@ def _lm_families() -> Tuple[LMFamily, ...]:
                  quantized=False, speculative=False,
                  active_params=lfm2_moe.active_params,
                  cache_stats=lfm2_moe.cache_stats),
+        LMFamily("ouro", ouro.OuroLM,
+                 quantized=False, speculative=False,
+                 active_params=ouro.active_params,
+                 cache_stats=ouro.cache_stats,
+                 note_counters=note_loop_counters),
         LMFamily("mistral", MistralLM,
                  lambda m: lambda t: convert_mistral(t, m.num_layers),
                  mixed_buckets=False),  # its window counts cache slots
@@ -1161,8 +1181,10 @@ class PromptGenerator:
     backend.py:25) when ``cfg.models.mistral`` is set, a Qwen3-Next-class
     sparse model with linear-attention layers when ``cfg.models.qwen3_next``
     is, an LFM2-MoE-class one (short convolutions, sigmoid-routed experts)
-    when ``cfg.models.lfm2_moe`` is. All expose the same prefill/decode_step
-    contract, so the scan in ops/decode.py drives each."""
+    when ``cfg.models.lfm2_moe`` is, an Ouro-class looped one (one dense
+    stack run several times a token) when ``cfg.models.ouro`` is. All
+    expose the same prefill/decode_step contract, so the scan in
+    ops/decode.py drives each."""
 
     PROMPT_BUCKETS = (32, 64, 128, 256)
 
@@ -1191,8 +1213,9 @@ class PromptGenerator:
         if not family.speculative and cfg.spec_decode.mode != "off":
             raise ValueError(
                 f"speculative decode is not served for {family.name}: a "
-                f"rejected draft would need its recurrent state or "
-                f"convolution window rolled back")
+                f"rejected draft would need its cache rolled back (a "
+                f"recurrent state, a convolution window, every pass's "
+                f"entries)")
         if family.converter is None and weights_dir:
             raise ValueError(
                 f"no checkpoint converter for {family.name}: it runs on "
@@ -1516,7 +1539,7 @@ class PromptGenerator:
         out_tokens = np.zeros((len(seed_texts), max_new), dtype=np.int32)
         out_len = np.zeros((len(seed_texts),), dtype=np.int32)
         spec_stats = []
-        routed = []  # a sparse LM's routing counters, one tree a dispatch
+        routed = []  # the cache's counters (cache_stats), one tree a dispatch
         dispatched = []  # (row indices, real rows, tokens, gen_len)
         dispatch_flops = 0.0
         self._decode_flops_tls.value = 0.0  # failed decodes attr nothing
@@ -1647,7 +1670,7 @@ class PromptGenerator:
                     # A/B's proof the path engaged)
                     metrics.inc("pipeline.w8a8_dispatches")
             self._record_spec_stats(spec_stats)
-            note_moe_counters(routed)
+            self.family.note_counters(routed)
             self._decode_flops_tls.value = dispatch_flops
             self._decode_invalid_tls.value = tuple(sorted(bad_members))
             return jnp.asarray(out_tokens), jnp.asarray(out_len)

@@ -358,6 +358,73 @@ def test_the_sparse_prompt_lm_compiles_for_one_v5e_at_its_served_size(
         or scope.endswith("_attn/mixer/convert_element_type"))], rounded
 
 
+@pytest.mark.parametrize("rows", [1, 4])
+def test_the_looped_prompt_lm_holds_its_weights_once_and_copies_nothing(
+        v5e, rows):
+    """``greedy_decode`` over ``ouro_game``'s looped LM (48 layers, 4
+    passes, published widths) at one row and at the served batch of four,
+    bucket 32: its arguments are the weights once (5.336 GB: the passes
+    loop over one stacked set), its temporaries the cache of every pass
+    and layer (0.20 GB a row at 128 slots) and a little; no weight matrix
+    and no cache is copied anywhere in the program (the prefill hands each
+    pass's k/v over as the cache in one write, laid out as the step reads
+    it; a prompt's q, k and v projections come out whole, else the
+    compiler copied the stacked q, k and v weights to a heads-major layout
+    at one row), no layer's weights are sliced out of the stack into a
+    buffer of their own (each product reads its slice in place), and the
+    step's layer loop writes the cache in place."""
+    from cassmantle_tpu import config as configs
+    from cassmantle_tpu.models import ouro
+    from cassmantle_tpu.ops.decode import greedy_decode, make_apply_pair
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    model = ouro.OuroLM(configs.ouro_game_config().models.ouro)
+    tree = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, BF16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jax.ShapeDtypeStruct((1, 8), jnp.int32)))
+    compiled = greedy_decode.lower(
+        make_apply_pair(model), tree, on_chip((rows, 32), jnp.int32),
+        on_chip((rows,), jnp.int32), on_chip((2,), jnp.uint32), 96, 257, 0.0,
+        40, row_mask=on_chip((rows,), jnp.bool_),
+        cache_stats=ouro.cache_stats,
+        position_offset=on_chip((rows,), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    assert 5.33e9 < memory.argument_size_in_bytes < 5.34e9
+    cache = 2 * 48 * 4 * rows * 128 * 2048 * 2
+    assert cache < memory.temp_size_in_bytes < cache + 0.3e9
+    text = compiled.as_text()
+    copies = [line for line in text.splitlines() if " copy(" in line and (
+        "bf16[48," in line.split(" copy(")[0] or "[2048,2048]" in line
+        or "[2048,5632]" in line or "[5632,2048]" in line)]
+    assert not copies, copies[:2]
+    # a layer's matrix as a buffer of its own: a result of that shape in a
+    # computation that is not a fusion's body (a fusion's body reads its
+    # slice of the stack in place)
+    weight = re.compile(
+        r"\s*(ROOT )?%\S+ = bf16\[(1,)?(2048,2048|2048,5632|5632,2048)\]")
+    body, sliced = "", []
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            body = line
+        elif weight.match(line) and "fused_computation" not in body:
+            sliced.append(line)
+    assert not sliced, sliced[:2]
+    writes = [line for line in text.splitlines()
+              if "lm_decode_step" in line and "ouro_pass" in line
+              and "self_attn/dynamic_update_slice" in line
+              and line.lstrip().startswith("%")
+              and "= bf16[48,4,%d,128,16,128]" % rows in line]
+    assert len(writes) == 2, len(writes)
+    # the step's head carries its scope into the device trace's op names
+    assert [line for line in text.splitlines()
+            if "lm_decode_step" in line and "/lm_head/" in line]
+
+
 @pytest.mark.parametrize("d, f", [(2048, 512), (2048, 1536)],
                          ids=["qwen3next_experts", "lfm2_experts"])
 def test_the_walk_kernels_rings_are_sized_by_piece_not_by_expert(d, f):
